@@ -13,6 +13,7 @@ from blendjax._native.build import (
     load_render_frame,
     load_tile_delta,
     load_tile_delta_palidx,
+    native_status,
 )
 
 __all__ = [
@@ -20,4 +21,5 @@ __all__ = [
     "load_tile_delta",
     "load_palettize",
     "load_tile_delta_palidx",
+    "native_status",
 ]

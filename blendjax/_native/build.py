@@ -4,6 +4,12 @@ No pybind11 in this environment, so the ABI is plain C (``extern "C"``)
 over ctypes. The shared object is cached next to the package keyed by a
 source hash, so rebuilds happen only when the source changes. Set
 ``BLENDJAX_NO_NATIVE=1`` to force the Python fallbacks.
+
+A failed build falls back to Python (Blender's bundled interpreter may
+have no compiler beside it) an order of magnitude slower, so which one
+loaded is never left to a log line: :func:`native_status` answers for
+this process, and the ``native.loaded`` / ``native.fallbacks`` counters
+ride the producers' telemetry to the consumer.
 """
 
 from __future__ import annotations
@@ -16,6 +22,7 @@ import tempfile
 import threading
 
 from blendjax.utils.logging import get_logger
+from blendjax.utils.metrics import metrics
 
 logger = get_logger("native")
 
@@ -51,6 +58,37 @@ def _build(src_path: str, tag: str):
     return ctypes.CDLL(out)
 
 
+def _load(name: str, tag: str, symbol: str, restype, argtypes):
+    """Resolve entry point ``symbol`` of ``<tag>.cpp`` once per process:
+    the bound ctypes function, or None for the Python fallback. Counts
+    the outcome."""
+    if os.environ.get("BLENDJAX_NO_NATIVE") == "1":
+        return None
+    with _LOCK:
+        if name not in _CACHE:
+            lib = _build(os.path.join(_HERE, tag + ".cpp"), tag)
+            fn = None
+            if lib is not None:
+                fn = getattr(lib, symbol)
+                fn.restype = restype
+                fn.argtypes = argtypes
+            metrics.count("native.loaded" if fn else "native.fallbacks")
+            _CACHE[name] = fn
+        return _CACHE[name]
+
+
+def native_status() -> dict:
+    """``{entry point: True (native) | False (Python fallback)}`` for
+    every entry point this process has resolved so far."""
+    with _LOCK:
+        return {name: fn is not None for name, fn in _CACHE.items()}
+
+
+_U8P = ctypes.POINTER(ctypes.c_uint8)
+_I64 = ctypes.c_int64
+_PTR = ctypes.c_void_p
+
+
 def load_tile_delta():
     """Returns the native changed-tile scan or None.
 
@@ -59,27 +97,11 @@ def load_tile_delta():
     -> count`` (tile-grid bounds restrict the scan; th/tw are the tile
     pixel dims — square tiles pass the same value twice).
     """
-    if os.environ.get("BLENDJAX_NO_NATIVE") == "1":
-        return None
-    with _LOCK:
-        if "tiledelta" not in _CACHE:
-            lib = _build(os.path.join(_HERE, "tiledelta.cpp"), "tiledelta")
-            if lib is None:
-                _CACHE["tiledelta"] = None
-            else:
-                u8p = ctypes.POINTER(ctypes.c_uint8)
-                fn = lib.bjx_tile_delta
-                fn.restype = ctypes.c_int64
-                fn.argtypes = [
-                    u8p, u8p,
-                    ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
-                    ctypes.c_int64, ctypes.c_int64,
-                    ctypes.c_int64, ctypes.c_int64,
-                    ctypes.c_int64, ctypes.c_int64,
-                    ctypes.POINTER(ctypes.c_int32), u8p,
-                ]
-                _CACHE["tiledelta"] = fn
-        return _CACHE["tiledelta"]
+    return _load(
+        "tiledelta", "tiledelta", "bjx_tile_delta",
+        _I64,
+        [_U8P, _U8P] + [_I64] * 9 + [ctypes.POINTER(ctypes.c_int32), _U8P],
+    )
 
 
 def load_palettize():
@@ -88,23 +110,10 @@ def load_palettize():
     ``palettize(px u8[n,c], n, c, cap, palette_out u8[cap,c],
     idx_out u8[n]) -> count | -1``.
     """
-    if os.environ.get("BLENDJAX_NO_NATIVE") == "1":
-        return None
-    with _LOCK:
-        if "palettize" not in _CACHE:
-            lib = _build(os.path.join(_HERE, "tiledelta.cpp"), "tiledelta")
-            if lib is None:
-                _CACHE["palettize"] = None
-            else:
-                u8p = ctypes.POINTER(ctypes.c_uint8)
-                fn = lib.bjx_palettize
-                fn.restype = ctypes.c_int64
-                fn.argtypes = [
-                    u8p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
-                    u8p, u8p,
-                ]
-                _CACHE["palettize"] = fn
-        return _CACHE["palettize"]
+    return _load(
+        "palettize", "tiledelta", "bjx_palettize",
+        _I64, [_U8P, _I64, _I64, _I64, _U8P, _U8P],
+    )
 
 
 def load_tile_delta_palidx():
@@ -116,32 +125,14 @@ def load_tile_delta_palidx():
     count | -1`` — keys/vals/palette/pcount are caller-owned persistent
     stream state.
     """
-    if os.environ.get("BLENDJAX_NO_NATIVE") == "1":
-        return None
-    with _LOCK:
-        if "tiledelta_palidx" not in _CACHE:
-            lib = _build(os.path.join(_HERE, "tiledelta.cpp"), "tiledelta")
-            if lib is None:
-                _CACHE["tiledelta_palidx"] = None
-            else:
-                fn = lib.bjx_tile_delta_palidx
-                fn.restype = ctypes.c_int64
-                # void* buffer args: callers pass cached raw addresses
-                # (ints) instead of re-marshalling POINTER objects per
-                # frame — this is the producer's per-frame hot call.
-                fn.argtypes = [
-                    ctypes.c_void_p, ctypes.c_void_p,
-                    ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
-                    ctypes.c_int64, ctypes.c_int64,
-                    ctypes.c_int64, ctypes.c_int64,
-                    ctypes.c_int64, ctypes.c_int64,
-                    ctypes.c_void_p, ctypes.c_void_p,
-                    ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                    ctypes.c_void_p,
-                    ctypes.c_int64,
-                ]
-                _CACHE["tiledelta_palidx"] = fn
-        return _CACHE["tiledelta_palidx"]
+    # void* buffer args: callers pass cached raw addresses (ints) instead
+    # of re-marshalling POINTER objects per frame — this is the
+    # producer's per-frame hot call.
+    return _load(
+        "tiledelta_palidx", "tiledelta",
+        "bjx_tile_delta_palidx", _I64,
+        [_PTR, _PTR] + [_I64] * 9 + [_PTR] * 6 + [_I64],
+    )
 
 
 def load_render_frame():
@@ -154,23 +145,9 @@ def load_render_frame():
     one FFI crossing (the producer's per-frame hot call; buffer args are
     ``c_void_p`` so callers can pass cached raw addresses).
     """
-    if os.environ.get("BLENDJAX_NO_NATIVE") == "1":
-        return None
-    with _LOCK:
-        if "render_frame" not in _CACHE:
-            lib = _build(os.path.join(_HERE, "rasterizer.cpp"), "rasterizer")
-            if lib is None:
-                _CACHE["render_frame"] = None
-            else:
-                fn = lib.bjx_render_frame
-                fn.restype = None
-                fn.argtypes = [
-                    ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
-                    ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                    ctypes.c_double,
-                    ctypes.c_void_p, ctypes.c_void_p,
-                    ctypes.c_int64, ctypes.c_int64,
-                    ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                ]
-                _CACHE["render_frame"] = fn
-        return _CACHE["render_frame"]
+    return _load(
+        "render_frame", "rasterizer", "bjx_render_frame",
+        None,
+        [_PTR, _PTR, _I64, _PTR, _PTR, _PTR, ctypes.c_double, _PTR, _PTR,
+         _I64, _I64, _PTR, _PTR, _PTR],
+    )

@@ -296,6 +296,10 @@ class ProcessLauncher:
             p for p in env.get("PYTHONPATH", "").split(os.pathsep) if p
         ]
         env["PYTHONPATH"] = os.pathsep.join(dict.fromkeys(parts))
+        # An accelerator belongs to one process, and that is the trainer
+        # that launches us. Producers are JAX-free by design; should one
+        # import JAX anyway, it must not reach for the parent's chip.
+        env["JAX_PLATFORMS"] = "cpu"
 
         # Shared-memory segment lifecycle (blendjax.transport.shm): the
         # launcher owns the unlink for instances it spawned. Producers

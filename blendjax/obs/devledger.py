@@ -58,6 +58,7 @@ __all__ = [
     "RetraceAudit",
     "V5E_PEAK_FLOPS",
     "batch_signature",
+    "chip_peak_flops",
     "default_peak_flops",
     "ledger",
     "measure_model_flops",
@@ -66,28 +67,21 @@ __all__ = [
 
 UNAVAILABLE = "unavailable"
 
-# Peak dense bf16 throughput of one TPU v5e chip (197 TFLOP/s, public
-# spec) — the denominator weather can't move. Lived in bench.py until
-# the ledger became the one home for the cost-model path.
+# Peak dense bf16 throughput of one TPU v5e chip (197 TFLOP/s, Google
+# Cloud documentation "TPU v5e").
 V5E_PEAK_FLOPS = 197e12
 
-#: Known-chip peak dense FLOP/s (bf16 where the chip has it), matched
-#: by substring against ``jax.devices()[0].device_kind.lower()``. The
-#: ``TrainDriver`` MFU gauge defaults its ``peak_flops`` denominator
-#: from this table when the backend is identifiable; an unknown chip
-#: logs once naming the missing knob instead of silently publishing
-#: nothing. Entries are (substring, peak_flops, label) — first match
-#: wins, so more specific substrings come first.
-KNOWN_CHIP_PEAKS = (
-    ("v5 lite", V5E_PEAK_FLOPS, "TPU v5e"),
-    ("v5e", V5E_PEAK_FLOPS, "TPU v5e"),
-    ("v5p", 459e12, "TPU v5p"),
-    ("v6e", 918e12, "TPU v6e"),
-    ("v4", 275e12, "TPU v4"),
-    ("v3", 123e12, "TPU v3"),
-    ("h100", 989e12, "H100"),
-    ("a100", 312e12, "A100"),
-)
+#: Peak dense bf16 FLOP/s of one chip, keyed by ``device_kind`` exactly
+#: as JAX reports it (``jax.devices()[0].device_kind``), with the label
+#: the public spec sheet uses. The ``TrainDriver`` MFU gauge takes its
+#: ``peak_flops`` denominator from here; an accelerator that is not in
+#: the table is an error (:func:`chip_peak_flops`), never a guess.
+CHIP_PEAK_FLOPS = {
+    "TPU v5 lite": (V5E_PEAK_FLOPS, "TPU v5e"),
+    "TPU v6 lite": (918e12, "TPU v6e"),
+    "TPU v4": (275e12, "TPU v4"),
+    "TPU v3": (123e12, "TPU v3"),
+}
 
 #: Collective kinds the HLO parser attributes, in HLO spelling.
 COLLECTIVE_KINDS = (
@@ -251,22 +245,28 @@ def batch_signature(batch: dict) -> tuple:
     return tuple(items)
 
 
-def default_peak_flops() -> tuple | None:
-    """``(peak_flops, chip_label)`` for the current backend from the
-    known-chip table, or ``None`` when the chip is not identifiable
-    (CPU, an unknown accelerator, or no jax at all)."""
+def chip_peak_flops(device_kind: str) -> tuple:
+    """``(peak_flops, chip_label)`` for a ``device_kind``; raises
+    ``KeyError`` naming the kind when the table does not hold it."""
     try:
-        import jax
+        return CHIP_PEAK_FLOPS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no peak FLOP/s on record for device_kind {device_kind!r} — "
+            "add it to blendjax.obs.devledger.CHIP_PEAK_FLOPS with its "
+            f"source (known: {sorted(CHIP_PEAK_FLOPS)})"
+        ) from None
 
-        if jax.default_backend() not in ("tpu", "gpu"):
-            return None
-        kind = (jax.devices()[0].device_kind or "").lower()
-    except Exception:
+
+def default_peak_flops() -> tuple | None:
+    """``(peak_flops, chip_label)`` of the chip this process runs on, or
+    ``None`` on the CPU backend (no chip, no utilization). An accelerator
+    missing from :data:`CHIP_PEAK_FLOPS` raises (:func:`chip_peak_flops`)."""
+    import jax
+
+    if jax.default_backend() == "cpu":
         return None
-    for sub, peak, label in KNOWN_CHIP_PEAKS:
-        if sub in kind:
-            return peak, label
-    return None
+    return chip_peak_flops(jax.devices()[0].device_kind)
 
 
 # -- the cost-model FLOPs probe (moved here from bench.py) --------------------

@@ -302,8 +302,7 @@ def diagnose(
             f"throttle_wait+place share="
             f"{shares['feed.throttle_wait'] + shares['feed.place']:.0%}: "
             "host->device transfer is the wall",
-            "shrink wire bytes (tile/pal encoding), raise chunk, or "
-            "check link weather",
+            "shrink wire bytes (tile/pal encoding) or raise chunk",
             shares,
         )
 

@@ -1,21 +1,8 @@
-"""Image preprocessing ops.
-
-``uint8_gamma_normalize`` is the hot-path op — every streamed frame goes
-uint8 -> normalized compute dtype (+ optional gamma). It has two
-implementations:
-
-- a plain jnp version XLA fuses into the consuming op, and
-- a Pallas TPU kernel (``_pallas_gamma_normalize``) demonstrating the
-  kernel path for ops XLA can't fuse: processes the image as 2D tiles in
-  VMEM, one grid row per image row-block (guide:
-  /opt/skills/guides/pallas_guide.md "Minimal Kernel"/"Grid and Block
-  Specifications"). On non-TPU backends it runs in interpreter mode so
-  tests stay hermetic.
-"""
+"""Image preprocessing ops: uint8 -> normalized compute dtype (+ optional
+gamma) and flip augmentation, all plain jnp that XLA fuses into the
+consuming op."""
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -58,51 +45,7 @@ def random_flip(rng, x, axis: int = 2):
     return jnp.where(bits.reshape(shape), flipped, x)
 
 
-# -- pallas kernel ----------------------------------------------------------
-
-
-def _gamma_kernel(x_ref, o_ref, *, inv_gamma: float, scale: float):
-    x = x_ref[:].astype(jnp.float32) * scale  # uint8 -> [0,1]
-    y = jnp.power(x, inv_gamma)
-    o_ref[:] = y.astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("gamma", "dtype", "interpret"))
-def _pallas_gamma_normalize(x, gamma: float = 2.2, dtype=jnp.float32,
-                            interpret: bool = False):
-    from jax.experimental import pallas as pl
-
-    b, h, w, c = x.shape
-    x2 = x.reshape(b * h, w * c)  # 2D layout for (sublane, lane) tiling
-    # Largest divisor of the row count <= 256: keeps blocks within VMEM for
-    # any resolution (worst case degrades to single-row blocks).
-    rows = b * h
-    block_rows = max(d for d in range(1, min(256, rows) + 1) if rows % d == 0)
-    grid = (rows // block_rows,)
-    out = pl.pallas_call(
-        functools.partial(
-            _gamma_kernel, inv_gamma=1.0 / gamma, scale=1.0 / 255.0
-        ),
-        out_shape=jax.ShapeDtypeStruct(x2.shape, dtype),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, w * c), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_rows, w * c), lambda i: (i, 0)),
-        interpret=interpret,
-    )(x2)
-    return out.reshape(b, h, w, c)
-
-
-def uint8_gamma_normalize(x, gamma: float = 2.2, dtype=jnp.float32,
-                          use_pallas: bool | None = None):
-    """uint8 NHWC -> gamma-corrected [0,1] image in ``dtype``.
-
-    ``use_pallas=None`` auto-selects: the Pallas kernel on TPU, fused jnp
-    elsewhere (Pallas interpret mode stays available for testing).
-    """
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if use_pallas:
-        return _pallas_gamma_normalize(x, gamma=gamma, dtype=dtype)
-    return gamma_correct(normalize_uint8(x, jnp.float32)).astype(dtype)
+def uint8_gamma_normalize(x, gamma: float = 2.2, dtype=jnp.float32):
+    """uint8 NHWC -> gamma-corrected [0,1] image in ``dtype`` (plain jnp;
+    XLA fuses it into the consuming op)."""
+    return gamma_correct(normalize_uint8(x, jnp.float32), gamma).astype(dtype)

@@ -509,15 +509,17 @@ def _palettize_flat(flat: np.ndarray, max_colors: int):
     return idx, pal, count
 
 
-def palettize_tiles(tiles: np.ndarray, max_colors: int = 256):
+def palettize_tiles(tiles: np.ndarray, max_colors: int = 256,
+                    min_bits: int = 2):
     """Try to palette-compress a packed tile array (B, K, t, t, C).
 
     Returns ``(packed, palette, bits)`` — ``packed`` is
     (B, K, t*t/4 | t*t/2 | t*t) uint8 for ``bits`` 2/4/8 (chosen by the
-    batch's distinct-color count: <=4 / <=16 / <=256), ``palette`` is
-    (4|16|256, C) zero-padded — or ``None`` when the tiles hold more
-    than ``max_colors`` distinct colors (ship raw instead). Runs as one
-    native C pass when available; numpy fallback.
+    batch's distinct-color count: <=4 / <=16 / <=256, and never narrower
+    than ``min_bits``), ``palette`` is (4|16|256, C) zero-padded — or
+    ``None`` when the tiles hold more than ``max_colors`` distinct
+    colors (ship raw instead). Runs as one native C pass when
+    available; numpy fallback.
     """
     max_colors = min(int(max_colors), 256)  # uint8 indices; native tables
     b, k, th, tw, c = tiles.shape
@@ -527,12 +529,12 @@ def palettize_tiles(tiles: np.ndarray, max_colors: int = 256):
     if out is None:
         return None
     idx, pal, count = out
-    if count <= 4 and tt % 4 == 0:
+    if count <= 4 and tt % 4 == 0 and min_bits <= 2:
         pal4c = np.zeros((4, c), np.uint8)
         pal4c[: min(len(pal), 4)] = pal[:4]
         packed = pack_palette_indices(idx, 2).reshape(b, k, tt // 4)
         return packed, pal4c, 2
-    if count <= 16 and tt % 2 == 0:
+    if count <= 16 and tt % 2 == 0 and min_bits <= 4:
         pal16 = np.zeros((16, c), np.uint8)
         pal16[: min(len(pal), 16)] = pal[:16]
         packed = pack_palette_indices(idx, 4).reshape(b, k, tt // 2)
@@ -587,8 +589,7 @@ def _lut_expand(packed, palette, bits: int):
     """Device-side byte-LUT palette expand: ONE gather per packed byte
     through a 256-entry LUT (byte value -> ``8/bits`` pixels x C bytes,
     built on device from the palette) instead of bit-unpack + per-pixel
-    gather. Bit-exact by construction; measured 1.2x faster than the
-    unpack+gather chain on a v5e (scripts/exp_lut_expand.py).
+    gather. Bit-exact by construction.
 
     ``packed``: (..., M) uint8; ``palette``: (cap, C). Returns
     (..., M, (8/bits)*C) uint8 — the caller reshapes (packed bytes hold
@@ -923,11 +924,10 @@ def expand_rle_fields(fields: dict, rle_groups) -> dict:
 
 # -- packed single-transfer form --------------------------------------------
 #
-# On remote/tunneled device hosts every host->device op pays a round trip,
-# so a batch spread over five arrays (idx, tiles, labels, ids, ...) costs
-# 5x the latency of one. pack_fields/unpack_fields collapse a batch dict
-# into ONE uint8 buffer + a static spec; the unpack runs under jit on
-# device (slice + bitcast), so the whole batch rides a single device_put.
+# pack_fields/unpack_fields collapse a batch dict (idx, tiles, labels,
+# ids, ...) into ONE uint8 buffer + a static spec; the unpack runs under
+# jit on device (slice + bitcast), so the whole batch rides a single
+# device_put.
 
 
 # 64-bit payloads are value-cast to 32 bits on the host before packing —
@@ -1025,8 +1025,7 @@ def decode_packed_superbatch(packed, refs, spec, names, geoms,
 
     Shared by :class:`blendjax.data.TileStreamDecoder` (decode-then-step)
     and :func:`blendjax.train.make_fused_tile_step` (decode fused into
-    the train jit: one device call per K batches instead of two, which
-    matters on high-latency device links).
+    the train jit: one device call per K batches instead of two).
     """
     import jax
 
@@ -1189,8 +1188,7 @@ def _pallas_decode_spatial(ref_tiles, idx, tiles, shape,
     the output and gathers either the changed tile that landed there or
     the reference block — so the slot buffer, its reference-broadcast
     init pass, and the tile->frame transpose pass of
-    :func:`_pallas_decode_scatter` all disappear (measured as the two
-    largest HBM terms of the decode chain; scripts/diagnose_decode.py).
+    :func:`_pallas_decode_scatter` all disappear.
 
     The tile->slot map inverts on device first (one tiny scatter over
     (B, GH*GW) int32): ``inv[b, p]`` is the row of ``tiles`` covering
@@ -1268,6 +1266,12 @@ def _pallas_decode_spatial(ref_tiles, idx, tiles, shape,
     return out.reshape(b, h, w, c)
 
 
+#: Suffixes of the ``tiles.decode_path.*`` trace-time counters
+#: :func:`decode_tile_delta` bumps: which implementation a trace took,
+#: plus ``shard_map`` when the kernel was wrapped for a multi-device mesh.
+DECODE_PATHS = ("pallas_spatial", "pallas_scatter", "xla_scatter", "shard_map")
+
+
 def decode_tile_delta(ref_tiles, idx, tiles, shape, use_pallas=None,
                       mesh=None, data_axis: str = "data"):
     """Reconstruct exact full frames on device.
@@ -1292,27 +1296,36 @@ def decode_tile_delta(ref_tiles, idx, tiles, shape, use_pallas=None,
     tiles the flagship scene streams), else the slot scatter
     (:func:`_pallas_decode_scatter`). Channel-sliced tiles (``Ct < C``,
     e.g. alpha slicing) stay kernel-eligible: the missing channels are
-    restored from the reference by one on-device gather first. On a
-    multi-device mesh pass ``mesh`` (with ``data_axis`` naming its batch
-    axis): the kernel is wrapped in ``shard_map`` over that axis — each
-    device decodes its local batch shard against the replicated
-    reference, so the fast path survives scale-out (the kernel alone is
-    not GSPMD-partitionable). Without ``mesh`` on multi-device, or when
-    B doesn't divide by the axis size, auto-select falls back to the
-    vmap'd XLA scatter, which partitions like any other op. Off TPU the
-    kernels run in interpreter mode (what the virtual-mesh tests use).
+    restored from the reference by one on-device gather first.
+
+    ``mesh`` says where the program runs. ``None`` (or a one-device
+    mesh) means a single-device program and the kernel is called bare,
+    however many devices the host has; a partitioned program that
+    reaches it that way is refused at lowering, not rerouted. A
+    multi-device ``mesh`` wraps the kernel in ``shard_map`` (the kernel
+    alone is not GSPMD-partitionable): over ``data_axis`` when the mesh
+    has it — each device decodes its local batch shard against the
+    replicated reference — and replicated otherwise. Only when B does
+    not divide by the axis size does auto-select take the vmap'd XLA
+    scatter, which partitions like any other op. Off TPU the kernels
+    run in interpreter mode (what the virtual-mesh tests use).
+
+    The path traced is counted under ``tiles.decode_path.<path>``
+    (:data:`DECODE_PATHS`; once per trace, not per execution).
     """
     import jax
+
+    from blendjax.utils.metrics import metrics
 
     h, w, c = (int(s) for s in shape)
     th, tw = tiles.shape[-3], tiles.shape[-2]
     ct = tiles.shape[-1]
     gh, gw = tile_grid((h, w, c), (th, tw))
     b = idx.shape[0]
+    multi_device = mesh is not None and mesh.size > 1
     n_axis = (
-        int(np.prod([mesh.shape[a] for a in (data_axis,)]))
-        if mesh is not None and data_axis in getattr(mesh, "shape", {})
-        else 1
+        int(mesh.shape[data_axis])
+        if multi_device and data_axis in mesh.shape else 1
     )
     eligible_spatial = (tw * c) % 128 == 0 and th % 8 == 0
     eligible = eligible_spatial or (th * tw * c) % 1024 == 0
@@ -1320,10 +1333,7 @@ def decode_tile_delta(ref_tiles, idx, tiles, shape, use_pallas=None,
         use_pallas = (
             jax.default_backend() == "tpu"
             and eligible
-            and (
-                jax.device_count() == 1
-                or (mesh is not None and n_axis > 1 and b % n_axis == 0)
-            )
+            and b % n_axis == 0
         )
     if use_pallas and not eligible:
         # explicit request for a kernel that can't lower: fail loudly
@@ -1335,6 +1345,8 @@ def decode_tile_delta(ref_tiles, idx, tiles, shape, use_pallas=None,
         )
     if use_pallas:
         interpret = jax.default_backend() != "tpu"
+        kernel = "pallas_spatial" if eligible_spatial else "pallas_scatter"
+        metrics.count(f"tiles.decode_path.{kernel}")
 
         if ct < c:
             # Channel-sliced stream (e.g. alpha slicing): the producer
@@ -1363,23 +1375,24 @@ def decode_tile_delta(ref_tiles, idx, tiles, shape, use_pallas=None,
                     0, 1, 3, 2, 4, 5
                 ).reshape(-1, h, w, c)
 
-        if mesh is not None and n_axis > 1 and b % n_axis == 0:
-            # Partition over the batch: each device runs the kernel on
-            # its local shard against the replicated reference (the
-            # kernel alone is not GSPMD-partitionable).
+        if multi_device:
             from jax.sharding import PartitionSpec as P
 
             from blendjax.parallel.collectives import _shard_map
 
+            batch_spec = P(data_axis) if n_axis > 1 else P()
             # check=False: pallas_call's out_shape carries no varying-
             # mesh-axes annotation, which the VMA checker requires.
             decode_fn = _shard_map(
                 decode_fn, mesh,
-                in_specs=(P(), P(data_axis), P(data_axis)),
-                out_specs=P(data_axis),
+                in_specs=(P(), batch_spec, batch_spec),
+                out_specs=batch_spec,
                 check=False,
             )
+            metrics.count("tiles.decode_path.shard_map")
         return decode_fn(ref_tiles, idx, tiles)
+
+    metrics.count("tiles.decode_path.xla_scatter")
 
     def one(i, tl):
         if ct < c:

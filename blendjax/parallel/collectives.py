@@ -12,31 +12,17 @@ from __future__ import annotations
 import functools
 
 
-def _resolve_shard_map():
-    """``shard_map`` across jax versions: top-level ``jax.shard_map`` on
-    current releases, ``jax.experimental.shard_map.shard_map`` before
-    the promotion. ONE resolver for every SPMD region in the repo (ring,
-    ulysses, pipeline parallel, the sharded tile decode)."""
+def _shard_map(fn, mesh, in_specs, out_specs, check: bool = True):
+    """``jax.shard_map`` for every SPMD region in the repo (ring,
+    ulysses, pipeline parallel, the sharded tile decode). ``check=False``
+    turns off the varying-manual-axes checker, which cannot infer e.g.
+    the replication of a tiled all_gather's output."""
     import jax
 
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    return sm
-
-
-def _shard_map(fn, mesh, in_specs, out_specs, check: bool = True):
-    sm = _resolve_shard_map()
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    if not check:
-        # Replication of e.g. tiled all_gather output is not statically
-        # inferred by the varying-manual-axes checker; the flag is named
-        # check_vma on current JAX, check_rep on older releases.
-        try:
-            return sm(fn, check_vma=False, **kwargs)
-        except TypeError:
-            return sm(fn, check_rep=False, **kwargs)
-    return sm(fn, **kwargs)
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check,
+    )
 
 
 def all_reduce_sum(x, mesh, axis: str = "data"):

@@ -87,13 +87,9 @@ def _pipeline_local(params, x, *, stage_fn, axis_name: str, n_stages: int,
     out0 = jnp.zeros((m_total,) + out_shape.shape, out_shape.dtype)
     # Constant carries must be marked device-varying for shard_map's VMA
     # type checking (same dance as ring.py).
-    if hasattr(jax.lax, "pcast"):
-        buf0, out0 = (
-            jax.lax.pcast(a, vary_axes, to="varying")
-            for a in (buf0, out0)
-        )
-    elif hasattr(jax.lax, "pvary"):
-        buf0, out0 = (jax.lax.pvary(a, vary_axes) for a in (buf0, out0))
+    buf0, out0 = (
+        jax.lax.pcast(a, vary_axes, to="varying") for a in (buf0, out0)
+    )
 
     n_steps = m_total + n_stages - 1
     (_, out), _ = jax.lax.scan(

@@ -13,6 +13,10 @@ Wire-size behaviors, all transparent to the consumer:
   shape, and each shape costs one jit compilation of the consumer's
   decode — so the capacity is a per-stream high-water mark (with ~30%
   initial headroom) that only grows on overflow.
+- **Sticky index width**: the palette index width (2/4/8 bits) is a wire
+  shape too — a batch that needs a wider one than its neighbours breaks
+  the consumer's chunk group and compiles its own program — so it is a
+  high-water mark as well, starting at ``palette_bits``.
 - **Alpha slicing**: when every frame's alpha channel matches the
   reference's (verified per batch), only RGB crosses the wire and the
   consumer restores alpha from the reference — still bit-exact.
@@ -80,15 +84,25 @@ class TileBatchPublisher:
     wire/array shape — one consumer decode compilation, and a chunk-group
     boundary — so a fleet of producers streaming the same scene should
     share an explicit capacity rather than each settling its own
-    high-water mark.
+    high-water mark. ``palette_bits`` (2, 4 or 8) does the same for the
+    palette index width: the narrowest the stream will ship, growing
+    (and staying grown) when a frame needs more colors. A fleet pins it
+    to what its scene's busiest frame needs: one frame in a few hundred
+    of the cube scene holds a fifth color, and at the default 2 each
+    such batch would cost the consumer a short chunk group and a
+    compile.
     """
 
     def __init__(self, publisher, ref: np.ndarray, batch_size: int,
                  tile=TILE, field: str = "image",
                  alpha_slice: bool = True, ref_interval: int = 0,
-                 palette: bool = True, capacity: int | None = None):
+                 palette: bool = True, capacity: int | None = None,
+                 palette_bits: int = 2):
         if batch_size < 1:
             raise ValueError("batch_size must be >= 1")
+        if palette_bits not in (2, 4, 8):
+            raise ValueError(f"palette_bits must be 2, 4 or 8, got {palette_bits}")
+        self._palette_bits = int(palette_bits)  # sticky: only grows
         self.publisher = publisher
         self.batch_size = int(batch_size)
         self.field = field
@@ -344,11 +358,13 @@ class TileBatchPublisher:
             if cmax <= 4 and tt % 4 == 0:
                 # four 2-bit indices per byte (flat-shaded frames often
                 # hold <=4 colors: background + a few faces)
-                bits, cap_colors = 2, 4
+                needed = 2
             elif cmax <= 16 and tt % 2 == 0:
-                bits, cap_colors = 4, 16
+                needed = 4
             else:
-                bits, cap_colors = 8, 256
+                needed = 8
+            bits = self._palette_bits = max(self._palette_bits, needed)
+            cap_colors = 1 << bits
             suffix = TILEPAL_SUFFIXES[bits]
             # fresh allocation either way: pal_idx is a reused batch
             # array and publish hands buffers to the IO thread by ref
@@ -411,10 +427,14 @@ class TileBatchPublisher:
                 h, w, c, (self.th, self.tw)
             ),
         }
-        compressed = palettize_tiles(tiles) if self.palette else None
+        compressed = (
+            palettize_tiles(tiles, min_bits=self._palette_bits)
+            if self.palette else None
+        )
         if compressed is not None:
             self._palette_misses = 0
             packed, pal, bits = compressed
+            self._palette_bits = bits
             suffix = TILEPAL_SUFFIXES[bits]
             msg[self.field + suffix] = packed
             msg[self.field + PALETTE_SUFFIX] = pal

@@ -124,8 +124,7 @@ def moe_per_token_reference(params, x) -> np.ndarray:
     idx = probs.argmax(-1)
     gate = probs.max(-1)
     # Gather each token's expert weights, then ONE batched pass (a
-    # per-token Python loop would pay one device dispatch per token —
-    # seconds of pure latency on tunneled backends).
+    # per-token Python loop would pay one device dispatch per token).
     w1 = np.asarray(params["expert_wi"])[idx]   # (N, C, H)
     b1 = np.asarray(params["expert_bi"])[idx]   # (N, H)
     w2 = np.asarray(params["expert_wo"])[idx]   # (N, H, C)

@@ -58,44 +58,46 @@ _MANIFEST = "aot_manifest.json"
 
 # -- persistent cache wiring --------------------------------------------------
 
-def configure_compilation_cache(cache_dir: str) -> bool:
-    """Point JAX's persistent compilation cache at ``cache_dir``.
+#: Where the compile cache goes when neither the environment nor the
+#: caller names a place: ``<checkout>/.xla_cache`` (git-ignored). A fixed
+#: path, because the path is part of what a cache entry is looked up by.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".xla_cache",
+)
 
-    Zeroes the min-compile-time / min-entry-size thresholds so the small
-    CPU-sized steps the CI bench compiles are persisted too (the defaults
-    only cache "expensive" compiles).  Each knob is applied independently
-    and version-drift-tolerantly: an option a given JAX build does not know
-    is skipped, not fatal.  Returns True when the cache directory itself
-    was accepted.
+
+def configure_compilation_cache(cache_dir: str | None = None) -> str:
+    """Turn JAX's persistent compilation cache on and return its directory.
+
+    The one place the repo decides where compiled programs are kept:
+    ``JAX_COMPILATION_CACHE_DIR`` wins whenever it is set (so a machine
+    can place the cache from outside, and no other directory is ever set
+    in code); otherwise ``cache_dir``; otherwise :data:`DEFAULT_CACHE_DIR`.
+
+    Zeroes the min-compile-time / min-entry-size thresholds so small
+    steps are persisted too (the defaults only cache "expensive"
+    compiles), and resets the cache so the settings take effect even
+    after something already compiled in this process.
     """
-    os.makedirs(cache_dir, exist_ok=True)
-    ok = False
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        ok = True
-    except Exception as e:  # pragma: no cover - depends on jax build
-        logger.warning("persistent compilation cache unavailable: %s", e)
-    for knob, value in (
-        ("jax_persistent_cache_min_compile_time_secs", 0),
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-        # without this the CPU backend never writes cache entries at all
-        ("jax_persistent_cache_enable_xla_caches", "all"),
-    ):
-        try:
-            jax.config.update(knob, value)
-        except Exception:  # pragma: no cover - knob renamed/absent
-            pass
+    from jax.experimental.compilation_cache import compilation_cache
+
+    resolved = (
+        os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        or cache_dir
+        or DEFAULT_CACHE_DIR
+    )
+    os.makedirs(resolved, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", resolved)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # without this the CPU backend never writes cache entries at all
+    jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
     # JAX latches the cache state on the first compile of the process: if
     # anything compiled before the dir was set (state init always does),
-    # the "no cache" decision sticks and every later knob is ignored.
-    # Resetting re-reads the config on next use.
-    try:
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except Exception:  # pragma: no cover - private module moved
-        pass
-    return ok
+    # the "no cache" decision sticks. Resetting re-reads the config.
+    compilation_cache.reset_cache()
+    return resolved
 
 
 def cache_key(
@@ -277,8 +279,8 @@ class AotStepSet:
     ``jit(...).lower(...).compile()`` does **not** seed the jit wrapper's
     own dispatch cache, so holding the compiled executables and dispatching
     to them directly is what actually makes step 0 instant.  The wrapped
-    jit remains the safety net for shapes outside the ladder (and for any
-    compiled-call failure): slower, never wrong.
+    jit takes shapes outside the ladder, each counted under
+    ``train.aot_fallbacks``.
     """
 
     def __init__(self, step, compiled: dict, compile_ms: float,
@@ -289,7 +291,6 @@ class AotStepSet:
         self.cache_hits = cache_hits
         self.cache_misses = cache_misses
         self.ledger_entries: list = []
-        self._warned: set = set()
 
     @property
     def signatures(self) -> tuple:
@@ -297,20 +298,12 @@ class AotStepSet:
 
     def __call__(self, state, batch):
         fields = {k: v for k, v in batch.items() if _is_batch_array(k, v)}
-        sig = _signature(fields)
-        exe = self._compiled.get(sig)
+        exe = self._compiled.get(_signature(fields))
         if exe is not None:
-            try:
-                return exe(state, fields)
-            except Exception:  # pragma: no cover - layout drift safety net
-                if sig not in self._warned:
-                    self._warned.add(sig)
-                    logger.warning(
-                        "aot executable rejected the batch; "
-                        "falling back to jit", exc_info=True,
-                    )
-        else:
-            metrics.count("train.aot_fallbacks")
+            # an executable that rejects a batch of its own signature
+            # (layout drift) is a fault: it raises, it is not rerouted
+            return exe(state, fields)
+        metrics.count("train.aot_fallbacks")
         return self._step(state, fields)
 
 
@@ -331,7 +324,10 @@ def build_aot_step(
     ``step`` must be a ``jax.jit`` wrapper (lowerable); ``state`` the
     concrete train state (its shapes/dtypes/shardings become the abstract
     state); ``example_batch`` a concrete full-size batch dict.  With
-    ``cache_dir`` set, the persistent compilation cache is configured and
+    ``cache_dir`` set, the persistent compilation cache is configured
+    (:func:`configure_compilation_cache` — XLA's entries go where
+    ``JAX_COMPILATION_CACHE_DIR`` says when that is set; the manifest
+    always lives in ``cache_dir``) and
     the keyed manifest decides hit/miss per signature — a warm manifest
     entry means XLA will be served from disk, and ``train.aot_cache_hits``
     counts it; a cold one counts ``train.aot_cache_misses``.

@@ -90,7 +90,7 @@ class TrainDriver:
     ``flops_per_image`` (hand-fed, or derived by :meth:`build` from
     the device ledger's ``compiled.cost_analysis()`` entries —
     :mod:`blendjax.obs.devledger`) and ``peak_flops`` (explicit, or
-    defaulted from the known-chip peaks table), retirements
+    defaulted from the chip peaks table), retirements
     additionally maintain a live ``train.mfu`` gauge (retired
     images/s x flops_per_image / peak_flops over ~1 s windows), so
     MFU is an always-on run metric the SLO watchdog can bound, not
@@ -173,12 +173,12 @@ class TrainDriver:
         self.startup_ms: float | None = None
 
     def _resolve_peak_flops(self) -> None:
-        """The ``train.mfu`` gauge needs BOTH knobs; historically
-        ``flops_per_image`` without ``peak_flops`` silently published
-        nothing. Now the denominator defaults from the known-chip peaks
-        table (x ``self.chips`` on mesh drivers) when the backend is
-        identifiable, and otherwise the missing knob is named once at
-        build time instead of the gauge vanishing without a word."""
+        """The ``train.mfu`` gauge needs BOTH knobs: the denominator
+        defaults from the chip peaks table (x ``self.chips`` on mesh
+        drivers). An accelerator the table does not hold raises
+        (:func:`blendjax.obs.devledger.chip_peak_flops`); on the CPU
+        backend there is no chip, and the gauge stays off with one log
+        line naming the missing knob."""
         if not self.flops_per_image or self.peak_flops:
             return
         chips = max(1, int(getattr(self, "chips", 1) or 1))
@@ -189,15 +189,15 @@ class TrainDriver:
             _log_once(
                 logger.info,
                 "train.mfu: peak_flops defaulted to %.4g "
-                "(%s known-chip peak x %d chip(s))",
+                "(%s peak x %d chip(s))",
                 self.peak_flops, label, chips,
             )
         else:
             _log_once(
                 logger.warning,
                 "train.mfu gauge disabled: flops_per_image is set but "
-                "peak_flops=None and this backend's chip is not in the "
-                "known-peaks table — pass peak_flops= to the driver",
+                "peak_flops=None and the CPU backend has no chip peak — "
+                "pass peak_flops= to the driver",
             )
 
     @classmethod

@@ -144,6 +144,7 @@ def make_mesh_fused_step(
         augment_rng=augment_rng,
         state_sharding=_state_jit_shardings(state, mesh),
         superbatch_constraint=_pin_batch_axis,
+        mesh=mesh, data_axis=data_axis,
     )
 
 

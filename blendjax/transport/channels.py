@@ -241,8 +241,8 @@ class DataPublisherSocket(_Channel):
             self._shm_slots = 0
         # Per-publisher wire compression (tensor codec only): level > 0
         # ships large array frames as zlib "ndz" entries. Trades producer
-        # CPU for wire bytes — the right trade on tunneled/cross-host
-        # links, the wrong one on ipc/loopback (docs/performance.md).
+        # CPU for wire bytes — the right trade across hosts, the wrong
+        # one on ipc/loopback (docs/performance.md).
         self.compress_level = int(compress_level)
         self.compress_min_bytes = int(compress_min_bytes)
         # Run-length "ndr" wire frames (docs/wire-protocol.md): cheap

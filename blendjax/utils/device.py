@@ -4,13 +4,7 @@ from __future__ import annotations
 
 
 def transfer_done(arr) -> bool:
-    """Non-blocking readiness poll for an in-flight device array; False
-    when the backend can't say (lazy-flushing remote runtimes may never
-    locally report ready — callers keep a bounded blocking wait as the
-    honest fallback). ONE definition for the feeder's throttle window
-    and the TrainDriver's dispatch ring, so their retirement semantics
-    cannot diverge."""
-    try:
-        return bool(arr.is_ready())
-    except Exception:
-        return False
+    """Non-blocking readiness poll for an in-flight device array. ONE
+    definition for the feeder's throttle window and the TrainDriver's
+    dispatch ring, so their retirement semantics cannot diverge."""
+    return bool(arr.is_ready())

@@ -20,6 +20,9 @@ Usage flags (passed via ``instance_args``):
   --tile T [TW]    tile dims for --encoding tile (default 16 32); two
                    values give rectangular (rows, cols) tiles — (16, 32)
                    at C=4 unlocks the consumer's direct-spatial decode
+  --tile-capacity N, --tile-pal-bits {2,4,8}
+                   pin the tile stream's wire shape (changed-tile slots
+                   per frame, palette index width) across a fleet
 """
 
 from __future__ import annotations
@@ -65,6 +68,14 @@ def main() -> None:
         "unbroken chunk groups). 0 = per-stream high-water mark.",
     )
     parser.add_argument(
+        "--tile-pal-bits", type=int, choices=[2, 4, 8], default=2,
+        help="narrowest palette index width the tile stream ships (it "
+        "grows, and stays grown, when a frame needs more colors). Like "
+        "--tile-capacity it pins one wire shape across a fleet: about "
+        "one cube frame in 200 holds a fifth color, so 4 keeps every "
+        "batch the same shape.",
+    )
+    parser.add_argument(
         "--trace-every", type=int, default=64,
         help="stamp every Nth published message with a sampled "
         "distributed-trace context (blendjax.obs.trace; "
@@ -98,6 +109,7 @@ def main() -> None:
             pub, scene.background_image(), opts.batch, tile=tile,
             alpha_slice=not opts.tile_rgba, ref_interval=opts.ref_interval,
             capacity=opts.tile_capacity or None,
+            palette_bits=opts.tile_pal_bits,
         )
         framebuf = np.empty((h, w, 4), np.uint8)
         flush = tiles.flush  # ship trailing frames of a partial batch

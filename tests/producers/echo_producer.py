@@ -5,6 +5,7 @@ Mirrors the reference test fixture ``tests/blender/launcher.blend.py:3-9``
 assert on), but runs headless — no Blender.
 """
 
+import os
 import sys
 import time
 
@@ -21,6 +22,7 @@ def main():
         btseed=args.btseed,
         sockets=args.btsockets,
         remainder=remainder,
+        jax_platforms=os.environ.get("JAX_PLATFORMS"),
     )
     # Stay alive briefly so the consumer can connect and drain.
     time.sleep(10)

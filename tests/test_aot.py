@@ -182,40 +182,57 @@ def test_aot_compile_span_recorded():
 # -- persistent cache manifest ------------------------------------------------
 
 
-@pytest.fixture
-def _cache_config_guard():
-    """configure_compilation_cache mutates process-global jax.config (by
-    design — it is a process-level lever); restore it so the rest of the
-    suite compiles exactly as it would without these tests."""
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.mark.usefixtures("compile_cache_config_guard")
+@pytest.mark.parametrize(
+    "env, explicit, expect",
+    [
+        ("env", "explicit", "env"),    # the environment wins over the caller
+        ("env", None, "env"),
+        (None, "explicit", "explicit"),
+        (None, None, os.path.join(_CHECKOUT, ".xla_cache")),
+    ],
+)
+def test_cache_dir_resolution(monkeypatch, tmp_path, env, explicit, expect):
+    """One resolver: JAX_COMPILATION_CACHE_DIR, else the caller's
+    directory, else <checkout>/.xla_cache — and with the variable set no
+    other directory is set or created."""
     import jax
 
-    keys = (
-        "jax_compilation_cache_dir",
-        "jax_persistent_cache_min_compile_time_secs",
-        "jax_persistent_cache_min_entry_size_bytes",
-        "jax_persistent_cache_enable_xla_caches",
+    from blendjax.train import configure_compilation_cache
+
+    if env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / env))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    got = configure_compilation_cache(
+        str(tmp_path / explicit) if explicit else None
     )
-    saved = {}
-    for k in keys:
-        try:
-            saved[k] = getattr(jax.config, k)
-        except AttributeError:
-            pass
-    yield
-    for k, v in saved.items():
-        try:
-            jax.config.update(k, v)
-        except Exception:
-            pass
-    try:
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except Exception:
-        pass
+    want = expect if os.path.isabs(expect) else str(tmp_path / expect)
+    assert got == want == jax.config.jax_compilation_cache_dir
+    if env and explicit:
+        assert not (tmp_path / explicit).exists()
 
 
-@pytest.mark.usefixtures("_cache_config_guard")
+@pytest.mark.usefixtures("compile_cache_config_guard")
+def test_aot_build_keeps_xla_cache_where_env_says(monkeypatch, tmp_path):
+    """aot_cache_dir names the manifest's home only: with the variable
+    set, XLA's entries go to the environment's directory."""
+    import jax
+
+    env_dir = tmp_path / "env"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(env_dir))
+    full = _batch()
+    build_aot_step(make_supervised_step(donate=False), _state(full), full,
+                   buckets=(8,), cache_dir=str(tmp_path / "aot"), key="k")
+    assert jax.config.jax_compilation_cache_dir == str(env_dir)
+    assert os.listdir(tmp_path / "aot") == ["aot_manifest.json"]
+    assert os.listdir(env_dir)
+
+
+@pytest.mark.usefixtures("compile_cache_config_guard")
 def test_manifest_cold_then_warm_counters(tmp_path):
     cache = str(tmp_path / "xla-cache")
     full = _batch()
@@ -235,7 +252,7 @@ def test_manifest_cold_then_warm_counters(tmp_path):
     assert os.path.exists(os.path.join(cache, "aot_manifest.json"))
 
 
-@pytest.mark.usefixtures("_cache_config_guard")
+@pytest.mark.usefixtures("compile_cache_config_guard")
 def test_manifest_key_isolation(tmp_path):
     """A different cache key (different model/ladder/mesh) never reads
     another key's manifest entries as warm."""

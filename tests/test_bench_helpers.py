@@ -1,5 +1,5 @@
-"""Guards for bench.py's measurement helpers (they feed BENCH_r*.json,
-the judged record — a silent mis-measurement is worse than a crash)."""
+"""Guards for bench.py's measurement helpers (a silent mis-measurement
+is worse than a crash)."""
 
 import numpy as np
 import pytest
@@ -41,34 +41,6 @@ def test_model_flops_matches_analytic_count():
     assert 0.7 * analytic < got < 1.3 * analytic, (got, analytic)
 
 
-def test_ceiling_ratio_row_publication_rules():
-    """utilization_vs_ceiling publishes a number ONLY when headline and
-    ceiling share fit windows and the ratio is sane — the r4 record
-    published 1.577 from a cross-window comparison (VERDICT r4 #1)."""
-    import bench
-
-    fitc = {"img_s": 600.0, "fit_window": True}
-    assert bench.ceiling_ratio_row(570.0, fitc, True) == 0.95
-    # live "beating" the ceiling beyond noise: windows weren't
-    # equivalent after all — invalid, uncomparable number preserved
-    r = bench.ceiling_ratio_row(700.0, fitc, True)
-    assert r["invalid"] == "window_mismatch"
-    assert r["uncomparable_ratio"] == 1.167
-    # unfit headline / unfit ceiling / capped ceiling -> weather-invalid
-    assert (
-        bench.ceiling_ratio_row(570.0, fitc, False)["invalid"] == "weather"
-    )
-    assert bench.ceiling_ratio_row(
-        570.0, {"img_s": 600.0, "fit_window": False}, True
-    )["invalid"] == "weather"
-    assert bench.ceiling_ratio_row(
-        570.0, {"img_s": 600.0, "fit_window": True, "capped": True}, True
-    )["invalid"] == "weather"
-    assert bench.ceiling_ratio_row(570.0, {}, True)["invalid"] == (
-        "ceiling_failed"
-    )
-
-
 def test_tile_capacity_default_derives_from_dims():
     """Measured geometries keep their measured fits; any other geometry
     gets an area-scaled estimate that covers the known changed-pixel
@@ -85,396 +57,100 @@ def test_tile_capacity_default_derives_from_dims():
     assert int(bench.tile_capacity_default(240, 320)) == 4
 
 
-def test_weather_probe_reports_window():
-    """The per-pass weather stamp must always carry a fit verdict and,
-    absent device errors, the RTT it judged from."""
+def _stub_rows(monkeypatch, values, raising=None):
+    """Replace every measurement bench._build_record makes with a stub:
+    the headline passes return ``values`` in turn, each add-on row a
+    small dict (``raising`` names one that raises instead)."""
     import bench
 
-    w = bench.weather_probe()
-    assert isinstance(w.get("fit"), bool)
-    if "error" not in w:
-        assert "rtt_s" in w
-
-
-class _Clock:
-    def __init__(self):
-        self.t = 0.0
-
-    def __call__(self):
-        return self.t
-
-    def sleep(self, s):
-        self.t += s
-
-
-def _probe_seq(probes, clock, probe_cost=1.0):
-    """Iterator-backed fake probe; repeats the last element forever and
-    advances the fake clock per call (probes aren't free)."""
-    it = iter(probes)
-    last = probes[-1]
-
-    def probe():
-        nonlocal last
-        clock.t += probe_cost
-        last = next(it, last)
-        return dict(last)
-
-    return probe
-
-
-FIT = {"fit": True, "rtt_s": 0.1, "h2d_MB_s": 43.0}
-COLLAPSED = {"fit": False, "rtt_s": 0.1, "h2d_MB_s": 12.0}
-BLIND = {"fit": False, "error": "boom"}
-
-
-def _measure_seq(values, clock, cost=5.0):
-    it = iter(values)
-    last = values[-1]
-
-    def run():
-        nonlocal last
-        clock.t += cost
-        last = next(it, last)
-        return {"value": last, "seconds": cost}
-
-    return run
-
-
-def test_collect_passes_stops_at_n_fit_passes_over_floor():
-    import bench
-
-    clock = _Clock()
-    passes = bench.collect_passes(
-        _measure_seq([500.0, 520.0], clock),
-        _probe_seq([FIT], clock),
-        n_passes=2, retry_floor=400.0, wait_budget=480.0, poll_sleep=12.0,
-        degraded=False, w0=FIT, clock=clock, sleep=clock.sleep,
+    passes = iter(values)
+    monkeypatch.setattr(
+        bench, "measure",
+        lambda *a, **k: {"value": next(passes), "seconds": 1.0, "chunk": 16},
     )
-    assert [p["value"] for p in passes] == [500.0, 520.0]
-    assert all(p["fit_window"] for p in passes)
-    # stopped as soon as the goal was met — no budget-burning extras
-    assert clock.t < 60
+    def row(name):
+        def fn(*a, **k):
+            if name == raising:
+                raise RuntimeError(f"{name} broke")
+            return {"img_s": 100.0}
+
+        return fn
+
+    for name in vars(bench):
+        if name.startswith("measure_") or name == "_raw_row":
+            monkeypatch.setattr(bench, name, row(name))
 
 
-def test_collect_passes_keeps_rolling_below_floor():
-    """Fit-probe windows whose passes run slow (the 38 MB/s + stalled
-    dispatch mode) must not satisfy the bench — it keeps rolling until
-    the budget or the 20-pass cap."""
+@pytest.mark.usefixtures("compile_cache_config_guard")
+def test_build_record_takes_plain_passes_and_names_platforms(monkeypatch):
+    """BLENDJAX_BENCH_PASSES plain passes, the best one reported; no
+    probe, window or shrunken workload anywhere; every row names the
+    platform it ran on."""
     import bench
 
-    clock = _Clock()
-    passes = bench.collect_passes(
-        _measure_seq([60.0], clock),
-        _probe_seq([FIT], clock),
-        n_passes=2, retry_floor=400.0, wait_budget=200.0, poll_sleep=12.0,
-        degraded=False, w0=FIT, clock=clock, sleep=clock.sleep,
-    )
-    assert len(passes) >= 3  # kept retrying
-    assert clock.t >= 200.0 or len(passes) == 20
+    monkeypatch.setenv("BLENDJAX_BENCH_PASSES", "3")
+    _stub_rows(monkeypatch, [10.0, 50.0, 20.0])
+    rec = bench._build_record()
+    detail = rec["detail"]
+    assert rec["value"] == 50.0
+    assert [p["value"] for p in detail["passes"]] == [10.0, 50.0, 20.0]
+    assert detail["platform"] == "cpu" and detail["device_count"] >= 1
+    assert detail["utilization"] == 0.5
+    assert detail["utilization_vs_ceiling"] == 0.5
+    rows = {k: v for k, v in detail.items() if isinstance(v, dict)}
+    assert len(rows) >= 16
+    assert all(v["platform"] == "cpu" for v in rows.values()), rows
+    # and nothing else is stamped on the record
+    assert {k for k in detail if k not in rows} == {
+        "seconds", "chunk", "platform", "device_kind", "device_count",
+        "passes", "utilization", "utilization_vs_ceiling",
+    }
 
 
-def test_collect_passes_fallback_when_never_fit():
-    """No fit window in the whole budget -> measure anyway, labeled."""
+@pytest.mark.usefixtures("compile_cache_config_guard")
+@pytest.mark.parametrize(
+    "raising", ["measure_live_fleet", "measure_step_alone", "measure_rl_hz"]
+)
+def test_a_raising_row_fails_the_run(monkeypatch, capsys, raising):
+    """No row is turned into an ``{"error": ...}`` field of a record
+    that still prints and exits 0: the exception leaves main()."""
     import bench
 
-    clock = _Clock()
-    passes = bench.collect_passes(
-        _measure_seq([20.0], clock),
-        _probe_seq([COLLAPSED], clock),
-        n_passes=3, retry_floor=400.0, wait_budget=60.0, poll_sleep=12.0,
-        degraded=False, w0=COLLAPSED, clock=clock, sleep=clock.sleep,
-    )
-    assert len(passes) == 3
-    assert not any(p["fit_window"] for p in passes)
+    monkeypatch.setenv("BLENDJAX_BENCH_PASSES", "1")
+    _stub_rows(monkeypatch, [10.0], raising=raising)
+    with pytest.raises(RuntimeError, match="broke"):
+        bench.main()
+    assert capsys.readouterr().out == ""
 
 
-def test_collect_passes_blind_probe_escape():
-    """Probes with no bandwidth figure can never turn fit — escape to
-    the fallback after 3 instead of sleeping the budget away."""
+@pytest.mark.parametrize(
+    "returncode, stdout",
+    [(3, '{"img_s": 1.0}'), (0, "no json line here")],
+    ids=["nonzero-exit", "no-record"],
+)
+def test_cpu_mesh_child_raises_on_a_failed_child(
+    monkeypatch, returncode, stdout
+):
+    """The forced-CPU mesh legs run in children; one that exits non-zero
+    or prints no record raises in the parent instead of becoming an
+    error row — and one that succeeds is stamped ``"platform": "cpu"``."""
+    import subprocess
+
     import bench
 
-    clock = _Clock()
-    passes = bench.collect_passes(
-        _measure_seq([20.0], clock),
-        _probe_seq([BLIND], clock),
-        n_passes=2, retry_floor=400.0, wait_budget=480.0, poll_sleep=12.0,
-        degraded=False, w0=BLIND, clock=clock, sleep=clock.sleep,
-    )
-    assert len(passes) == 2
-    # 3 blind polls (2 sleeps between) + fallback probes; far under budget
-    assert clock.t < 100
+    def fake_run(argv, **kw):
+        return subprocess.CompletedProcess(argv, returncode, stdout, "boom")
 
-
-def test_collect_passes_degraded_skips_probes():
-    """Outage mode: zero probe calls (each costs multi-second RTTs);
-    w0 stamps the first pass, the skip marker the rest."""
-    import bench
-
-    clock = _Clock()
-    calls = {"probes": 0}
-
-    def probe():
-        calls["probes"] += 1
-        return dict(BLIND)
-
-    w0 = {"fit": False, "rtt_s": 24.0}
-    passes = bench.collect_passes(
-        _measure_seq([5.0], clock), probe,
-        n_passes=2, retry_floor=400.0, wait_budget=0.0, poll_sleep=12.0,
-        degraded=True, w0=w0, clock=clock, sleep=clock.sleep,
-    )
-    assert calls["probes"] == 0
-    assert len(passes) == 2
-    assert passes[0]["weather"]["pre"] == w0
-    assert passes[1]["weather"]["pre"].get("skipped") == "outage"
-
-
-def test_collect_passes_fallback_is_probe_free():
-    """ADVICE r5: once the wait budget is spent, fallback passes must
-    not issue fresh probe() calls (on a degraded link each costs
-    multi-second RTTs that eat the watchdog budget) — the first
-    fallback pass reuses the LAST poll probe, the rest carry the skip
-    marker, and no pass gets a post probe."""
-    import bench
-
-    clock = _Clock()
-    seq = []
-
-    def probe():
-        seq.append("probe")
-        clock.t += 2.0
-        return dict(COLLAPSED)
-
-    inner = _measure_seq([20.0], clock)
-
-    def run():
-        seq.append("measure")
-        return inner()
-
-    passes = bench.collect_passes(
-        run, probe,
-        n_passes=3, retry_floor=400.0, wait_budget=30.0, poll_sleep=12.0,
-        degraded=False, w0=COLLAPSED, clock=clock, sleep=clock.sleep,
-    )
-    assert len(passes) == 3
-    # the poll loop probed; the fallback (everything from the first
-    # measure onward) issued ZERO fresh probes
-    assert seq.index("measure") > 0
-    assert "probe" not in seq[seq.index("measure"):]
-    assert passes[0]["weather"]["pre"] == COLLAPSED  # last poll reused
-    for p in passes:
-        assert p["weather"]["post"].get("skipped") == "outage"
-    for p in passes[1:]:
-        assert p["weather"]["pre"].get("skipped") == "outage"
-
-
-def test_collect_passes_zero_budget_first_pass_stamped_by_w0():
-    """wait_budget=0 (the CI smoke config): no poll probe ever ran, so
-    the run-start probe stamps the first fallback pass and still no
-    fresh probes are issued."""
-    import bench
-
-    clock = _Clock()
-    calls = {"probes": 0}
-
-    def probe():
-        calls["probes"] += 1
-        return dict(FIT)
-
-    passes = bench.collect_passes(
-        _measure_seq([20.0], clock), probe,
-        n_passes=2, retry_floor=400.0, wait_budget=0.0, poll_sleep=12.0,
-        degraded=False, w0=COLLAPSED, clock=clock, sleep=clock.sleep,
-    )
-    assert calls["probes"] == 0
-    assert len(passes) == 2
-    assert passes[0]["weather"]["pre"] == COLLAPSED
-    assert passes[1]["weather"]["pre"].get("skipped") == "outage"
-
-
-def test_collect_passes_flap_mid_pass_is_not_fit():
-    """pre fit, post collapsed -> the window didn't hold; the pass is
-    recorded but not fit (the r4 lesson: pre-only gating was defeated
-    by mid-run flaps)."""
-    import bench
-
-    clock = _Clock()
-    passes = bench.collect_passes(
-        _measure_seq([300.0], clock),
-        _probe_seq([FIT, COLLAPSED], clock),  # pre fit, post collapsed
-        n_passes=1, retry_floor=150.0, wait_budget=30.0, poll_sleep=12.0,
-        degraded=False, w0=FIT, clock=clock, sleep=clock.sleep,
-    )
-    assert passes[0]["fit_window"] is False
-
-
-def _row_fn(values, clock, cost=5.0):
-    it = iter(values)
-    last = values[-1]
-
-    def fn():
-        nonlocal last
-        clock.t += cost
-        last = next(it, last)
-        return {"img_s": last}
-
-    return fn
-
-
-def test_gated_row_polls_for_fit_when_headline_fit():
-    import bench
-
-    clock = _Clock()
-    row = bench.run_gated_row(
-        _row_fn([600.0], clock),
-        _probe_seq([COLLAPSED, COLLAPSED, FIT, FIT], clock),
-        headline_fit=True, degraded=False, budget=180.0,
-        poll_sleep=12.0, clock=clock, sleep=clock.sleep,
-    )
-    assert row["fit_window"] is True
-    assert row["weather"]["pre"]["h2d_MB_s"] == 43.0
-
-
-def test_gated_row_runs_immediately_when_headline_unfit():
-    import bench
-
-    clock = _Clock()
-    probes = {"n": 0}
-
-    def probe():
-        probes["n"] += 1
-        clock.t += 1.0
-        return dict(COLLAPSED)
-
-    row = bench.run_gated_row(
-        _row_fn([100.0], clock), probe,
-        headline_fit=False, degraded=False, budget=180.0,
-        poll_sleep=12.0, clock=clock, sleep=clock.sleep,
-    )
-    assert row["fit_window"] is False
-    assert probes["n"] == 2  # pre + post only: no polling, no retry
-    assert clock.t < 10
-
-
-def test_gated_row_retries_once_after_midrow_collapse():
-    import bench
-
-    clock = _Clock()
-    # attempt 1: pre fit, post collapsed AND BOTH decayed re-probes
-    # still collapsed (a real mid-row flap); attempt 2: fit holds
-    row = bench.run_gated_row(
-        _row_fn([500.0, 510.0], clock),
-        _probe_seq(
-            [FIT, COLLAPSED, COLLAPSED, COLLAPSED, FIT, FIT], clock
+    monkeypatch.setattr(subprocess, "run", fake_run)
+    with pytest.raises(RuntimeError, match="--multichip-live.*boom"):
+        bench.measure_multichip_live()
+    monkeypatch.setattr(
+        subprocess, "run",
+        lambda argv, **kw: subprocess.CompletedProcess(
+            argv, 0, 'noise\n{"img_s": 1.0}', ""
         ),
-        headline_fit=True, degraded=False, budget=180.0,
-        poll_sleep=12.0, clock=clock, sleep=clock.sleep,
     )
-    assert row["fit_window"] is True
-    assert row["img_s"] == 510.0  # the retry's measurement
-
-
-def test_gated_row_single_jitter_sample_cannot_invalidate():
-    """One collapsed post sample between two fit ones is host jitter,
-    not weather: the immediate re-probe absorbs it, the row stays fit
-    on its FIRST measurement, and the discarded sample is preserved
-    (the BENCH_r05 `utilization.invalid: "weather"` mode)."""
-    import bench
-
-    clock = _Clock()
-    row = bench.run_gated_row(
-        _row_fn([500.0, 510.0], clock),
-        _probe_seq([FIT, COLLAPSED, FIT], clock),
-        headline_fit=True, degraded=False, budget=180.0,
-        poll_sleep=12.0, clock=clock, sleep=clock.sleep,
-    )
-    assert row["fit_window"] is True
-    assert row["img_s"] == 500.0  # no re-measurement needed
-    assert row["weather"]["post"]["jitter_discarded"] == 12.0
-
-
-def test_gated_row_decaying_bar_accepts_jittered_reprobe():
-    """A post sample under the full fit bar but above the decayed
-    re-probe bar (teardown jitter, not a collapse) keeps the window
-    fit: re-probe 1 judges at 0.9x the bar, re-probe 2 at 0.81x — the
-    BENCH_r05 mode where one re-probe at the full bar still
-    invalidated `utilization` with `invalid: "weather"`."""
-    import bench
-
-    clock = _Clock()
-    near_fit = {"fit": False, "rtt_s": 0.1, "h2d_MB_s": 33.0}
-    # 33.0 fails the 35.0 bar and the first decayed bar (31.5 passes!)
-    # -> accepted on re-probe 1 with the relaxed-bar stamp
-    row = bench.run_gated_row(
-        _row_fn([500.0], clock),
-        _probe_seq([FIT, near_fit, near_fit], clock),
-        headline_fit=True, degraded=False, budget=180.0,
-        poll_sleep=12.0, clock=clock, sleep=clock.sleep,
-    )
-    assert row["fit_window"] is True
-    assert row["img_s"] == 500.0  # no re-measurement needed
-    post = row["weather"]["post"]
-    assert post["relaxed_bar_MB_s"] == 31.5  # 35.0 * 0.9
-    assert post["jitter_discarded"] == 33.0
-    # a genuinely collapsed window fails every decayed bar and the
-    # discarded samples are all preserved
-    clock2 = _Clock()
-    row2 = bench.run_gated_row(
-        _row_fn([500.0], clock2),
-        _probe_seq([FIT, COLLAPSED], clock2),
-        headline_fit=True, degraded=False, budget=10.0, attempts=1,
-        poll_sleep=12.0, clock=clock2, sleep=clock2.sleep,
-    )
-    assert row2["fit_window"] is False
-    assert "jitter_discarded" not in row2["weather"]["post"]
-
-
-def test_utilization_row_partial_instead_of_invalid():
-    """Cross-window utilization publishes a one-sided lower bound with
-    an explicit `partial` flag — never the old `invalid: "weather"`
-    wholesale discard (the recurring r05 outcome)."""
-    import bench
-
-    fit_alone = {"img_s": 1000.0, "fit_window": True}
-    assert bench.utilization_row(500.0, fit_alone, True) == 0.5
-    p = bench.utilization_row(500.0, fit_alone, False)
-    assert p["partial"] is True and p["one_sided"] == 0.5
-    assert p["reason"] == "weather"
-    assert p["headline_fit"] is False and p["step_alone_fit"] is True
-    # unfit headline deflates the numerator: the figure is a floor
-    assert p["bound"] == "lower"
-    p2 = bench.utilization_row(
-        500.0, {"img_s": 1000.0, "fit_window": False}, True
-    )
-    assert p2["partial"] is True and p2["step_alone_fit"] is False
-    # unfit step-alone deflates the DENOMINATOR: the figure can only
-    # overstate utilization — it must publish as an upper bound
-    assert p2["bound"] == "upper"
-    p3 = bench.utilization_row(
-        500.0, {"img_s": 1000.0, "fit_window": False}, False
-    )
-    assert p3["bound"] == "unknown"
-    assert all("invalid" not in x for x in (p, p2, p3))
-    assert bench.utilization_row(500.0, {}, True)["invalid"] == (
-        "step_alone_failed"
-    )
-
-
-def test_gated_row_degraded_skips_probes_entirely():
-    import bench
-
-    clock = _Clock()
-
-    def probe():  # pragma: no cover - must not be called
-        raise AssertionError("probe called in degraded mode")
-
-    row = bench.run_gated_row(
-        _row_fn([5.0], clock), probe,
-        headline_fit=False, degraded=True,
-        clock=clock, sleep=clock.sleep,
-    )
-    assert row["fit_window"] is False
-    assert row["weather"]["pre"].get("skipped") == "outage"
+    assert bench.measure_multichip_live() == {"img_s": 1.0, "platform": "cpu"}
 
 
 def test_pipelined_ceiling_caps_and_flags(monkeypatch):

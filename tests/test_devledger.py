@@ -17,9 +17,11 @@ from blendjax.obs.devledger import (
     HBM_GAUGES,
     LEDGER_GAUGES,
     UNAVAILABLE,
+    V5E_PEAK_FLOPS,
     ExecutableLedger,
     RetraceAudit,
     batch_signature,
+    chip_peak_flops,
     default_peak_flops,
     ledger as global_ledger,
     measure_model_flops,
@@ -242,9 +244,18 @@ def test_poll_memory_is_a_graceful_noop_on_cpu():
     assert led.report()["memory"] in (None, {"supported": False})
 
 
-def test_default_peak_flops_unknown_backend_is_none():
-    # tier-1 runs on JAX_PLATFORMS=cpu: no known-chip match, no guess
+def test_default_peak_flops_on_cpu_is_none():
+    # tier-1 runs on JAX_PLATFORMS=cpu: no chip, no utilization
     assert default_peak_flops() is None
+
+
+def test_chip_peaks_are_keyed_by_exact_device_kind():
+    """The kind a v5e reports is in the table; anything else is an
+    error naming the kind — no substring guess, no silent None."""
+    assert chip_peak_flops("TPU v5 lite") == (V5E_PEAK_FLOPS, "TPU v5e")
+    for kind in ("TPU v5 lite pod", "tpu v5 lite", "TPU v44", ""):
+        with pytest.raises(KeyError, match="no peak FLOP/s on record"):
+            chip_peak_flops(kind)
 
 
 # -- retrace events and the audit --------------------------------------------

@@ -36,9 +36,10 @@ def test_arguments_roundtrip():
     assert args.instance_id == 3 and args.seed == 13 and args.sockets
 
 
-def test_launch_two_instances_handshake():
+def test_launch_two_instances_handshake(monkeypatch):
     """Two instances get distinct ids, seeds seed+i, distinct tcp addresses,
     and their per-instance extra args (reference ``test_launcher.py:20-44``)."""
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")  # what a trainer's env may say
     with PythonProducerLauncher(
         script=PRODUCER,
         num_instances=2,
@@ -59,6 +60,8 @@ def test_launch_two_instances_handshake():
     assert seen[0]["remainder"] == ["--x", "a"]
     assert seen[1]["remainder"] == ["--x", "b"]
     assert seen[0]["sockets"]["DATA"] == addrs[0]
+    # a child of the process that holds the chip can never need it
+    assert seen[0]["jax_platforms"] == seen[1]["jax_platforms"] == "cpu"
 
 
 def test_assert_alive_and_teardown():

@@ -356,6 +356,70 @@ def test_tile_publisher_raw_direct_pack_path():
             np.testing.assert_array_equal(got, want)
 
 
+@pytest.mark.parametrize("alpha_slice", [False, True], ids=["fused", "two-pass"])
+def test_tile_publisher_index_width_is_sticky_and_pinnable(alpha_slice):
+    """The palette index width is a wire shape (a wider batch breaks the
+    consumer's chunk group and compiles its own step), so like the
+    capacity it only grows — and ``palette_bits`` pins where it starts.
+    Both publish paths; frames still decode bit-exact."""
+    from blendjax.ops.tiles import (
+        TILEIDX_SUFFIX,
+        TILEPAL_SUFFIXES,
+        TILESHAPE_SUFFIX,
+        decode_tile_delta_np,
+        expand_palette_tiles_np,
+        pop_tile_payload,
+    )
+    from blendjax.producer.tile_publisher import TileBatchPublisher
+
+    class Capture:
+        def __init__(self):
+            self.msgs = []
+
+        def publish(self, **kw):
+            self.msgs.append(kw)
+
+    ref = np.zeros((32, 64, 4), np.uint8)
+    ref[..., 3] = 255
+
+    def frame(n_colors):
+        img = ref.copy()
+        for j in range(n_colors - 1):  # the background is one color
+            img[2 * j: 2 * j + 2, 0:8, :3] = 10 * (j + 1)
+        return img
+
+    def widths(palette_bits, colors_per_batch):
+        cap = Capture()
+        pub = TileBatchPublisher(
+            cap, ref, batch_size=2, tile=(16, 32), capacity=4,
+            alpha_slice=alpha_slice, palette_bits=palette_bits,
+        )
+        assert pub._fused_ok != alpha_slice
+        sent = []
+        for n in colors_per_batch:
+            pub.add(frame(2))
+            pub.add(frame(n))
+            sent += [frame(2), frame(n)]
+        out = []
+        for msg in cap.msgs:
+            msg = dict(msg)
+            out.append(next(
+                b for b, suf in TILEPAL_SUFFIXES.items() if "image" + suf in msg
+            ))
+            idx = msg.pop("image" + TILEIDX_SUFFIX)
+            geom = msg.pop("image" + TILESHAPE_SUFFIX)
+            tiles = pop_tile_payload(msg, "image", geom, expand_palette_tiles_np)
+            got = decode_tile_delta_np(ref, idx, tiles, tile=(16, 32))
+            for g in got:
+                np.testing.assert_array_equal(g, sent.pop(0))
+        return out
+
+    assert widths(2, [2, 3, 5, 2, 3]) == [2, 2, 4, 4, 4]
+    assert widths(4, [2, 3, 5, 2]) == [4, 4, 4, 4]
+    with pytest.raises(ValueError, match="palette_bits"):
+        TileBatchPublisher(Capture(), ref, batch_size=2, palette_bits=3)
+
+
 def test_tile_publisher_fused_palette_overflow_falls_back():
     """A frame pushing the persistent stream palette past 256 colors
     latches the fused path off mid-batch; already-packed rows
@@ -1674,7 +1738,7 @@ def test_stream_pipeline_pal_encoding_end_to_end():
         for i, f in enumerate(np.asarray(b["frameid"])):
             np.testing.assert_array_equal(img[i], local[int(f)])
     # wire accounting: the codec actually compressed (cube scene fits
-    # pal4 => ~8x; assert a conservative 3x to stay weather-proof)
+    # pal4 => ~8x; assert a conservative 3x)
     wire = reg.counters.get("pal.wire_bytes", 0)
     decoded = reg.counters.get("pal.decoded_bytes", 0)
     assert decoded and wire and decoded / wire > 3.0

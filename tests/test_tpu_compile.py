@@ -1,0 +1,345 @@
+"""What the chip's compiler says, asked without the chip.
+
+libtpu compiles for a *described* TPU v5e (``on-chip-measurement`` guide
+§2.3), so every Pallas kernel of the main path and the jitted steps
+around them are lowered here at the widths the bench streams (480x640x4
+frames, 16x32 and 16x16 tiles) and must come back from the TPU compiler
+with the kernel inside. Nothing runs: this guards against a kernel the
+chip refuses (interpret mode accepts far more), a program that does not
+fit 16 GB, and a mesh step that lost its collective — not against a
+wrong result, which only ``chip_smoke.py`` on the chip can show.
+
+Code under test picks its TPU branch from ``jax.default_backend()``,
+which still says "cpu" here, so the ``tpu_branches`` fixture answers
+"tpu" for the duration of a test. The default cases take seconds each;
+the ``slow`` ones are the rest of the rehearsal to make before a chip
+call (``python -m pytest -m slow tests/test_tpu_compile.py``): the
+StreamFormer's fused step, the four-chip fused step, the echo-fused step
+and the full-frame palette group.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+import bench
+from blendjax.models import CubeRegressor
+from blendjax.ops import tiles as T
+from blendjax.train import (
+    make_echo_fused_step,
+    make_fused_tile_step,
+    make_train_state,
+)
+from blendjax.train.mesh_driver import (
+    make_mesh_fused_step,
+    make_mesh_supervised_step,
+)
+
+H, W, C = (*bench.SHAPE, 4)
+B = bench.BATCH
+V5E_HBM_BYTES = 16 * 2**30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no libtpu here: nothing to ask
+        pytest.skip(f"cannot describe a TPU v5e topology: {e!r}")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _compile_cache_off():
+    """A compile for a described device is written to the persistent
+    cache but cannot be read back without the chip (the next one warns
+    and recompiles), so the cache is off around this file."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def tpu_branches(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _abstract_state(model, sharding):
+    """The train state's shapes, every array leaf on ``sharding`` —
+    there is no device to hold a real one."""
+    state = jax.eval_shape(
+        lambda: make_train_state(model, np.zeros((B, H, W, C), np.uint8))
+    )
+    return jax.tree_util.tree_map(
+        lambda x: _sds(x.shape, x.dtype, sharding)
+        if hasattr(x, "shape") else x,
+        state,
+    )
+
+
+def _tile_plan(tile, capacity=None):
+    """The packed layout + decode plan of one tile batch at bench
+    geometry, as the pipeline's host stage hands it to the fused step —
+    the wire shape the cube producers ship (full-channel tiles as 4-bit
+    per-frame palette indices, ``bench.TILE_PAL_BITS``):
+    ``(row_bytes, spec, names, geoms, ref_shape)``."""
+    th, tw = T.tile_hw(tile)
+    cap = int(capacity or bench.tile_capacity_default(th, tw))
+    n = (H // th) * (W // tw)
+    bits = int(bench.TILE_PAL_BITS)
+    buf, spec = T.pack_fields({
+        "image" + T.TILEIDX_SUFFIX: np.zeros((B, cap), np.int32),
+        "image" + T.TILEPAL_SUFFIXES[bits]: np.zeros(
+            (B, cap, th * tw * bits // 8), np.uint8
+        ),
+        "image" + T.PALETTE_SUFFIX: np.zeros((B, 1 << bits, C), np.uint8),
+        "xy": np.zeros((B, 8, 2), np.float32),
+        "frameid": np.zeros((B,), np.int64),
+    })
+    geoms = (tuple(T.tileshape_wire(H, W, C, (th, tw))),)
+    return buf.shape[0], spec, ("image",), geoms, (n, th, tw, C)
+
+
+def _assert_fits_with_kernel(compiled, kernel=True):
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == kernel
+    ma = compiled.memory_analysis()
+    per_device = (
+        ma.argument_size_in_bytes + ma.output_size_in_bytes
+        + ma.temp_size_in_bytes - ma.alias_size_in_bytes
+    )
+    assert per_device < V5E_HBM_BYTES, per_device
+    return text
+
+
+@pytest.mark.parametrize(
+    "tile, batch",
+    [((16, 32), 8), ((16, 32), 128), (16, 8), (16, 128)],
+    ids=["spatial-B8", "spatial-B128", "scatter-B8", "scatter-B128"],
+)
+def test_decode_kernel_compiles(topo, tpu_branches, tile, batch):
+    """Direct-spatial (16x32) and slot-scatter (16x16) decode at one
+    batch and at one K=16 chunk group (128 frames), K=288 tile slots."""
+    one = SingleDeviceSharding(topo.devices[0])
+    th, tw = T.tile_hw(tile)
+    n = (H // th) * (W // tw)
+    fn = jax.jit(lambda r, i, tl: T.decode_tile_delta(r, i, tl, (H, W, C)))
+    compiled = fn.lower(
+        _sds((n, th, tw, C), jnp.uint8, one),
+        _sds((batch, 288), jnp.int32, one),
+        _sds((batch, 288, th, tw, C), jnp.uint8, one),
+    ).compile()
+    _assert_fits_with_kernel(compiled)
+
+
+@pytest.mark.parametrize(
+    "shape", [(4, 3072, 4, 128), (8, 768, 4, 128)], ids=["T3072", "T768"]
+)
+def test_flash_attention_fwd_bwd_compiles(topo, tpu_branches, shape):
+    """The library flash kernel under the repo's pinned block sizes, at
+    the bench's longseq and live-row attention shapes (bf16)."""
+    from blendjax.ops.attention import local_attention
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def loss(q, k, v):
+        out = local_attention(q, k, v, backend="flash")
+        return jnp.sum(out.astype(jnp.float32))
+
+    q = _sds(shape, jnp.bfloat16, one)
+    compiled = jax.jit(
+        jax.value_and_grad(loss, argnums=(0, 1, 2))
+    ).lower(q, q, q).compile()
+    _assert_fits_with_kernel(compiled)
+
+
+def test_gamma_normalize_compiles(topo):
+    """The Pallas gamma kernel is gone (the chip refused its uint8 ->
+    float32 cast and nothing called it); what replaced it is plain jnp
+    and the chip takes it."""
+    from blendjax.ops.image import uint8_gamma_normalize
+
+    one = SingleDeviceSharding(topo.devices[0])
+    compiled = jax.jit(uint8_gamma_normalize).lower(
+        _sds((B, H, W, C), jnp.uint8, one)
+    ).compile()
+    _assert_fits_with_kernel(compiled, kernel=False)
+
+
+def _lower_fused_tile(step, state, chunk, sharding, plan):
+    row_bytes, spec, names, geoms, ref_shape = plan
+    return step.jits["tile"].lower(
+        state,
+        _sds((chunk, row_bytes), jnp.uint8, sharding),
+        {"image": _sds(ref_shape, jnp.uint8, sharding)},
+        spec, names, geoms, (),
+    )
+
+
+@pytest.mark.parametrize(
+    "model_and_loss",
+    [
+        lambda: (CubeRegressor(), None),
+        pytest.param(
+            bench._transformer_model_and_loss, marks=pytest.mark.slow
+        ),
+    ],
+    ids=["cnn", "streamformer"],
+)
+def test_fused_tile_step_compiles_with_the_kernel(
+    topo, tpu_branches, model_and_loss
+):
+    """``make_fused_tile_step`` whole at the bench's K=16 — unpack,
+    palette expand, Pallas decode, 16 scanned updates — with the kernel
+    branch actually taken."""
+    one = SingleDeviceSharding(topo.devices[0])
+    model, loss_fn = model_and_loss()
+    step = make_fused_tile_step(loss_fn=loss_fn)
+    compiled = _lower_fused_tile(
+        step, _abstract_state(model, one), bench.CHUNK, one,
+        _tile_plan((16, 32)),
+    ).compile()
+    _assert_fits_with_kernel(compiled)
+
+
+@pytest.fixture
+def mesh4(topo):
+    return Mesh(np.array(topo.devices).reshape(4), ("data",))
+
+
+def test_four_chip_data_parallel_step_has_its_all_reduce(topo, mesh4):
+    """The question is the collective, not the width: a narrow CNN keeps
+    this to seconds (the full-width mesh step is the ``slow`` fused case
+    below)."""
+    rep = NamedSharding(mesh4, P())
+    by_batch = NamedSharding(mesh4, P("data"))
+    state = _abstract_state(CubeRegressor(features=(8, 16)), rep)
+    compiled = make_mesh_supervised_step(state, mesh4).lower(
+        state,
+        {
+            "image": _sds((B, H, W, C), jnp.uint8, by_batch),
+            "xy": _sds((B, 8, 2), jnp.float32, by_batch),
+        },
+    ).compile()
+    text = _assert_fits_with_kernel(compiled, kernel=False)
+    assert "all-reduce(" in text or "all-reduce-start(" in text
+
+
+def test_four_chip_sharded_decode_keeps_the_kernel(topo, tpu_branches, mesh4):
+    """The kernel cannot be partitioned by GSPMD; with ``mesh=`` it goes
+    through ``shard_map`` over the batch axis and survives."""
+    rep = NamedSharding(mesh4, P())
+    by_batch = NamedSharding(mesh4, P("data"))
+    th, tw = 16, 32
+    cap = int(bench.tile_capacity_default(th, tw))
+    n = (H // th) * (W // tw)
+    fn = jax.jit(
+        lambda r, i, tl: T.decode_tile_delta(r, i, tl, (H, W, C), mesh=mesh4)
+    )
+    compiled = fn.lower(
+        _sds((n, th, tw, C), jnp.uint8, rep),
+        _sds((B, cap), jnp.int32, by_batch),
+        _sds((B, cap, th, tw, C), jnp.uint8, by_batch),
+    ).compile()
+    _assert_fits_with_kernel(compiled)
+
+
+@pytest.mark.slow
+def test_four_chip_fused_step_has_kernel_and_all_reduce(
+    topo, tpu_branches, mesh4
+):
+    """What ``MeshTrainDriver.build(fused=True)`` dispatches: the packed
+    group arrives replicated, decodes shard-locally through the kernel,
+    trains data-parallel."""
+    rep = NamedSharding(mesh4, P())
+    state = _abstract_state(CubeRegressor(), rep)
+    step = make_mesh_fused_step(state, mesh4)
+    compiled = _lower_fused_tile(
+        step, state, 2, rep, _tile_plan((16, 32))
+    ).compile()
+    text = _assert_fits_with_kernel(compiled)
+    assert "all-reduce(" in text or "all-reduce-start(" in text
+
+
+def test_unsharded_kernel_in_a_partitioned_program_is_refused(
+    topo, tpu_branches, mesh4
+):
+    """No mesh passed means "single-device program": when that is not
+    true the lowering refuses — it does not quietly take another path."""
+    by_batch = NamedSharding(mesh4, P("data"))
+    th, tw = 16, 32
+    n = (H // th) * (W // tw)
+    fn = jax.jit(lambda r, i, tl: T.decode_tile_delta(r, i, tl, (H, W, C)))
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        fn.lower(
+            _sds((n, th, tw, C), jnp.uint8, NamedSharding(mesh4, P())),
+            _sds((B, 160), jnp.int32, by_batch),
+            _sds((B, 160, th, tw, C), jnp.uint8, by_batch),
+        )
+
+
+@pytest.mark.slow
+def test_echo_fused_step_compiles(topo):
+    """Reservoir gather + re-augmentation + update in one program, at
+    frame size (no kernel of ours inside; the question is whether the
+    chip takes the uint8 ring gather and the augment chain)."""
+    from blendjax.data.echo import SampleReservoir, default_echo_augment
+
+    one = SingleDeviceSharding(topo.devices[0])
+    res = SampleReservoir(capacity=2 * B, augment=default_echo_augment(), rng=0)
+    res.insert({
+        "image": np.zeros((B, H, W, C), np.uint8),
+        "xy": np.zeros((B, 8, 2), np.float32),
+    })
+    step = make_echo_fused_step(res.draw)
+    buffers = jax.tree_util.tree_map(
+        lambda x: _sds(x.shape, x.dtype, one), res.draw_token([0])["_echo_buffers"]
+    )
+    compiled = step.jits["echo"].lower(
+        _abstract_state(CubeRegressor(), one), buffers,
+        _sds((B,), jnp.int32, one), _sds((), jnp.uint32, one),
+    ).compile()
+    _assert_fits_with_kernel(compiled, kernel=False)
+
+
+@pytest.mark.slow
+def test_fused_palette_group_compiles(topo):
+    """The full-frame palette codec's fused form (``_pal`` groups):
+    byte-LUT gather decode + K'=8 scanned updates."""
+    one = SingleDeviceSharding(topo.devices[0])
+    frames = np.random.default_rng(0).integers(
+        0, 12, (B, H, W, 1), np.uint8
+    ).repeat(C, axis=-1)
+    packed, palette, bits = T.palettize_frames(frames)
+    buf, spec = T.pack_fields({
+        "image" + T.FRAMEPAL_SUFFIXES[bits]: packed,
+        "image" + T.PALETTE_SUFFIX: palette,
+        "xy": np.zeros((B, 8, 2), np.float32),
+    })
+    step = make_fused_tile_step()
+    compiled = step.jits["pal"].lower(
+        _abstract_state(CubeRegressor(), one),
+        _sds((bench.RAW_CHUNK, buf.shape[0]), jnp.uint8, one),
+        spec, (("image", (H, W, C, bits)),), (),
+    ).compile()
+    _assert_fits_with_kernel(compiled, kernel=False)
