@@ -1,0 +1,6 @@
+"""Peak device memory on the fullest chip after the window, GB."""
+
+
+def read(obs):
+    peak = obs["device"].get("memory_peak_bytes")
+    return peak / 1e9 if peak else None
