@@ -36,7 +36,10 @@ local attention runs; this module is the per-device math below them.
 
 from __future__ import annotations
 
+import jax
+
 from blendjax.parallel.ring import reference_attention
+from blendjax.utils.metrics import SCOPE_ATTN_CORE
 
 # Per-call score-residual budget (bytes of f32 probs saved for the
 # backward pass) above which `auto` switches to the flash kernel: at
@@ -143,8 +146,11 @@ def local_attention(q, k, v, causal: bool = False, scale=None,
     use_flash = backend == "flash" or (
         backend == "auto" and auto_picks_flash(q, k)
     )
+    # one name for attention without its projections, whichever backend
+    # runs it (docs/observability.md "Device scopes")
     if not use_flash:
-        return reference_attention(q, k, v, causal=causal, scale=scale)
+        with jax.named_scope(SCOPE_ATTN_CORE):
+            return reference_attention(q, k, v, causal=causal, scale=scale)
 
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         flash_attention,
@@ -154,12 +160,13 @@ def local_attention(q, k, v, causal: bool = False, scale=None,
     scale = scale if scale is not None else d**-0.5
     # kernel layout is (B, H, T, D); blocks pinned explicitly so the
     # launch grid is the one flash_supported admitted, on every jax
-    o = flash_attention(
-        q.transpose(0, 2, 1, 3),
-        k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3),
-        causal=causal,
-        sm_scale=scale,
-        block_sizes=flash_block_sizes(q.shape[1], k.shape[1]),
-    )
-    return o.transpose(0, 2, 1, 3)
+    with jax.named_scope(SCOPE_ATTN_CORE):
+        o = flash_attention(
+            q.transpose(0, 2, 1, 3),
+            k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3),
+            causal=causal,
+            sm_scale=scale,
+            block_sizes=flash_block_sizes(q.shape[1], k.shape[1]),
+        )
+        return o.transpose(0, 2, 1, 3)

@@ -47,6 +47,12 @@ from __future__ import annotations
 
 import numpy as np
 
+from blendjax.utils.metrics import (
+    KERNEL_TILE_DECODE_SCATTER,
+    KERNEL_TILE_DECODE_SPATIAL,
+    SCOPE_PALETTE_EXPAND,
+)
+
 TILE = 32  # default tile side; must divide both image dims
 
 TILEIDX_SUFFIX = "__tileidx"
@@ -596,15 +602,17 @@ def _lut_expand(packed, palette, bits: int):
     consecutive pixels of the flattened pixel axis, so flattening the
     last two dims restores flat pixel-major x channel order).
     """
+    import jax
     import jax.numpy as jnp
 
-    px = 8 // bits
-    nib = unpack_palette_indices(
-        jnp.arange(256, dtype=jnp.uint8)[:, None], bits, jnp
-    )  # (256, px) index table, built once per jit trace
-    c = palette.shape[-1]
-    lut = palette[nib].reshape(256, px * c)
-    return lut[packed]
+    with jax.named_scope(SCOPE_PALETTE_EXPAND):
+        px = 8 // bits
+        nib = unpack_palette_indices(
+            jnp.arange(256, dtype=jnp.uint8)[:, None], bits, jnp
+        )  # (256, px) index table, built once per jit trace
+        c = palette.shape[-1]
+        lut = palette[nib].reshape(256, px * c)
+        return lut[packed]
 
 
 def expand_palette_frames(packed, palette, bits: int, h: int, w: int,
@@ -1171,13 +1179,17 @@ def _pallas_decode_scatter(ref_tiles, idx, tiles, interpret: bool = False):
             lambda bi, ki, idxp: (bi, idxp[bi, ki], 0, 0),
         ),
     )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, n + 1, 8, lanes), jnp.uint8),
-        input_output_aliases={1: 0},  # basep (after the prefetch arg)
-        interpret=interpret,
-    )(idx, basep, flat_tiles)
+    # the kernel's name, as the call's ``name=`` and as a scope: the
+    # scope puts it on the name stack of the operation in a trace
+    with jax.named_scope(KERNEL_TILE_DECODE_SCATTER):
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((b, n + 1, 8, lanes), jnp.uint8),
+            input_output_aliases={1: 0},  # basep (after the prefetch arg)
+            interpret=interpret,
+            name=KERNEL_TILE_DECODE_SCATTER,
+        )(idx, basep, flat_tiles)
     return out[:, :n].reshape(b, n, ttc)
 
 
@@ -1257,12 +1269,14 @@ def _pallas_decode_spatial(ref_tiles, idx, tiles, shape,
             (1, th, twc), lambda bi, gy, gx, invp: (bi, gy, gx)
         ),
     )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, w * c), jnp.uint8),
-        interpret=interpret,
-    )(inv, ref_img, tiles3)
+    with jax.named_scope(KERNEL_TILE_DECODE_SPATIAL):  # as in the scatter
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((b, h, w * c), jnp.uint8),
+            interpret=interpret,
+            name=KERNEL_TILE_DECODE_SPATIAL,
+        )(inv, ref_img, tiles3)
     return out.reshape(b, h, w, c)
 
 

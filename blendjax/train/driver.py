@@ -83,10 +83,14 @@ class TrainDriver:
     zero when the device keeps up), ``syncs`` (periodic loss fetches).
 
     Device-timeline metrics: each ring entry is timed dispatch ->
-    retirement (the moment the completion poll/fetch observes it done),
-    feeding the ``train.step_device_ms`` histogram — an upper bound on
-    per-step device latency that converges on it while the ring cycles
-    (a finished entry is examined again within one submit). Given
+    observed retirement (the moment the completion poll/fetch sees it
+    done), feeding the ``train.step_device_ms`` histogram. That is an
+    upper bound on per-step device latency and nothing tighter: with
+    two or more steps in flight a step is dispatched while the one
+    ahead of it still runs, so its reading includes the wait behind
+    that step (about ``inflight`` x the device time on a step-bound
+    run). Device time per step comes from a profiler trace
+    (``benchmark/reduce_trace.py``), not from this histogram. Given
     ``flops_per_image`` (hand-fed, or derived by :meth:`build` from
     the device ledger's ``compiled.cost_analysis()`` entries —
     :mod:`blendjax.obs.devledger`) and ``peak_flops`` (explicit, or
@@ -622,7 +626,10 @@ class TrainDriver:
         if not self._pending:
             return self.losses[-1] if self.losses else None
         newest = self._pending[-1]
-        val = float(np.asarray(newest[0]).reshape(-1)[-1])
+        # with driver.ring_wait and driver.loss_sync, the whole of the
+        # host's wait for the device
+        with metrics.span("driver.drain_wait"):
+            val = float(np.asarray(newest[0]).reshape(-1)[-1])
         # the fetch transitively completed every older entry: retire
         # them all (device-timeline accounting + trace terminal stamps)
         while self._pending:

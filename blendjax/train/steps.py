@@ -17,6 +17,11 @@ from flax.training.train_state import TrainState
 
 from blendjax.parallel.sharding import param_sharding_rules
 from blendjax.train.precision import policy_value_and_grad, resolve_policy
+from blendjax.utils.metrics import (
+    SCOPE_DECODE,
+    SCOPE_OPTIMIZER,
+    SCOPE_RESHARD,
+)
 
 
 def make_train_state(
@@ -252,7 +257,8 @@ def make_supervised_step(
             )
             loss = loss_sum / accum_steps
             grads = jax.tree.map(lambda g: g / accum_steps, grad_sum)
-        state = state.apply_gradients(grads=grads)
+        with jax.named_scope(SCOPE_OPTIMIZER):
+            state = state.apply_gradients(grads=grads)
         metrics = {"loss": loss}
         return state, metrics
 
@@ -291,7 +297,8 @@ def _chunk_scan_body(loss_fn, augment, base_rng, policy=None):
             return loss_fn(st, params, batch)
 
         loss, grads = policy_value_and_grad(scalar_loss, st.params, policy)
-        return st.apply_gradients(grads=grads), loss
+        with jax.named_scope(SCOPE_OPTIMIZER):
+            return st.apply_gradients(grads=grads), loss
 
     return body
 
@@ -389,13 +396,16 @@ def make_fused_tile_step(
     def _fused(state, packed, refs, spec, names, geoms, rle):
         from blendjax.ops.tiles import decode_packed_superbatch
 
-        superbatch = decode_packed_superbatch(
-            packed, refs, spec, names, geoms, rle_groups=rle,
-            mesh=mesh, data_axis=data_axis,
-        )
+        with jax.named_scope(SCOPE_DECODE):
+            superbatch = decode_packed_superbatch(
+                packed, refs, spec, names, geoms, rle_groups=rle,
+                mesh=mesh, data_axis=data_axis,
+            )
+        with jax.named_scope(SCOPE_RESHARD):
+            superbatch = pin(superbatch)
         state, losses = jax.lax.scan(
             _chunk_scan_body(loss_fn, augment, base_rng, precision), state,
-            pin(superbatch),
+            superbatch,
         )
         return state, {"loss": losses}
 
@@ -409,12 +419,15 @@ def make_fused_tile_step(
     def _fused_pal(state, packed, spec, pal_groups, rle):
         from blendjax.ops.tiles import decode_packed_pal_superbatch
 
-        superbatch = decode_packed_pal_superbatch(
-            packed, spec, pal_groups, rle
-        )
+        with jax.named_scope(SCOPE_DECODE):
+            superbatch = decode_packed_pal_superbatch(
+                packed, spec, pal_groups, rle
+            )
+        with jax.named_scope(SCOPE_RESHARD):
+            superbatch = pin(superbatch)
         state, losses = jax.lax.scan(
             _chunk_scan_body(loss_fn, augment, base_rng, precision), state,
-            pin(superbatch),
+            superbatch,
         )
         return state, {"loss": losses}
 
@@ -521,7 +534,8 @@ def make_echo_fused_step(
         loss, grads = policy_value_and_grad(
             scalar_loss, state.params, policy
         )
-        state = state.apply_gradients(grads=grads)
+        with jax.named_scope(SCOPE_OPTIMIZER):
+            state = state.apply_gradients(grads=grads)
         return state, {"loss": loss}
 
     jit_kwargs = _sharding_jit_kwargs(

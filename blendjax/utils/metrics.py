@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import contextlib
 import math
+import sys
 import threading
 import time
 from collections import defaultdict, deque
@@ -28,6 +29,46 @@ from blendjax.utils.tg import guard
 # (one log + one dict bump) for the ingest hot path.
 _GAMMA = 2.0 ** 0.125
 _LOG_GAMMA = math.log(_GAMMA)
+
+
+# The device-side vocabulary: the ``jax.named_scope`` names the step's
+# parts carry where they run, so that every device operation in a
+# profiler trace says which part it belongs to (its ``tf_op`` stat is
+# the name stack: ``jit(_fused)/decode/vmap(vmap(palette_expand))/gather``).
+# Forward and backward need no scope of ours: ``jvp(<Model>)`` and
+# ``transpose(jvp(<Model>))`` are already on the stack. Documented in
+# docs/observability.md ("Device scopes"); read by benchmark/trace_scopes.py.
+SCOPE_DECODE = "decode"            # packed bytes -> decoded superbatch
+SCOPE_PALETTE_EXPAND = "palette_expand"  # inside decode: palette -> RGBA
+SCOPE_RESHARD = "reshard"          # mesh path: re-shard the decoded batch
+SCOPE_OPTIMIZER = "optimizer"      # apply_gradients
+SCOPE_ATTN_CORE = "attn_core"      # scores -> softmax -> weighted sum
+STEP_SCOPES = (
+    SCOPE_DECODE, SCOPE_PALETTE_EXPAND, SCOPE_RESHARD, SCOPE_OPTIMIZER,
+    SCOPE_ATTN_CORE,
+)
+# The Pallas decode kernels: each is the ``name=`` of its ``pallas_call``
+# and a scope around the call (inside ``decode``).
+KERNEL_TILE_DECODE_SPATIAL = "tile_decode_spatial"
+KERNEL_TILE_DECODE_SCATTER = "tile_decode_scatter"
+KERNEL_NAMES = (KERNEL_TILE_DECODE_SPATIAL, KERNEL_TILE_DECODE_SCATTER)
+
+
+# ``jax.profiler.TraceAnnotation``, bound the first time a span opens
+# in a process that has already imported jax: the program's spans then
+# lie on the profiler's clock, in the trace's ``/host:CPU`` plane, over
+# the device operations (one Perfetto file, no second export). Producer
+# processes never import jax and pay one ``None`` test and one dict
+# lookup a span; with the profiler off an annotation adds ~0.5 us.
+_TraceAnnotation = None
+
+
+def _bind_trace_annotation():
+    global _TraceAnnotation
+    _TraceAnnotation = getattr(
+        sys.modules.get("jax.profiler"), "TraceAnnotation", None
+    )
+    return _TraceAnnotation
 
 
 class Histogram:
@@ -253,11 +294,17 @@ class Metrics:
 
     @contextlib.contextmanager
     def span(self, name: str):
+        annotate = _TraceAnnotation or _bind_trace_annotation()
+        annotation = annotate(name) if annotate else None
+        if annotation:
+            annotation.__enter__()
         t0 = time.perf_counter()
         try:
             yield
         finally:
             dt = time.perf_counter() - t0
+            if annotation:
+                annotation.__exit__(None, None, None)
             with self._lock:
                 s = self._spans[name]
                 s[0] += 1

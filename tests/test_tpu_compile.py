@@ -222,6 +222,35 @@ def test_fused_tile_step_compiles_with_the_kernel(
     _assert_fits_with_kernel(compiled)
 
 
+def test_fused_tile_step_names_the_decode_kernel(topo, tpu_branches):
+    """In the step compiled for the described v5e the Pallas decode is
+    found by name, not by shape: the custom call's ``op_name`` carries
+    the ``decode`` scope and the kernel's name (what a trace of the chip
+    shows as the operation's ``tf_op``), and the TPU lowering names the
+    instruction after the ``pallas_call``'s ``name=``. A small chunk:
+    the names do not depend on it and the compile takes seconds."""
+    import re
+
+    from blendjax.utils.metrics import (
+        KERNEL_TILE_DECODE_SPATIAL,
+        SCOPE_DECODE,
+    )
+
+    one = SingleDeviceSharding(topo.devices[0])
+    compiled = _lower_fused_tile(
+        make_fused_tile_step(), _abstract_state(CubeRegressor(), one), 2,
+        one, _tile_plan((16, 32)),
+    ).compile()
+    (call,) = [
+        ln for ln in compiled.as_text().splitlines()
+        if "tpu_custom_call" in ln and " custom-call(" in ln
+    ]
+    segments = re.search(r'op_name="([^"]*)"', call).group(1).split("/")
+    assert SCOPE_DECODE in segments
+    assert KERNEL_TILE_DECODE_SPATIAL in segments
+    assert call.strip().startswith(f"%{KERNEL_TILE_DECODE_SPATIAL}")
+
+
 @pytest.fixture
 def mesh4(topo):
     return Mesh(np.array(topo.devices).reshape(4), ("data",))
