@@ -973,11 +973,10 @@ def measure_transformer_row(chunk: int) -> dict:
     # patch tokens (4x the headline row), step-alone only (the live
     # stream is 480x640) — evidences the long-context train path on
     # real hardware in the driver record. attn_backend='auto' resolves
-    # by blendjax.ops.attention's memory-driven policy (measured: the
-    # materialized path stays faster in-model until its saved score
-    # tensors threaten HBM; flash is the enabler beyond, not a
-    # mid-length speedup). remat off: activations fit at this size and
-    # remat measured 31.3 -> 24.8 img/s.
+    # by blendjax.ops.attention's policy (the fused kernel from 24 MiB
+    # of f32 scores a call up, measured on the chip; this shape has
+    # 604 MB). remat off: activations fit at this size and remat
+    # measured 31.3 -> 24.8 img/s.
     import jax.numpy as jnp
 
     from blendjax.models import StreamFormer

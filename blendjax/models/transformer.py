@@ -63,8 +63,8 @@ class MultiHeadAttention(nn.Module):
             # softmax with matmul inputs left in the compute dtype
             # (bf16 on the MXU — f32 matmuls run ~4x slower on v5e and
             # halved the bench transformer row's MFU) and whose flash
-            # backend (TPU, long-context regime) is the Pallas
-            # streaming-softmax kernel; ring_attention upcasts
+            # backend is the fused Pallas kernel with the same
+            # precision; ring_attention upcasts
             # internally only when it actually rings, because its
             # streaming softmax carries running max/sum in the input
             # dtype.
@@ -154,9 +154,9 @@ class StreamFormer(nn.Module):
     moe_every: int = 2  # MoE MLP in every nth block (others stay dense)
     sp_mode: str = "ring"  # sequence-parallel strategy: 'ring' | 'ulysses'
     attn_backend: str = "auto"  # local attention: materialized-scores
-    # XLA path until a call's saved score tensors threaten HBM, Pallas
-    # flash kernel beyond (memory-driven policy, measured in
-    # blendjax.ops.attention)
+    # XLA path for short sequences, the fused Pallas kernel from 24 MiB
+    # of f32 scores a call up on a TPU (the crossover measured on the
+    # chip; table in blendjax.ops.attention)
     remat: bool = False  # rematerialize blocks: ~O(sqrt) activation
     # memory in backprop for long sequences/deep stacks, recompute on the
     # backward pass (jax.checkpoint via nn.remat — HBM for FLOPs)

@@ -41,7 +41,7 @@ def _ulysses_local(q, k, v, *, axis_name: str, causal: bool, scale,
     a2a = functools.partial(jax.lax.all_to_all, axis_name=axis_name, tiled=True)
     qg, kg, vg = (a2a(x, split_axis=2, concat_axis=1) for x in (q, k, v))
     # The local attention here sees the FULL sequence (for its head
-    # slice) — exactly the regime where the flash backend pays: long-T
+    # slice) — exactly the regime where the fused backend pays: long-T
     # Ulysses composes all-to-alls with the Pallas kernel under 'auto'.
     o = local_attention(qg, kg, vg, causal=causal, scale=scale,
                         backend=backend)
@@ -69,11 +69,12 @@ def ulysses_attention(
     per-device local attention after the all-to-all
     (:func:`blendjax.ops.attention.local_attention`). Note the policy
     input there is the POST-all-to-all shape — each device attends the
-    full sequence for H/n heads, so the per-call score residual
-    shrinks by the axis size: ``auto`` (memory-driven) keeps the
-    materialized path until even that per-head-subset residual
-    threatens HBM, and takes the Pallas flash kernel beyond (pass
-    ``backend="flash"`` to force it).
+    full sequence for H/n heads, so the bytes of scores one call would
+    materialise shrink by the axis size: ``auto`` takes the fused
+    kernel when that per-head-subset figure reaches
+    ``FLASH_RESIDUAL_BYTES`` and the full sequence's K/V of a head fit
+    VMEM (16k keys), and the materialized path otherwise (pass
+    ``backend="flash"`` to force the kernel).
     """
     import jax
     from jax.sharding import PartitionSpec as P
@@ -99,7 +100,10 @@ def ulysses_attention(
     )
     from blendjax.parallel.collectives import _shard_map
 
+    # check=False: the fused kernel's pallas_call carries no varying-
+    # mesh-axes annotation, which the VMA checker requires
     f = _shard_map(
-        body, mesh, in_specs=(spec, spec, spec), out_specs=spec
+        body, mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check=False,
     )
     return f(q, k, v)

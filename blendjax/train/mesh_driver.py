@@ -67,6 +67,7 @@ def make_mesh_supervised_step(
     donate: bool = True,
     augment=None,
     augment_rng=None,
+    data_axis: str = "data",
 ):
     """:func:`blendjax.train.make_supervised_step` with the layout made
     explicit: ``in_shardings``/``out_shardings`` are pinned from the
@@ -80,10 +81,11 @@ def make_mesh_supervised_step(
     sharding threaded through, so single-chip and mesh runs can never
     train different math.
     """
-    from blendjax.train.steps import make_supervised_step
+    from blendjax.train.steps import loss_on_mesh, make_supervised_step
 
     return make_supervised_step(
-        loss_fn=loss_fn, donate=donate, augment=augment,
+        loss_fn=loss_on_mesh(loss_fn, mesh, data_axis),
+        donate=donate, augment=augment,
         augment_rng=augment_rng,
         state_sharding=_state_jit_shardings(state, mesh),
     )
@@ -176,7 +178,7 @@ def make_mesh_echo_fused_step(
     identical math."""
     jax = _require_jax()
 
-    from blendjax.train.steps import make_echo_fused_step
+    from blendjax.train.steps import loss_on_mesh, make_echo_fused_step
 
     if data_axis not in mesh.axis_names:
         # same build-time failure as make_mesh_fused_step: a typo'd
@@ -207,7 +209,8 @@ def make_mesh_echo_fused_step(
 
     return make_echo_fused_step(
         reservoir_draw=reservoir.draw,
-        loss_fn=loss_fn, donate=donate, precision=precision,
+        loss_fn=loss_on_mesh(loss_fn, mesh, data_axis),
+        donate=donate, precision=precision,
         state_sharding=_state_jit_shardings(state, mesh),
         buffer_sharding=ring_sharding,
         draw_constraint=_pin_drawn_batch,
@@ -345,6 +348,7 @@ class MeshTrainDriver(TrainDriver):
             step = make_mesh_supervised_step(
                 state, mesh, loss_fn=loss_fn, augment=augment,
                 augment_rng=augment_rng,
+                data_axis=driver_kwargs.get("data_axis", "data"),
             )
         ledger_entry = None
         if aot and not fused and aot_batch is not None:

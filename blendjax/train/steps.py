@@ -116,6 +116,25 @@ def _default_loss(state, params, batch):
     )
 
 
+def loss_on_mesh(loss_fn, mesh, data_axis: str = "data"):
+    """``loss_fn`` (default: the builders' own) traced with the
+    program's mesh declared to the ops below the model: a Pallas kernel
+    reached through the model (the fused attention core) cannot be
+    partitioned by GSPMD and wraps itself in ``shard_map`` over
+    ``data_axis`` instead. ``mesh=None`` returns ``loss_fn`` as given."""
+    loss_fn = loss_fn or _default_loss
+    if mesh is None:
+        return loss_fn
+
+    from blendjax.ops.attention import batch_sharded_over
+
+    def on_mesh(state, params, batch):
+        with batch_sharded_over(mesh, data_axis):
+            return loss_fn(state, params, batch)
+
+    return on_mesh
+
+
 def _sharding_jit_kwargs(state_sharding, n_data_args: int = 1,
                          data_shardings: dict | None = None) -> dict:
     """jit kwargs pinning a state's layout: ``in_shardings``/
@@ -187,9 +206,11 @@ def make_supervised_step(
       ``data``-sharded batch — cross the mesh in bf16 (half the
       bytes), cast back to f32 before the optimizer.
     """
-    del mesh, batch_sharding  # layouts ride on the arrays (see above)
+    # layouts ride on the arrays (see above); the mesh is only declared
+    # to the kernels below the model
+    data_axis = (getattr(batch_sharding, "spec", None) or ("data",))[0]
+    loss_fn = loss_on_mesh(loss_fn, mesh, data_axis)
     base_rng = _resolve_augment_rng(augment, augment_rng)
-    loss_fn = loss_fn or _default_loss
     policy = resolve_policy(precision)
     accum_steps = max(1, int(accum_steps))
 
@@ -381,10 +402,11 @@ def make_fused_tile_step(
     the scan — the mesh path re-shards the decoded fields over the
     batch axis there (``blendjax.train.mesh_driver``). Both default
     off with zero behavior change. ``mesh``/``data_axis`` go to the
-    tile decode (:func:`blendjax.ops.tiles.decode_tile_delta`), which
-    needs them to run its kernel inside a multi-device program.
+    tile decode (:func:`blendjax.ops.tiles.decode_tile_delta`) and,
+    through :func:`loss_on_mesh`, to the attention core: both need them
+    to run their kernels inside a multi-device program.
     """
-    loss_fn = loss_fn or _default_loss
+    loss_fn = loss_on_mesh(loss_fn, mesh, data_axis)
     chunked = make_chunked_supervised_step(
         loss_fn=loss_fn, donate=donate,
         augment=augment, augment_rng=augment_rng,

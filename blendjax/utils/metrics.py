@@ -51,7 +51,13 @@ STEP_SCOPES = (
 # and a scope around the call (inside ``decode``).
 KERNEL_TILE_DECODE_SPATIAL = "tile_decode_spatial"
 KERNEL_TILE_DECODE_SCATTER = "tile_decode_scatter"
-KERNEL_NAMES = (KERNEL_TILE_DECODE_SPATIAL, KERNEL_TILE_DECODE_SCATTER)
+# The fused attention core's forward and backward (inside ``attn_core``).
+KERNEL_FLASH_FWD = "flash_attention_fwd"
+KERNEL_FLASH_BWD = "flash_attention_bwd"
+KERNEL_NAMES = (
+    KERNEL_TILE_DECODE_SPATIAL, KERNEL_TILE_DECODE_SCATTER,
+    KERNEL_FLASH_FWD, KERNEL_FLASH_BWD,
+)
 
 
 # ``jax.profiler.TraceAnnotation``, bound the first time a span opens

@@ -64,6 +64,7 @@ class Sizes:
     former_steps: int = 4
     former: dict | None = None  # StreamFormer kwargs; None = bench's row
     flash_shape: tuple = (4, 3072, 4, 128)
+    attn_shape: tuple = (8, 1200, 12, 64)  # the benchmark's, through auto
     rl_steps: int = 12
     mesh_batches: int = 12    # --four-chips: recorded batches
     mesh_chunk: int = 2
@@ -166,14 +167,12 @@ def phase_kernels(sizes: Sizes, seed: int) -> dict:
     import jax
     import jax.numpy as jnp
 
-    from blendjax.ops.attention import local_attention
     from blendjax.ops.tiles import (
         decode_tile_delta,
         decode_tile_delta_np,
         tile_hw,
         tile_ref,
     )
-    from blendjax.parallel.ring import reference_attention
 
     rng = np.random.default_rng(seed)
     out: dict = {"phase": "kernels"}
@@ -193,18 +192,67 @@ def phase_kernels(sizes: Sizes, seed: int) -> dict:
         took = counters().get(f"tiles.decode_path.{path}", 0) - before
         check(took == 1 or not tpu, f"decode at tile {tile} did not take {path}")
         out[f"decode_{th}x{tw}"] = path if took else "xla_scatter"
-    q, k, v = (
-        jnp.asarray(rng.normal(size=sizes.flash_shape), jnp.bfloat16)
-        for _ in range(3)
+    out["flash_max_abs_diff"] = _attention_diffs(
+        rng, sizes.flash_shape, "flash"
+    )["out"]
+    before = counters().get("attn.path.flash", 0)
+    out["attn_auto_max_abs_diff"] = _attention_diffs(
+        rng, sizes.attn_shape, "auto"
     )
-    flash = local_attention(q, k, v, backend="flash" if tpu else "xla")
-    err = float(jnp.max(jnp.abs(
-        flash.astype(jnp.float32)
-        - reference_attention(q, k, v).astype(jnp.float32)
-    )))
-    check(err < FLASH_ATOL, f"flash vs reference: max abs diff {err}")
-    out["flash_max_abs_diff"] = err
+    took = counters().get("attn.path.flash", 0) - before
+    check(took == 1 or not tpu, f"auto at {sizes.attn_shape} stayed on xla")
     return out
+
+
+def _attention_diffs(rng, shape, backend: str, mesh=None) -> dict:
+    """``local_attention`` against ``reference_attention`` on bf16
+    inputs: max abs diff of the output and of the three gradients, each
+    under FLASH_ATOL. Off the TPU ``flash`` is the kernel interpreted
+    and ``auto`` the XLA path. ``mesh``: batch-sharded over ``data``,
+    declared as the mesh step builders declare it."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from blendjax.ops.attention import batch_sharded_over, local_attention
+    from blendjax.parallel.ring import reference_attention
+
+    q, k, v, w = (
+        jnp.asarray(rng.normal(size=shape), dt)
+        for dt in (jnp.bfloat16,) * 3 + (jnp.float32,)
+    )
+    if mesh is not None:
+        q, k, v, w = jax.device_put(
+            (q, k, v, w), NamedSharding(mesh, PartitionSpec("data"))
+        )
+
+    def run(attend):
+        def loss(q, k, v):
+            o = attend(q, k, v)
+            return jnp.sum(o.astype(jnp.float32) * w), o
+
+        (_, o), grads = jax.jit(
+            jax.value_and_grad(loss, (0, 1, 2), has_aux=True)
+        )(q, k, v)
+        return o, *grads
+
+    def attend(q, k, v):
+        with batch_sharded_over(mesh):
+            return local_attention(q, k, v, backend=backend)
+
+    diffs = {
+        name: float(jnp.max(jnp.abs(
+            got.astype(jnp.float32) - want.astype(jnp.float32)
+        )))
+        for name, got, want in zip(
+            ("out", "dq", "dk", "dv"), run(attend), run(reference_attention)
+        )
+    }
+    check(
+        max(diffs.values()) < FLASH_ATOL,
+        f"{backend} attention at {shape} vs reference: {diffs}",
+    )
+    return diffs
 
 
 # -- phase 2: the headline path ----------------------------------------------
@@ -588,6 +636,21 @@ def phase_four_chips(sizes: Sizes, seed: int) -> dict:
                     float(losses[0]), float(losses[-1])
                 )
                 out["legs"].append(leg)
+    # the fused attention core per shard: the benchmark's shape a chip,
+    # batch-sharded, the mesh declared as the step builders declare it
+    from blendjax.parallel import create_mesh
+
+    before = counters().get("attn.path.shard_map", 0)
+    b, *rest = sizes.attn_shape
+    out["attn_max_abs_diff"] = _attention_diffs(
+        np.random.default_rng(seed), (4 * b, *rest),
+        "auto" if on_tpu() else "flash",
+        mesh=create_mesh({"data": 4}, devices=devices),
+    )
+    check(
+        counters().get("attn.path.shard_map", 0) == before + 1,
+        "attention did not run per shard",
+    )
     return out
 
 

@@ -1,0 +1,68 @@
+#!/usr/bin/env python
+"""The attention core alone, forward + backward, materialised against
+fused, on the chip: the measurement ``FLASH_RESIDUAL_BYTES`` in
+``blendjax/ops/attention.py`` is set from.
+
+    python scripts/attn_core_bench.py [B,T,H,D ...]
+
+One JSON line a shape and backend: ms a call (12 chained calls a
+dispatch, bf16, host clock around ``block_until_ready``) and the bytes
+of scores the ``auto`` policy reads. Exits 2 off a TPU: a CPU time says
+nothing about either path.
+"""
+
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+
+from blendjax.ops.attention import local_attention, scores_residual_bytes
+
+SHAPES = [(8, 197, 12, 64), (8, 768, 4, 128), (8, 1200, 12, 64),
+          (4, 3072, 4, 128)]
+LAYERS, CALLS = 12, 10
+
+
+def ms_per_call(backend, q, k, v, w):
+    def loss(q, k, v):
+        for _ in range(LAYERS):
+            q = local_attention(q, k, v, backend=backend)
+        return jnp.sum(q.astype(jnp.float32) * w)
+
+    step = jax.jit(jax.value_and_grad(loss, (0, 1, 2)))
+    for _ in range(2):  # compile, then one warm dispatch
+        jax.block_until_ready(step(q, k, v))
+    start = time.perf_counter()
+    for _ in range(CALLS):
+        out = step(q, k, v)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - start) / CALLS / LAYERS * 1e3
+
+
+def main(argv):
+    if jax.default_backend() != "tpu":
+        print("attn_core_bench: no TPU here", file=sys.stderr)
+        return 2
+    shapes = [tuple(int(n) for n in a.split(",")) for a in argv] or SHAPES
+    for shape in shapes:
+        keys = jax.random.split(jax.random.key(0), 4)
+        q, k, v = (jax.random.normal(key, shape, jnp.bfloat16)
+                   for key in keys[:3])
+        w = jax.random.normal(keys[3], shape, jnp.float32)
+        for backend in ("xla", "flash"):
+            print(json.dumps({
+                "shape": shape, "backend": backend,
+                "scores_bytes": scores_residual_bytes(q),
+                "ms_per_call": ms_per_call(backend, q, k, v, w),
+                "device": jax.devices()[0].device_kind,
+            }), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))

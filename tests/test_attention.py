@@ -1,8 +1,9 @@
 """Local-attention backend dispatch (blendjax.ops.attention).
 
-The flash kernel itself is TPU hardware (`-m tpu` tier); the dispatch
-contract — explicit-request failures, auto fallback, the memory-driven
-auto policy — is hermetic.
+The fused kernel runs here in Pallas interpreter mode, so the whole
+flash path — pad, mask, kernel forward and backward, slice — is compared
+with ``reference_attention`` on the CPU; its speed and the chip's
+compiler are tests/test_tpu_compile.py's and chip_smoke.py's.
 """
 
 import numpy as np
@@ -11,39 +12,70 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
+from blendjax.ops import attention as A  # noqa: E402
 from blendjax.ops.attention import (  # noqa: E402
+    FLASH_MAX_KV,
     FLASH_RESIDUAL_BYTES,
+    FLASH_TILE_ELEMS,
     auto_picks_flash,
+    batch_sharded_over,
+    flash_block_sizes,
     flash_supported,
     local_attention,
     scores_residual_bytes,
 )
 from blendjax.parallel.ring import reference_attention  # noqa: E402
+from blendjax.utils.metrics import metrics  # noqa: E402
 
 
-def _qkv(t=128, b=2, h=2, d=8, dtype=jnp.float32):
+def _qkv(t=128, b=2, h=2, d=64, dtype=jnp.float32, t_kv=None):
     k = jax.random.key(0)
     return tuple(
-        jax.random.normal(jax.random.fold_in(k, i), (b, t, h, d), dtype)
+        jax.random.normal(
+            jax.random.fold_in(k, i),
+            (b, t if i == 0 else (t_kv or t), h, d), dtype,
+        )
         for i in range(3)
     )
 
 
-def test_flash_unsupported_off_tpu():
+class _Shape:
+    """An input as the policy sees it: a shape."""
+
+    def __init__(self, *shape):
+        self.shape, self.ndim = shape, len(shape)
+
+
+@pytest.fixture
+def one_tpu(monkeypatch):
+    """What the policy asks of the process: a TPU backend, one device."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+
+
+def _attn_counts():
+    return {
+        k: v for k, v in metrics.report()["counters"].items()
+        if k.startswith("attn.path.")
+    }
+
+
+def test_auto_stays_on_xla_off_tpu():
     q, _, _ = _qkv()
     if jax.default_backend() != "tpu":
-        assert not flash_supported(q)
+        assert flash_supported(q)  # the shape is eligible; the backend is not
         assert not auto_picks_flash(q)
+        assert not auto_picks_flash(_Shape(8, 1200, 12, 64))
 
 
 def test_explicit_flash_raises_when_unsupported():
     """Same contract as the tile decode's use_pallas: an explicit
     backend request must fail loudly, never silently measure xla."""
-    if jax.default_backend() == "tpu":
-        pytest.skip("flash is supported here")
-    q, k, v = _qkv()
-    with pytest.raises(ValueError, match="flash attention backend"):
-        local_attention(q, k, v, backend="flash")
+    for bad in (dict(d=48), dict(h=3, d=64), dict(d=256)):
+        q, k, v = _qkv(**bad)
+        assert not flash_supported(q, k)
+        with pytest.raises(ValueError, match="flash attention backend"):
+            local_attention(q, k, v, backend="flash")
 
 
 def test_unknown_backend_rejected():
@@ -53,73 +85,184 @@ def test_unknown_backend_rejected():
 
 
 def test_flash_support_checks_kv_length_too():
-    """Cross-attention with an un-tileable KV length must not dispatch
-    to the kernel (auto falls back; explicit flash raises)."""
-    q, _, _ = _qkv(t=128)
-    k_bad, _, _ = _qkv(t=120)
-    assert not flash_supported(q, k_bad)
+    """Any length is padded and masked, the KV side of cross-attention
+    too; what the kernel cannot take is more keys than one head keeps
+    in VMEM."""
+    assert flash_supported(_Shape(1, 128, 2, 64), _Shape(1, 120, 2, 64))
+    assert flash_supported(_Shape(1, 128, 2, 64), _Shape(1, FLASH_MAX_KV, 2, 64))
+    assert not flash_supported(
+        _Shape(1, 128, 2, 64), _Shape(1, FLASH_MAX_KV + 1, 2, 64)
+    )
+    assert not flash_supported(_Shape(128, 2, 64))
 
 
-def test_flash_block_sizes_pinned_and_consistent():
-    """Every flash call passes an EXPLICIT BlockSizes built from
-    FLASH_BLOCK (kernel defaults drifting across jax upgrades change
-    nothing): all block edges are pinned, none exceeds FLASH_BLOCK,
-    and any shape flash_supported admits tiles the pinned grid
-    exactly — eligibility and launch share one source of truth."""
-    from blendjax.ops.attention import FLASH_BLOCK, flash_block_sizes
-
-    for t_q, t_kv in [(128, 128), (3072, 3072), (256, 1024), (64, 128)]:
-        bs = flash_block_sizes(t_q, t_kv)
-        edges = {
-            name: getattr(bs, name)
-            for name in (
-                "block_q", "block_k_major", "block_k", "block_b",
-                "block_q_major_dkv", "block_k_major_dkv", "block_k_dkv",
-                "block_q_dkv", "block_k_major_dq", "block_k_dq",
-                "block_q_dq",
-            )
-        }
-        assert all(v is not None for v in edges.values()), edges
-        assert all(v <= FLASH_BLOCK for v in edges.values()), edges
-        if t_q % FLASH_BLOCK == 0 and t_kv % FLASH_BLOCK == 0:
-            # the admitted regime: every q-edge tiles t_q, every
-            # k-edge tiles t_kv — the grid flash_supported promised
-            for name, v in edges.items():
-                if name == "block_b":
-                    continue
-                t = t_q if name.startswith("block_q") else t_kv
-                assert t % v == 0, (name, v, t_q, t_kv)
+@pytest.mark.parametrize(
+    "t_q, t_kv",
+    [(64, 64), (130, 130), (197, 197), (1200, 1200), (3072, 3072),
+     (256, 1000), (5000, 16384)],
+)
+def test_flash_block_sizes_from_the_shape(t_q, t_kv):
+    """Eligibility and launch share one source of truth, computed from
+    the shape: the blocks tile the padded lengths, the padding is less
+    than one block (q) and one lane tile (kv), and the score tile stays
+    within FLASH_TILE_ELEMS wherever a 128-row block allows."""
+    block_q, padded_q, padded_kv = flash_block_sizes(t_q, t_kv)
+    assert block_q % 128 == 0 and padded_kv % 128 == 0
+    assert padded_q % block_q == 0
+    assert t_q <= padded_q < t_q + block_q
+    assert t_kv <= padded_kv < t_kv + 128
+    assert block_q == 128 or block_q * padded_kv <= FLASH_TILE_ELEMS
 
 
-def test_scores_residual_bytes_and_auto_threshold():
-    """The auto policy is memory-driven: f32 prob-residual bytes per
-    call against FLASH_RESIDUAL_BYTES (in-model, the materialized path
-    measured FASTER than the kernel at every length HBM absorbs —
-    docs in the module header — so flash engages only where xla
-    becomes infeasible)."""
-    class Q:
-        ndim = 4
+def test_flash_block_sizes_at_the_benchmark_shape():
+    assert flash_block_sizes(1200, 1200) == (640, 1280, 1280)
 
-        def __init__(self, b, t, h, d):
-            self.shape = (b, t, h, d)
 
-    # f32 probs saved for backward (measured ~600 MB at this shape)
-    assert scores_residual_bytes(Q(4, 3072, 4, 128)) == 4 * 4 * 3072**2 * 4
-    # ~604 MB at the bench longseq shape: under the 2 GiB bar
-    assert scores_residual_bytes(Q(4, 3072, 4, 128)) < FLASH_RESIDUAL_BYTES
-    # T=16k at B=1, H=4 (the module docstring's OOM example): ~4.3 GB
-    assert scores_residual_bytes(Q(1, 16384, 4, 128)) > FLASH_RESIDUAL_BYTES
+@pytest.mark.parametrize(
+    "shape, flash",
+    [((8, 197, 12, 64), False), ((8, 768, 4, 128), True),
+     ((8, 1200, 12, 64), True), ((4, 3072, 4, 128), True),
+     ((8, 256, 12, 64), True), ((8, 512, 4, 128), True),
+     ((8, 384, 12, 64), True), ((8, 64, 4, 128), False)],
+)
+def test_scores_residual_bytes_and_auto_threshold(one_tpu, shape, flash):
+    """The auto policy at the four shapes ISSUE 26 named, three of the
+    four measured between them (module docstring) and the rehearsal's
+    64 tokens: bytes of f32 scores a call against
+    FLASH_RESIDUAL_BYTES, on a TPU."""
+    b, t, h, _ = shape
+    assert scores_residual_bytes(_Shape(*shape)) == b * h * t * t * 4
+    assert (
+        scores_residual_bytes(_Shape(*shape)) >= FLASH_RESIDUAL_BYTES
+    ) == flash
+    assert auto_picks_flash(_Shape(*shape)) == flash
 
 
 @pytest.mark.parametrize("backend", ["auto", "xla"])
 def test_dispatch_matches_reference_off_tpu(backend):
-    """Off-TPU, every backend choice resolves to the xla path."""
+    """Off-TPU, auto resolves to the xla path — and says so."""
     q, k, v = _qkv()
+    before = _attn_counts().get("attn.path.xla", 0)
     out = local_attention(q, k, v, backend=backend)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(reference_attention(q, k, v)),
         atol=1e-6,
     )
+    assert _attn_counts()["attn.path.xla"] == before + 1
+
+
+def _loss_and_grads(fn, q, k, v, w):
+    def loss(q, k, v):
+        return jnp.sum(fn(q, k, v).astype(jnp.float32) * w)
+
+    return jax.value_and_grad(loss, (0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize(
+    "t, t_kv, causal",
+    [(130, None, False), (197, None, False), (1200, None, False),
+     (130, None, True), (64, 300, False), (128, None, False)],
+    ids=["T130", "T197", "T1200", "T130-causal", "cross-64x300",
+         "T128-unpadded"],
+)
+def test_flash_pad_and_mask_matches_reference(t, t_kv, causal):
+    """The pad-and-mask path equals reference_attention, output and the
+    three gradients, at lengths no block tiles."""
+    b = 1 if t > 1000 else 2
+    q, k, v = _qkv(t=t, t_kv=t_kv, b=b)
+    w = jax.random.normal(jax.random.key(9), q.shape, jnp.float32)
+    before = _attn_counts().get("attn.path.flash", 0)
+    loss, grads = _loss_and_grads(
+        lambda *a: local_attention(*a, causal=causal, backend="flash"),
+        q, k, v, w,
+    )
+    assert _attn_counts()["attn.path.flash"] == before + 1
+    want, want_grads = _loss_and_grads(
+        lambda *a: reference_attention(*a, causal=causal), q, k, v, w
+    )
+    np.testing.assert_allclose(loss, want, rtol=1e-5)
+    for got, ref in zip(grads, want_grads):
+        assert got.shape == ref.shape
+        np.testing.assert_allclose(got, ref, atol=5e-6)
+
+
+@pytest.mark.parametrize("h, d", [(1, 128), (4, 32)], ids=["D128", "D32"])
+def test_flash_head_widths_that_fill_the_lanes(h, d):
+    """One 128-wide head a block, and four 32-wide ones."""
+    q, k, v = _qkv(t=130, b=1, h=h, d=d)
+    w = jax.random.normal(jax.random.key(9), q.shape, jnp.float32)
+    loss, grads = _loss_and_grads(
+        lambda *a: local_attention(*a, backend="flash"), q, k, v, w
+    )
+    want, want_grads = _loss_and_grads(reference_attention, q, k, v, w)
+    np.testing.assert_allclose(loss, want, rtol=1e-5)
+    for got, ref in zip(grads, want_grads):
+        np.testing.assert_allclose(got, ref, atol=5e-6)
+
+
+def test_flash_bf16_keeps_the_reference_precision():
+    """bf16 operands, f32 statistics: against the f32 reference the
+    kernel is no further off than the materialised bf16 path."""
+    q, k, v = _qkv(t=200, dtype=jnp.bfloat16)
+    exact = reference_attention(*(x.astype(jnp.float32) for x in (q, k, v)))
+    err = {
+        backend: float(jnp.max(jnp.abs(
+            local_attention(q, k, v, backend=backend).astype(jnp.float32)
+            - exact
+        )))
+        for backend in ("flash", "xla")
+    }
+    assert err["flash"] <= 2 * err["xla"], err
+
+
+def test_flash_runs_per_batch_shard_under_a_declared_mesh():
+    """A mesh declared while the model is traced wraps the kernel in
+    shard_map over the batch axis: same numbers, batch-sharded
+    gradients, no all-gather of q/k/v in the compiled program."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+    by_batch = NamedSharding(mesh, P("data"))
+    q, k, v = (jax.device_put(x, by_batch) for x in _qkv(t=130, b=4))
+    w = jnp.ones(q.shape, jnp.float32)
+
+    def run(backend):
+        def fn(q, k, v):
+            with batch_sharded_over(mesh, "data"):
+                return local_attention(q, k, v, backend=backend)
+
+        return jax.jit(lambda *a: _loss_and_grads(fn, *a, w))
+
+    before = _attn_counts().get("attn.path.shard_map", 0)
+    loss, grads = run("flash")(q, k, v)
+    assert _attn_counts()["attn.path.shard_map"] == before + 1
+    want, want_grads = run("xla")(q, k, v)
+    np.testing.assert_allclose(loss, want, rtol=1e-5)
+    for got, ref in zip(grads, want_grads):
+        np.testing.assert_allclose(got, ref, atol=5e-6)
+        assert got.sharding.spec == P("data")
+    assert "all-gather" not in run("flash").lower(q, k, v).compile().as_text()
+
+
+def test_auto_takes_the_kernel_only_where_it_knows_the_program(monkeypatch):
+    """One device: bare. A declared mesh: per shard, if its batch axis
+    divides the batch. Several devices and nothing declared (a jit with
+    shardings, a model's init on a mesh): the lowering would refuse a
+    bare kernel, so auto keeps XLA."""
+    from jax.sharding import Mesh
+
+    shape = _Shape(8, 1200, 12, 64)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert jax.device_count() > 1 and not auto_picks_flash(shape)
+    mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+    with batch_sharded_over(mesh, "data"):
+        assert auto_picks_flash(shape)
+        assert not auto_picks_flash(_Shape(6, 1200, 12, 64))
+    with batch_sharded_over(Mesh(np.array(jax.devices()[:1]), ("data",))):
+        assert auto_picks_flash(_Shape(6, 1200, 12, 64))
+    assert A._PROGRAM_MESH.get() is None
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    assert auto_picks_flash(shape)
 
 
 @pytest.mark.tpu
@@ -128,13 +271,10 @@ def test_flash_matches_reference_on_tpu():
     (run with BLENDJAX_TEST_TPU=1 pytest -m tpu)."""
     # self-skip beats relying on the marker filter: a pytest invocation
     # overriding -m (e.g. `-m 'not slow'`) runs this on the CPU mesh,
-    # where the kernel is structurally unsupported
-    import jax
-
+    # where the compiled kernel is not what runs
     if jax.default_backend() != "tpu":
-        pytest.skip("flash kernel needs a real TPU")
-    q, k, v = _qkv(t=1024, h=4, d=128, dtype=jnp.bfloat16)
-    assert flash_supported(q)
+        pytest.skip("the compiled kernel needs a real TPU")
+    q, k, v = _qkv(t=1200, h=4, d=64, dtype=jnp.bfloat16)
     for causal in (False, True):
         out = local_attention(q, k, v, causal=causal, backend="flash")
         ref = reference_attention(q, k, v, causal=causal)
@@ -145,11 +285,3 @@ def test_flash_matches_reference_on_tpu():
         # bar is a few bf16 ulps at the output magnitudes (~2-4 on the
         # causal path's early rows, where one ulp is 2^-6)
         assert diff < 2e-2, (causal, diff)
-    # auto at this (small-residual) shape takes the xla path — the
-    # memory-driven policy — and still matches
-    out_auto = local_attention(q, k, v, backend="auto")
-    np.testing.assert_allclose(
-        np.asarray(out_auto.astype(jnp.float32)),
-        np.asarray(reference_attention(q, k, v).astype(jnp.float32)),
-        atol=2e-2,
-    )

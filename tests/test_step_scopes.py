@@ -40,6 +40,8 @@ from blendjax.train import (
 )
 from blendjax.train.mesh_driver import MeshTrainDriver
 from blendjax.utils.metrics import (
+    KERNEL_FLASH_BWD,
+    KERNEL_FLASH_FWD,
     KERNEL_NAMES,
     KERNEL_TILE_DECODE_SCATTER,
     KERNEL_TILE_DECODE_SPATIAL,
@@ -79,9 +81,10 @@ def _state(model):
     )
 
 
-def _streamformer():
-    model = StreamFormer(patch=16, dim=32, depth=1, num_heads=2,
-                         num_outputs=16)
+def _streamformer(attn_backend="auto"):
+    # two heads of 64: one 128-lane block of the fused kernel
+    model = StreamFormer(patch=16, dim=128, depth=1, num_heads=2,
+                         num_outputs=16, attn_backend=attn_backend)
 
     def loss_fn(state, params, batch):
         pred = state.apply_fn({"params": params}, batch["image"])
@@ -115,11 +118,14 @@ def _lower_fused_tile(step, state):
     )
 
 
-@pytest.mark.parametrize("which", ["cnn", "streamformer"])
+@pytest.mark.parametrize("which", ["cnn", "streamformer", "flash"])
 def test_fused_tile_step_names_its_parts(which):
+    """``flash``: the fused attention core's backward is a custom_vjp's,
+    traced apart from its forward, and still reads under ``attn_core``
+    inside ``transpose(jvp(<Model>))``."""
     model, loss_fn = (
         (CubeRegressor(features=(4,)), None) if which == "cnn"
-        else _streamformer()
+        else _streamformer("flash" if which == "flash" else "auto")
     )
     step = make_fused_tile_step(loss_fn=loss_fn)
     names = op_names(_lower_fused_tile(step, _state(model)).compile())
@@ -137,8 +143,15 @@ def test_fused_tile_step_names_its_parts(which):
     # forward and backward need no scope of ours
     assert any(f"/jvp({cls})/" in n for n in names)
     assert any(f"/transpose(jvp({cls}))/" in n for n in names)
-    if which == "streamformer":
+    if which != "cnn":
         core = under(names, SCOPE_ATTN_CORE)
+        kernels = {KERNEL_FLASH_FWD, KERNEL_FLASH_BWD}
+        assert {
+            k for k in kernels if under(core, k)
+        } == (kernels if which == "flash" else set())
+        assert all(
+            "transpose(jvp(" in n for n in under(core, KERNEL_FLASH_BWD)
+        )
         assert any("transpose(jvp(" in n for n in core)
         assert any(
             "jvp(" in n and "transpose(" not in n for n in core
@@ -214,7 +227,9 @@ def test_pallas_decode_kernels_carry_their_names(monkeypatch, tile, kernel):
     (call,) = calls(jaxpr.jaxpr)
     said = str(call.params.get("name_and_src_info", call.params.get("name")))
     assert said.split(" ")[0] == kernel
-    (other,) = set(KERNEL_NAMES) - {kernel}
+    (other,) = {
+        KERNEL_TILE_DECODE_SPATIAL, KERNEL_TILE_DECODE_SCATTER
+    } - {kernel}
     assert other not in str(jaxpr)
 
 

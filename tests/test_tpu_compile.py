@@ -152,25 +152,68 @@ def test_decode_kernel_compiles(topo, tpu_branches, tile, batch):
     _assert_fits_with_kernel(compiled)
 
 
-@pytest.mark.parametrize(
-    "shape", [(4, 3072, 4, 128), (8, 768, 4, 128)], ids=["T3072", "T768"]
-)
-def test_flash_attention_fwd_bwd_compiles(topo, tpu_branches, shape):
-    """The library flash kernel under the repo's pinned block sizes, at
-    the bench's longseq and live-row attention shapes (bf16)."""
+def _attn_loss(backend, causal=False):
     from blendjax.ops.attention import local_attention
 
-    one = SingleDeviceSharding(topo.devices[0])
-
     def loss(q, k, v):
-        out = local_attention(q, k, v, backend="flash")
+        out = local_attention(q, k, v, causal=causal, backend=backend)
         return jnp.sum(out.astype(jnp.float32))
 
-    q = _sds(shape, jnp.bfloat16, one)
-    compiled = jax.jit(
-        jax.value_and_grad(loss, argnums=(0, 1, 2))
-    ).lower(q, q, q).compile()
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+
+
+@pytest.mark.parametrize(
+    "shape, causal",
+    [
+        ((4, 3072, 4, 128), False), ((8, 768, 4, 128), False),
+        ((2, 1200, 2, 64), True),
+        pytest.param((1, 16384, 4, 128), False, marks=pytest.mark.slow),
+    ],
+    ids=["T3072", "T768", "T1200-causal", "T16384"],
+)
+def test_flash_attention_fwd_bwd_compiles(topo, tpu_branches, shape, causal):
+    """The fused kernel under the blocks ``flash_block_sizes`` computes
+    from the shape, at the bench's longseq and live-row attention
+    shapes, a padded causal one, and the most keys it admits (bf16)."""
+    q = _sds(shape, jnp.bfloat16, SingleDeviceSharding(topo.devices[0]))
+    compiled = _attn_loss("flash", causal).lower(q, q, q).compile()
     _assert_fits_with_kernel(compiled)
+
+
+def test_auto_attention_at_the_benchmark_shape_is_fused(
+    topo, tpu_branches, monkeypatch
+):
+    """(8, 1200, 12, 64) bf16 through ``auto`` on a one-chip machine:
+    both kernels inside by name, the backward's under the ``attn_core``
+    scope too, and less temporary memory than the materialised path."""
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    from blendjax.utils.metrics import (
+        KERNEL_FLASH_BWD,
+        KERNEL_FLASH_FWD,
+        SCOPE_ATTN_CORE,
+    )
+
+    q = _sds((8, 1200, 12, 64), jnp.bfloat16,
+             SingleDeviceSharding(topo.devices[0]))
+    auto = _attn_loss("auto").lower(q, q, q).compile()
+    text = _assert_fits_with_kernel(auto)
+    op_names = {
+        ln.split("=")[0].strip(): ln.split('op_name="')[1].split('"')[0]
+        for ln in text.splitlines() if "tpu_custom_call" in ln
+    }
+    fwd, bwd = (
+        next(op for call, op in op_names.items()
+             if call.startswith(f"%{kernel}"))
+        for kernel in (KERNEL_FLASH_FWD, KERNEL_FLASH_BWD)
+    )
+    assert SCOPE_ATTN_CORE in fwd and "transpose(" not in fwd, fwd
+    assert SCOPE_ATTN_CORE in bwd and "transpose(jvp(" in bwd, bwd
+    xla = _attn_loss("xla").lower(q, q, q).compile()
+    _assert_fits_with_kernel(xla, kernel=False)
+    assert (
+        auto.memory_analysis().temp_size_in_bytes
+        < xla.memory_analysis().temp_size_in_bytes / 2
+    )
 
 
 def test_gamma_normalize_compiles(topo):
@@ -291,6 +334,44 @@ def test_four_chip_sharded_decode_keeps_the_kernel(topo, tpu_branches, mesh4):
         _sds((B, cap, th, tw, C), jnp.uint8, by_batch),
     ).compile()
     _assert_fits_with_kernel(compiled)
+
+
+def test_four_chip_attention_runs_per_shard(topo, tpu_branches, mesh4):
+    """Data-parallel at batch 32 with the mesh declared, as the mesh
+    step builders do: the kernel survives partitioning (shard_map over
+    the batch axis), q/k/v are not gathered, and the gradient of a
+    replicated parameter still has its all-reduce."""
+    from blendjax.ops.attention import batch_sharded_over, local_attention
+
+    def loss(scale, q, k, v):
+        with batch_sharded_over(mesh4, "data"):
+            out = local_attention(q * scale, k, v)
+        return jnp.sum(out.astype(jnp.float32))
+
+    q = _sds((32, 1200, 12, 64), jnp.bfloat16, NamedSharding(mesh4, P("data")))
+    scale = _sds((64,), jnp.bfloat16, NamedSharding(mesh4, P()))
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        scale, q, q, q
+    ).compile()
+    text = _assert_fits_with_kernel(compiled)
+    assert "all-gather" not in text
+    assert "all-reduce(" in text or "all-reduce-start(" in text
+    assert "bf16[8,1280,768]" in text  # one shard, padded, heads in lanes
+
+
+def test_undeclared_attention_in_a_partitioned_program(
+    topo, tpu_branches, mesh4
+):
+    """Several devices and no ``batch_sharded_over``: ``auto`` keeps the
+    XLA path, which GSPMD partitions; an explicit ``flash`` is a bare
+    custom call and the lowering refuses it — it does not quietly
+    gather."""
+    q = _sds((32, 1200, 12, 64), jnp.bfloat16, NamedSharding(mesh4, P("data")))
+    _assert_fits_with_kernel(
+        _attn_loss("auto").lower(q, q, q).compile(), kernel=False
+    )
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        _attn_loss("flash").lower(q, q, q)
 
 
 @pytest.mark.slow

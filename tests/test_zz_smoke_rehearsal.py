@@ -23,7 +23,7 @@ TINY = chip_smoke.Sizes(
     shape=(64, 64), batch=4, chunk=2, tile_capacity="8", producers=2,
     cnn_steps=4, former_steps=2,
     former=dict(patch=8, dim=32, depth=1, num_heads=4, num_outputs=16),
-    flash_shape=(1, 128, 2, 32), rl_steps=4, mesh_batches=4, mesh_chunk=2,
+    flash_shape=(1, 128, 2, 64), attn_shape=(1, 200, 2, 64), rl_steps=4, mesh_batches=4, mesh_chunk=2,
 )
 
 
