@@ -8,9 +8,13 @@ metric lives in a file of its own that is found by name:
                                precision, driver arguments, loss band
     traffic/<traffic>.json     kind (live | replay), producer arguments,
                                message batch, counts
-    losses/<loss>.py           ``loss_fn(state, params, batch)``
+    losses/<loss>.py           ``loss_fn(state, params, batch)`` and its
+                               plain float32 form for the reference,
+                               ``reference_loss(prediction, labels, (h, w))``
     references/<model>.py      the plain float32 forward of that model
     flops/<model>.py           required operations from shapes
+    flops/kernels/<family>.py  the work a kernel's call requires, from
+                               the same shapes, for its roofline share
     layer_metrics/<metric>.json + readers/<reader>.py
 
 so a later PR adds a cell by adding files and entries, never by editing
@@ -55,7 +59,7 @@ def load_module(kind: str, name: str):
     if not os.path.exists(path):
         raise FileNotFoundError(f"no {kind[:-1]} named {name!r}: {path}")
     spec = importlib.util.spec_from_file_location(
-        f"benchmark_{kind}_{name.replace('.', '_')}", path
+        f"benchmark_{kind}_{name}".replace(".", "_").replace("/", "_"), path
     )
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
@@ -225,6 +229,9 @@ class Cell:
     def loss_fn(self):
         return load_module("losses", self.config["loss"]).loss_fn
 
+    def reference_loss(self):
+        return load_module("losses", self.config["loss"]).reference_loss
+
     def optimizer(self):
         import optax
 
@@ -259,21 +266,30 @@ class Cell:
         layout = resolve_layout(self.config["layout_4chips"])
         return create_mesh(layout.mesh_axes(), devices=list(devices))
 
-    def make_state(self, model, seed: int, mesh=None):
+    def state_fn(self, model, mesh=None):
+        """``init_fn`` jitted for one chip or, with the layout's
+        shardings, for ``mesh``. One program: called twice with one key it
+        gives the same state twice, which is how the reference and
+        production start from the same parameters without both being on
+        the device at once."""
         import jax
 
         from blendjax.parallel.sharding import resolve_rules, state_shardings
 
         init = self.init_fn(model)
-        key = jax.random.key(seed)
         if mesh is None:
-            return jax.jit(init)(key)
+            return jax.jit(init)
         layout = self.config["layout_4chips"]
         shardings = state_shardings(
-            jax.eval_shape(init, key), mesh=mesh,
+            jax.eval_shape(init, jax.random.key(0)), mesh=mesh,
             rules=resolve_rules(layout=layout, model=model),
         )
-        return jax.jit(init, out_shardings=shardings)(key)
+        return jax.jit(init, out_shardings=shardings)
+
+    def make_state(self, model, seed: int, mesh=None):
+        import jax
+
+        return self.state_fn(model, mesh)(jax.random.key(seed))
 
     def make_step(self, state, mesh=None):
         """The fused decode+step the cell dispatches."""

@@ -9,7 +9,8 @@ group's shapes from the real pipeline, and hands them with the abstract
 train state to the TPU compiler: one device of a ``v5e:2x2`` for a
 one-chip cell, the cell's mesh over all four for a four-chip cell. It
 prints ``memory_analysis()`` per device, whether the Pallas decode
-kernel is in the program, and which collectives the compiler put in.
+kernel is in the program, which collectives the compiler put in, and
+what the reference check will hold at its peak (``reference_stage``).
 
 Nothing runs on a TPU: what this prints are the compiler's byte counts,
 never a time. It is what settles ``attn_backend``/``remat`` for a
@@ -36,14 +37,54 @@ for p in (ROOT, HERE):
 V5E_HBM_BYTES = 16 * 2**30
 
 
-def rehearse(workload: str, seed: int) -> dict:
+def reference_stage(cell, model, device) -> dict:
+    """What the reference check will hold on ``device`` at its peak: the
+    20 bytes a parameter of ``reference.reference_losses`` and the
+    temporaries of its loss-and-gradient program over one micro-batch,
+    float32 at ``highest``, as the TPU compiler counts them."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import SingleDeviceSharding
+
+    import cells
+    import reference
+
+    placed = SingleDeviceSharding(device)
+    params = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=placed),
+        jax.eval_shape(cell.init_fn(model), jax.random.key(0)).params,
+    )
+    grad_fn = reference.loss_and_grad(
+        cells.load_module("references", cell.model_class()).forward,
+        cell.config["model"]["kwargs"], cell.reference_loss(),
+    )
+    rows = int(cell.config["reference_check"]["microbatch"])
+    images = jax.ShapeDtypeStruct(
+        (rows, *cell.shape, cell.channels), jnp.uint8, sharding=placed
+    )
+    xy = jax.ShapeDtypeStruct((rows, 8, 2), jnp.float32, sharding=placed)
+    with jax.default_matmul_precision("highest"):
+        ma = grad_fn.lower(params, images, xy).compile().memory_analysis()
+    state = 20 * sum(
+        int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params)
+    )
+    stage = state + ma.temp_size_in_bytes
+    return {
+        "microbatch": rows, "state_bytes": state,
+        "temp_bytes": ma.temp_size_in_bytes, "stage_bytes": stage,
+        "fits_16GB": stage < V5E_HBM_BYTES,
+    }
+
+
+def rehearse(workload: str, seed: int, benchmark_json=None) -> dict:
     import jax
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
     import cells
 
-    cell = cells.Cell(workload)
+    cell = cells.Cell(workload, benchmark_json=benchmark_json)
     path = cell.ensure_recording(seed, cell.chunk)
     with cell.pipeline(path) as pipe:
         batch = next(iter(pipe))
@@ -99,6 +140,7 @@ def rehearse(workload: str, seed: int) -> dict:
         "alias_bytes": ma.alias_size_in_bytes,
         "per_device_bytes": per_device,
         "fits_16GB": per_device < V5E_HBM_BYTES,
+        "reference_stage": reference_stage(cell, model, topo.devices[0]),
         "decode_kernel_in_program": "tpu_custom_call" in text,
         "collectives": sorted(
             c for c in ("all-reduce", "all-gather", "reduce-scatter",
@@ -112,6 +154,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", action="append")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--benchmark-json", default=None,
+                    help="another file in BENCHMARK.json's format")
     args = ap.parse_args(argv)
 
     import jax
@@ -120,14 +164,16 @@ def main(argv=None) -> int:
     # persistent cache without the chip: keep it off here
     jax.config.update("jax_enable_compilation_cache", False)
 
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+    with open(
+        args.benchmark_json or os.path.join(ROOT, "BENCHMARK.json")
+    ) as f:
         names = args.workload or [
             w["name"] for w in json.load(f)["workloads"]
         ]
     ok = True
     for name in names:
-        line = rehearse(name, args.seed)
-        ok &= line["fits_16GB"]
+        line = rehearse(name, args.seed, args.benchmark_json)
+        ok &= line["fits_16GB"] and line["reference_stage"]["fits_16GB"]
         print(json.dumps(line), flush=True)
     return 0 if ok else 1
 

@@ -6,19 +6,30 @@ step, ``lax.scan`` over the group, donated state, the configuration's
 precision (bf16 compute, float32 parameters), the Pallas decode kernel.
 The reference shares none of that: every recorded message is decoded on
 the host with ``decode_tile_delta_np``, the model is the plain float32
-forward in ``references/<model>.py``, and each optimizer update is an
-un-scanned, un-donated ``jax.jit`` of loss and gradient followed by the
-optax update, under ``jax.default_matmul_precision("highest")`` (on a
-TPU a float32 product otherwise runs in bf16 passes). A batch too large
+forward in ``references/<model>.py``, the loss its plain form in
+``losses/<loss>.py``, and each optimizer update is an un-scanned
+``jax.jit`` of loss and gradient followed by the optax update, under
+``jax.default_matmul_precision("highest")`` (on a TPU a float32 product
+otherwise runs in bf16 passes). A batch too large
 for float32 activations is split into equal micro-batches whose
 gradients are averaged, which is the same mean.
 
 Both start from the same seeded parameters and see the same frames, so
 their per-update losses differ only by the precision of the arithmetic
 and, from the second update on, by what that did to the parameters.
+
+The reference runs first and alone: ``run.py`` makes the seeded state,
+takes its parameters' :func:`parameter_checksum`, drops its moments, lets
+:func:`reference_losses` consume the parameters, and only then makes the
+production state with the same program from the same key, whose
+parameters must give the same checksum. So a configuration of P
+parameters costs 16 P bytes in its step and at most 20 P in its check,
+never both at once.
 """
 
 from __future__ import annotations
+
+import functools
 
 import numpy as np
 
@@ -80,41 +91,78 @@ def decode_recording(path: str, messages: int):
     return out
 
 
-def corner_mse(pred, xy, hw):
-    """Mean squared error of the 8 predicted corners in image
-    coordinates normalised to [0, 1] by (width, height)."""
+@functools.lru_cache(maxsize=None)
+def _checksum_program():
+    import jax
     import jax.numpy as jnp
 
-    h, w = hw
-    scale = jnp.asarray([w, h], jnp.float32)
-    return jnp.mean((pred.reshape(-1, 8, 2) / scale - xy / scale) ** 2)
+    def one(x):
+        bits = jax.lax.bitcast_convert_type(
+            x.astype(jnp.float32), jnp.uint32
+        ).reshape(-1)
+        weight = 2 * jax.lax.iota(jnp.uint32, bits.size) + 1
+        return jnp.sum(bits * weight, dtype=jnp.uint32)
+
+    return jax.jit(lambda leaves: jnp.stack([one(x) for x in leaves]))
 
 
-REFERENCE_LOSSES = {"corner_mse": corner_mse}
-
-
-def reference_losses(forward, forward_kwargs: dict, loss: str, tx, params,
-                     batches, microbatch: int) -> np.ndarray:
-    """One float32 loss per update over ``batches`` from ``params``."""
+def parameter_checksum(params) -> np.ndarray:
+    """One uint32 a leaf: the sum of the elements' bit patterns, each
+    times an odd weight from its position, modulo 2**32. Integer sums
+    are exact in any order, so one chip, a mesh and the CPU give the same
+    number for the same bits, and nothing larger than a leaf is made."""
     import jax
-    import optax
 
-    loss_of = REFERENCE_LOSSES[loss]
+    return np.asarray(_checksum_program()(jax.tree_util.tree_leaves(params)))
+
+
+def live_bytes() -> int:
+    """Bytes of every array this process holds on its devices."""
+    import jax
+
+    return sum(a.nbytes for a in jax.live_arrays())
+
+
+def loss_and_grad(forward, forward_kwargs: dict, loss_of):
+    """The reference's one large program, jitted: ``(parameters, images,
+    labels) -> (loss, gradient)`` over one micro-batch."""
+    import jax
 
     def loss_fn(p, images, xy):
         return loss_of(
             forward(p, images, **forward_kwargs), xy, images.shape[1:3]
         )
 
-    grad_fn = jax.jit(jax.value_and_grad(loss_fn))
-    add = jax.jit(lambda a, b: jax.tree_util.tree_map(jax.numpy.add, a, b))
+    return jax.jit(jax.value_and_grad(loss_fn))
 
-    @jax.jit
+
+def reference_losses(forward, forward_kwargs: dict, loss_of, tx, params,
+                     batches, microbatch: int, watch=None) -> np.ndarray:
+    """One float32 loss per update over ``batches`` from ``params``,
+    which are consumed: ``update`` donates them and the moments (the
+    gradient sum has no result to become and is dropped after it), and
+    the accumulation donates its accumulator, so at its peak (a
+    micro-batch's gradient beside the sum) the stage holds parameters 4
+    + moments 8 + sum 4 + gradient 4 = 20 bytes a parameter and the
+    activations of one micro-batch inside a jit.
+    ``loss_of(prediction, labels, (height, width))`` is the loss's plain
+    float32 form (``losses/<loss>.py`` ``reference_loss``); ``watch()``
+    is called at that peak."""
+    import jax
+    import optax
+
+    grad_fn = loss_and_grad(forward, forward_kwargs, loss_of)
+    add = jax.jit(
+        lambda a, b: jax.tree_util.tree_map(jax.numpy.add, a, b),
+        donate_argnums=0,
+    )
+
     def update(p, opt_state, grad_sum, parts):
         grads = jax.tree_util.tree_map(lambda g: g / parts, grad_sum)
         updates, opt_state = tx.update(grads, opt_state, p)
         return optax.apply_updates(p, updates), opt_state
 
+    update = jax.jit(update, donate_argnums=(0, 1))
     losses = []
     with jax.default_matmul_precision("highest"):
         opt_state = tx.init(params)
@@ -129,8 +177,12 @@ def reference_losses(forward, forward_kwargs: dict, loss: str, tx, params,
                 sl = slice(j * microbatch, (j + 1) * microbatch)
                 value, grads = grad_fn(params, images[sl], xy[sl])
                 total += float(value)
+                if watch is not None:
+                    watch()
                 grad_sum = grads if grad_sum is None else add(grad_sum, grads)
+                del grads
             params, opt_state = update(params, opt_state, grad_sum, parts)
+            del grad_sum
             losses.append(total / parts)
     return np.asarray(losses, np.float32)
 

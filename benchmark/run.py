@@ -3,16 +3,19 @@
     python3 benchmark/run.py --workload NAME --seed N --seconds S --trace 0|1
 
 A new process that takes the cell's chips, sets up (producers or the
-recording, state on the device from ``--seed``, the reference check,
-warm-up of the cell's one program), measures for ``--seconds`` and
-prints one JSON object as the last line of its standard output:
-``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` and, when
-traced, ``breakdown``. With ``--trace 0`` the metrics are the cell's
-end-to-end metrics; with ``--trace 1`` the profiler is on for a short
-steady slice at the end of the window (its ``stop_trace`` falls outside)
-and the metrics are the cell's per-layer metrics. Everything else (the checks one by one, the doctor's
-verdict, sample counts, cache hits) goes on earlier lines and, in full,
-into ``benchmark/out/runs/``.
+recording, the plain reference from the seeded parameters, state on the
+device from ``--seed``, production's first dispatch against the
+reference, warm-up of the cell's one program), measures for ``--seconds``
+and prints one JSON object as the last line of its standard output:
+``correct``, ``attempted``, ``failed``, ``metrics``, ``device``, when
+traced ``breakdown``, and last ``compared``: each number that was compared
+beside its limit, which are also the last lines on standard error. With
+``--trace 0`` the metrics are the cell's end-to-end metrics; with
+``--trace 1`` the profiler is on for a short steady slice at the end of
+the window (its ``stop_trace`` falls outside) and the metrics are the
+cell's per-layer metrics. Everything else (the checks one by one, the
+doctor's verdict, sample counts, cache hits) goes on earlier lines and, in
+full, into ``benchmark/out/runs/``.
 
 It never falls back to the CPU: without a TPU, or with fewer chips than
 the cell asks for, it prints its reason on stderr, no result, and exits
@@ -226,10 +229,63 @@ def main(argv=None) -> int:
         recording = cell.ensure_recording(args.seed, n_messages)
         mark("recording")
 
+        # -- (1) the plain reference, first and alone ---------------------------
+        # The seeded state is made, its moments dropped, and its parameters
+        # consumed by the reference (20 bytes a parameter at its peak,
+        # reference.reference_losses); then the same program makes the
+        # production state from the same key, whose parameters must be
+        # the ones the reference started from.
+        # The model and the first call of the state's program (its load or
+        # compile) stay on ``setup.compile_s``'s clock, where they always
+        # were; ``reference_s`` is the stage's own time.
+        t_state = time.perf_counter()
+        model = cell.model()
+        ref_cfg = cell.config["reference_check"]
+        updates = min(int(ref_cfg["updates"]), check_messages, chunk)
+        make_state, key = cell.state_fn(model, mesh), jax.random.key(args.seed)
+        state = jax.block_until_ready(make_state(key))
+        first_state_s = time.perf_counter() - t_state
+        mark("seeded_state")
+        t_ref = time.perf_counter()
+        seeded_sum = reference.parameter_checksum(state.params)
+        seeded = jax.device_put(state.params, devices[0])
+        del state
+        n_params = sum(x.size for x in jax.tree_util.tree_leaves(seeded))
+        stage = {"live_peak_bytes": 0}
+
+        def watch():
+            stage["live_peak_bytes"] = max(
+                stage["live_peak_bytes"], reference.live_bytes()
+            )
+
+        ref_losses = reference.reference_losses(
+            cells.load_module("references", cell.model_class()).forward,
+            cell.config["model"]["kwargs"], cell.reference_loss(),
+            cell.optimizer(), seeded,
+            reference.decode_recording(recording, updates),
+            int(ref_cfg["microbatch"]), watch=watch,
+        )
+        del seeded  # donated: nothing of the stage is left on the device
+        stage.update(
+            parameters=n_params,
+            live_bytes_per_parameter=stage["live_peak_bytes"] / n_params,
+            live_bytes_after=reference.live_bytes(),
+            peak_bytes_in_use=(devices[0].memory_stats() or {}).get(
+                "peak_bytes_in_use"
+            ),
+        )
+        reference_s = time.perf_counter() - t_ref
+        mark("reference")
+
         # -- state, step, driver, stream ---------------------------------------
         t_programs = time.perf_counter()
-        model = cell.model()
-        state = cell.make_state(model, args.seed, mesh)
+        state = make_state(key)
+        differing = int(
+            (reference.parameter_checksum(state.params) != seeded_sum).sum()
+        )
+        check("seeded_parameters", differing == 0, {
+            "leaves": int(seeded_sum.size), "differing": differing,
+        })
         step = cell.make_step(state, mesh)
         loss_vectors: list = []
         driver = cell.make_driver(
@@ -246,26 +302,13 @@ def main(argv=None) -> int:
         it = iter(pipe)
         mark("state_and_stream")
 
-        # -- (1) production against the plain reference ------------------------
+        # -- production's first dispatch against the reference's losses --------
         if live:
             with cell.pipeline(recording, mesh) as once:
                 check_batch = next(iter(once))
         else:
             check_batch = next(it)
-        t_ref = time.perf_counter()
-        ref_cfg = cell.config["reference_check"]
-        updates = min(int(ref_cfg["updates"]), check_messages, chunk)
-        ref_losses = reference.reference_losses(
-            cells.load_module("references", cell.model_class()).forward,
-            cell.config["model"]["kwargs"], cell.config["loss"],
-            cell.optimizer(),
-            jax.device_put(driver.state.params, devices[0]),
-            reference.decode_recording(recording, updates),
-            int(ref_cfg["microbatch"]),
-        )
-        reference_s = time.perf_counter() - t_ref
-        mark("reference")
-        driver.submit(check_batch)  # donates the state the reference read
+        driver.submit(check_batch)
         driver.drain()
         verdict = reference.compare(
             np.asarray(loss_vectors[0], np.float32).reshape(-1), ref_losses,
@@ -274,7 +317,10 @@ def main(argv=None) -> int:
         check("reference", verdict["ok"], {
             k: verdict[k] for k in ("updates", "max_rel_diff", "rtol")
         })
-        say({"phase": "reference", "seconds": round(reference_s, 2), **verdict})
+        say({
+            "phase": "reference", "seconds": round(reference_s, 2), **verdict,
+            "seeded_parameters": differing == 0, "stage": stage,
+        })
         mark("first_dispatch")
 
         # -- warm-up: the cell's one (group length, shape) program. The
@@ -285,7 +331,7 @@ def main(argv=None) -> int:
             batch = next(it)
             driver.submit(batch)
         driver.drain()
-        compile_s = time.perf_counter() - t_programs - reference_s
+        compile_s = first_state_s + time.perf_counter() - t_programs
         mark("warm")
         counters = metrics.report()["counters"]
         paths = sorted(
@@ -464,6 +510,13 @@ def main(argv=None) -> int:
             "trace": trace_summary,
             "device": device,
             "flops_per_image": flops.train_flops_per_image(cell),
+            "model": {
+                "class": cell.model_class(),
+                "kwargs": cell.config["model"]["kwargs"],
+                "input_shape": (*cell.shape, cell.channels),
+                "batch_per_chip": batch_images // cell.chips,
+                "precision": cell.config["precision"],
+            },
             "setup": {
                 "compile_s": compile_s
                 + c.get("train.compile_ms", 0.0) / 1e3,
@@ -515,6 +568,7 @@ def main(argv=None) -> int:
             ],
             "setup": {
                 "setup_s": setup_s, "programs_s": compile_s,
+                "first_state_s": first_state_s,
                 "reference_s": reference_s, "compiles": warm["compiles"],
                 "marks": marks,
             },
@@ -555,7 +609,21 @@ def main(argv=None) -> int:
                 ],
                 "idle_gaps": trace_summary["idle_gaps"],
             }
+        # every number that was compared beside its limit: last in the
+        # line, and the last lines on standard error
+        line["compared"] = {
+            "loss_rel_diff": [verdict["max_rel_diff"], verdict["rtol"]],
+            "seeded_leaves_differing": [differing, 0],
+            "loss_at_budget": [at_budget, band["below"]],
+            "nonfinite_losses": [int((~np.isfinite(losses)).sum()), 0],
+            "failed_checks": [len(check.failures()), 0],
+        }
     say(line)
+    for name, result in check.failures().items():
+        print(f"failed check {name}: {result['detail']}", file=sys.stderr)
+    for name, (value, limit) in line["compared"].items():
+        print(f"compared {name}: {value} limit {limit}", file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 
