@@ -96,19 +96,19 @@ def pack_palette_indices(idx, bits: int):
     return idx
 
 
-def unpack_palette_indices(packed, bits: int, xp=np):
-    """Inverse of :func:`pack_palette_indices` (``xp``: ``numpy`` or
-    ``jax.numpy`` — the expression is jit-safe)."""
+def unpack_palette_indices(packed, bits: int):
+    """Inverse of :func:`pack_palette_indices`, on the host (the device
+    cuts the indices out lane by lane: :func:`_expand_palette`)."""
     lead = packed.shape[:-1]
     m = packed.shape[-1]
     if bits == 2:
-        return xp.stack(
+        return np.stack(
             [packed >> 6, (packed >> 4) & 3, (packed >> 2) & 3,
              packed & 3],
             axis=-1,
         ).reshape(*lead, m * 4)
     if bits == 4:
-        return xp.stack(
+        return np.stack(
             [packed >> 4, packed & 0xF], axis=-1
         ).reshape(*lead, m * 2)
     return packed
@@ -476,7 +476,8 @@ def decode_tile_delta_np(ref: np.ndarray, idx: np.ndarray,
 # Flat-shaded synthetic frames carry very few distinct colors, so the
 # changed tiles compress losslessly to palette indices: <=16 colors ->
 # two 4-bit indices per byte (8x fewer bytes than RGBA), <=256 -> one
-# byte per pixel (4x). The device side is a trivial fused gather.
+# byte per pixel (4x). The device expands 2- and 4-bit indices by dense
+# arithmetic and selects, 8-bit ones by a gather (:func:`_expand_palette`).
 
 
 def _palettize_flat(flat: np.ndarray, max_colors: int):
@@ -559,9 +560,9 @@ def palettize_frames(frames: np.ndarray, max_colors: int = 256):
     drifts past 16). Returns ``(packed, palette, bits)`` — ``packed``
     (B, H*W/4 | H*W/2 | H*W) uint8 for ``bits`` 2/4/8 (16x/8x/4x fewer
     bytes than RGBA across BOTH the socket and the host->device link;
-    the device side is one fused gather through ``palette`` (B, cap,
-    C)) — or ``None`` when any single frame holds more than
-    ``max_colors`` distinct colors (ship raw instead).
+    the device side expands through ``palette`` (B, cap, C),
+    :func:`_expand_palette`) — or ``None`` when any single frame holds
+    more than ``max_colors`` distinct colors (ship raw instead).
     """
     max_colors = min(int(max_colors), 256)
     b, h, w, c = frames.shape
@@ -591,50 +592,88 @@ def palettize_frames(frames: np.ndarray, max_colors: int = 256):
     return packed, palette, bits
 
 
-def _lut_expand(packed, palette, bits: int):
-    """Device-side byte-LUT palette expand: ONE gather per packed byte
-    through a 256-entry LUT (byte value -> ``8/bits`` pixels x C bytes,
-    built on device from the palette) instead of bit-unpack + per-pixel
-    gather. Bit-exact by construction.
+#: Suffixes of the ``tiles.expand_path.*`` trace-time counters
+#: :func:`_expand_palette` bumps: how a trace turned palette indices into
+#: colours (once per trace, not per execution).
+EXPAND_PATHS = ("select", "gather")
 
-    ``packed``: (..., M) uint8; ``palette``: (cap, C). Returns
-    (..., M, (8/bits)*C) uint8 — the caller reshapes (packed bytes hold
-    consecutive pixels of the flattened pixel axis, so flattening the
-    last two dims restores flat pixel-major x channel order).
+
+def _expand_palette(packed, palette, bits: int):
+    """Device-side palette expand of the flattened pixel axis.
+
+    ``packed``: (..., M) uint8 holding ``8/bits`` consecutive pixels a
+    byte (first index in the high bits); ``palette``: (cap, C), or
+    (..., cap, C) with leading axes matching ``packed``'s leading ones
+    (each row then expands through its own palette, one ``vmap`` a
+    level). Returns uint8 of ``M * (8/bits) * C`` bytes a row in flat
+    pixel-major x channel order — the caller reshapes. Bit-exact by
+    construction.
+
+    The palette's row count decides how. 256 rows (``bits == 8``):
+    ``palette[packed]``, one look-up a pixel (``expand_path.gather``).
+    4 or 16 rows: no indexed load, because a TPU looks the indices of a
+    gather up one at a time (5.2 M of them a dispatch took 49 ms) where
+    dense arithmetic runs over whole vector registers
+    (``expand_path.select``). The flat output is viewed in rows of whole
+    128-lane registers (a (16, 32) RGBA tile row is one); a 0/1 product
+    spreads each packed byte over the ``(8/bits)*C`` lanes it covers
+    (one non-zero term an output and bf16 holds 0..255: exact), a
+    per-lane shift and mask cut each lane's index out of its byte, and
+    one compare-and-select a palette row picks the colour from the
+    palette tiled along the lanes.
     """
+    import math
+
     import jax
     import jax.numpy as jnp
 
+    from blendjax.utils.metrics import metrics
+
+    if palette.ndim >= 3:
+        return jax.vmap(
+            lambda p, q: _expand_palette(p, q, bits)
+        )(packed, palette)
+    if bits == 8:
+        metrics.count("tiles.expand_path.gather")
+        return palette[packed]
+    metrics.count("tiles.expand_path.select")
     with jax.named_scope(SCOPE_PALETTE_EXPAND):
+        # The operands as they stand in memory: left alone XLA pulls the
+        # slices that cut them out of the packed group into the product,
+        # and the cube CNN's fused step compiles for a v5e in 30.8 s
+        # where it takes 10.2 behind the barrier (the gather's took 8.0).
+        packed, palette = jax.lax.optimization_barrier((packed, palette))
+        lead, m = packed.shape[:-1], packed.shape[-1]
+        cap, c = palette.shape
         px = 8 // bits
-        nib = unpack_palette_indices(
-            jnp.arange(256, dtype=jnp.uint8)[:, None], bits, jnp
-        )  # (256, px) index table, built once per jit trace
-        c = palette.shape[-1]
-        lut = palette[nib].reshape(256, px * c)
-        return lut[packed]
+        span = px * c  # output bytes (lanes) one packed byte covers
+        per_row = math.gcd(m, math.lcm(128, span) // span)  # packed bytes
+        lane = np.arange(per_row * span)
+        spread = lane // span == np.arange(per_row)[:, None]
+        byte = jnp.einsum(
+            "...b,bl->...l",
+            packed.reshape(*lead, m // per_row, per_row).astype(jnp.bfloat16),
+            jnp.asarray(spread, jnp.bfloat16),
+            preferred_element_type=jnp.float32,
+        ).astype(jnp.int32)
+        shift = bits * (px - 1 - (lane // c) % px)
+        idx = (byte >> jnp.asarray(shift, jnp.int32)) & ((1 << bits) - 1)
+        rows = jnp.tile(palette.astype(jnp.int32), (1, lane.size // c))
+        out = rows[0]
+        for r in range(1, cap):
+            out = jnp.where(idx == r, rows[r], out)
+        return out.astype(jnp.uint8)
 
 
 def expand_palette_frames(packed, palette, bits: int, h: int, w: int,
                           c: int):
-    """Device-side inverse of :func:`palettize_frames` (jit-safe
-    gather). ``packed``: (..., H*W/4|H*W/2|H*W) uint8; ``palette``:
-    (cap, C) batch-level, or (..., cap, C) per-row with leading axes
-    matching ``packed``'s (each row gathers through its own table).
-    Returns (..., H, W, C) uint8."""
-    import jax.numpy as jnp
-
-    if palette.ndim >= 3:
-        import jax
-
-        return jax.vmap(
-            lambda p, q: expand_palette_frames(p, q, bits, h, w, c)
-        )(packed, palette)
+    """Device-side inverse of :func:`palettize_frames` (jit-safe;
+    :func:`_expand_palette`). ``packed``: (..., H*W/4|H*W/2|H*W) uint8;
+    ``palette``: (cap, C) batch-level, or (..., cap, C) per-row with
+    leading axes matching ``packed``'s (each row expands through its own
+    palette). Returns (..., H, W, C) uint8."""
     lead = packed.shape[:-1]
-    if bits < 8:
-        return _lut_expand(packed, palette, bits).reshape(*lead, h, w, c)
-    idx = unpack_palette_indices(packed, bits, jnp)
-    return palette[idx].reshape(*lead, h, w, c)
+    return _expand_palette(packed, palette, bits).reshape(*lead, h, w, c)
 
 
 def expand_palette_frames_np(packed, palette, bits: int, h: int, w: int,
@@ -646,7 +685,7 @@ def expand_palette_frames_np(packed, palette, bits: int, h: int, w: int,
             for p, q in zip(packed, palette)
         ])
     lead = packed.shape[:-1]
-    idx = unpack_palette_indices(packed, bits, np)
+    idx = unpack_palette_indices(packed, bits)
     return palette[idx].reshape(*lead, h, w, c)
 
 
@@ -676,30 +715,18 @@ def pop_frame_palette_batches(hb: dict):
 
 
 def expand_palette_tiles(packed, palette, bits: int, t, c: int):
-    """Device-side inverse of :func:`palettize_tiles` (jit-safe gather).
+    """Device-side inverse of :func:`palettize_tiles` (jit-safe;
+    :func:`_expand_palette`).
 
-    ``packed``: (..., K, t*t/2|t*t) uint8; ``palette``: (cap, C), or
+    ``packed``: (..., K, t*t/4|t*t/2|t*t) uint8; ``palette``: (cap, C), or
     (..., cap, C) with leading axes matching ``packed``'s leading dims
     (per-frame palettes, and the chunked-decode case stacks another
-    level) — each row then gathers through its own palette. ``t`` is an
+    level) — each row then expands through its own palette. ``t`` is an
     int side or ``(th, tw)`` pair. Returns (..., K, th, tw, C) uint8.
     """
-    import jax.numpy as jnp
-
     th, tw = tile_hw(t)
-    if palette.ndim >= 3:
-        import jax
-
-        return jax.vmap(
-            lambda p, q: expand_palette_tiles(p, q, bits, t, c)
-        )(packed, palette)
     lead = packed.shape[:-1]
-    if bits < 8:
-        return _lut_expand(packed, palette, bits).reshape(
-            *lead, th, tw, c
-        )
-    idx = unpack_palette_indices(packed, bits, jnp)
-    return palette[idx].reshape(*lead, th, tw, c)
+    return _expand_palette(packed, palette, bits).reshape(*lead, th, tw, c)
 
 
 def expand_palette_tiles_np(packed, palette, bits: int, t, c: int):
@@ -711,7 +738,7 @@ def expand_palette_tiles_np(packed, palette, bits: int, t, c: int):
             for p, q in zip(packed, palette)
         ])
     lead = packed.shape[:-1]
-    idx = unpack_palette_indices(packed, bits, np)
+    idx = unpack_palette_indices(packed, bits)
     return palette[idx].reshape(*lead, th, tw, c)
 
 
@@ -1057,7 +1084,7 @@ def decode_packed_superbatch(packed, refs, spec, names, geoms,
 
 def decode_packed_pal_batch(packed, spec, pal_groups, rle_groups=()):
     """Decode ONE packed full-frame-palette batch to full fields —
-    jit-safe (slice/bitcast unpack + the byte-LUT palette gather).
+    jit-safe (slice/bitcast unpack + :func:`_expand_palette`).
 
     ``packed``: (total,) uint8 buffer of :func:`pack_fields` layout
     ``spec``; ``pal_groups``: ``((name, (h, w, c, bits)), ...)`` as
@@ -1078,7 +1105,7 @@ def decode_packed_pal_batch(packed, spec, pal_groups, rle_groups=()):
 
 def decode_packed_pal_superbatch(packed, spec, pal_groups, rle_groups=()):
     """(K', total) stacked packed pal buffers -> (K', B, ...) superbatch
-    fields — each group member gathers through its OWN palette (vmap
+    fields — each group member expands through its OWN palette (vmap
     over the chunk axis). The full-frame-palette twin of
     :func:`decode_packed_superbatch`, consumed by the same two callers.
     """

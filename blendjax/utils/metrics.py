@@ -34,7 +34,7 @@ _LOG_GAMMA = math.log(_GAMMA)
 # The device-side vocabulary: the ``jax.named_scope`` names the step's
 # parts carry where they run, so that every device operation in a
 # profiler trace says which part it belongs to (its ``tf_op`` stat is
-# the name stack: ``jit(_fused)/decode/vmap(vmap(palette_expand))/gather``).
+# the name stack: ``jit(_fused)/decode/vmap(vmap(palette_expand))/select_n``).
 # Forward and backward need no scope of ours: ``jvp(<Model>)`` and
 # ``transpose(jvp(<Model>))`` are already on the stack. Documented in
 # docs/observability.md ("Device scopes"); read by benchmark/trace_scopes.py.

@@ -128,10 +128,23 @@ def test_fused_tile_step_names_its_parts(which):
         else _streamformer("flash" if which == "flash" else "auto")
     )
     step = make_fused_tile_step(loss_fn=loss_fn)
-    names = op_names(_lower_fused_tile(step, _state(model)).compile())
+    state = _state(model)
+    counters = reg.report()["counters"]
+    names = op_names(_lower_fused_tile(step, state).compile())
     cls = type(model).__name__
     assert under(names, SCOPE_DECODE)
-    assert under(names, SCOPE_DECODE, SCOPE_PALETTE_EXPAND)
+    # the 4-bit expansion is a lane product and selects inside ``decode``,
+    # with no gather, and its trace says which form it took, once
+    expand = under(names, SCOPE_DECODE, SCOPE_PALETTE_EXPAND)
+    primitives = {n.rsplit("/", 1)[1] for n in expand}
+    assert {"dot_general", "select_n"} <= primitives, primitives
+    assert "gather" not in primitives
+    took = {
+        path: reg.report()["counters"].get(f"tiles.expand_path.{path}", 0)
+        - counters.get(f"tiles.expand_path.{path}", 0)
+        for path in T.EXPAND_PATHS
+    }
+    assert took == {"select": 1, "gather": 0}
     assert under(names, SCOPE_OPTIMIZER)
     # the parts do not overlap: decode runs before the scan, the
     # optimizer outside the differentiation

@@ -1694,6 +1694,78 @@ def test_palettize_frames_roundtrip_all_widths_and_overflow():
     assert palettize_frames(many) is None
 
 
+def _palette_case(bits, form, rank):
+    """``(expand, expand_np, packed, palette)`` of one expansion: every
+    byte value under every palette, palettes with unused (zero) rows as
+    the codec pads them, and the values at the ends and the middle."""
+    from blendjax.ops import tiles as T
+
+    rng = np.random.default_rng(0)
+    lead = {"shared": (3,), "per_row": (3,), "per_row_vmap2": (2, 3)}[rank]
+    cap = 1 << bits
+    if form == "tiles":
+        th, tw, c = 16, 32, 4
+        body = (2, th * tw * bits // 8)  # K tiles of M packed bytes
+        expand = lambda p, q: T.expand_palette_tiles(p, q, bits, (th, tw), c)
+        expand_np = lambda p, q: T.expand_palette_tiles_np(
+            p, q, bits, (th, tw), c
+        )
+    else:
+        h, w, c = 32, 32, 3  # 3 channels: a row of 384 lanes, not 128
+        body = (h * w * bits // 8,)
+        expand = lambda p, q: T.expand_palette_frames(p, q, bits, h, w, c)
+        expand_np = lambda p, q: T.expand_palette_frames_np(
+            p, q, bits, h, w, c
+        )
+    packed = rng.integers(0, 256, (*lead, *body), np.uint8)
+    packed.reshape(*lead, -1)[..., :256] = np.arange(256, dtype=np.uint8)
+    palette = rng.integers(
+        0, 256, (*(() if rank == "shared" else lead), cap, c), np.uint8
+    )
+    palette[..., 0, :] = (0, 1, 127, 128)[:c]
+    palette[..., 1, :] = (255, 254, 129, 0)[:c]
+    palette[..., cap - cap // 4:, :] = 0  # the codec's zero padding
+    return expand, expand_np, packed, palette
+
+
+@pytest.mark.parametrize("rank", ["shared", "per_row", "per_row_vmap2"])
+@pytest.mark.parametrize("form", ["tiles", "frames"])
+@pytest.mark.parametrize("bits", [2, 4, 8])
+def test_palette_expand_is_bit_exact(bits, form, rank):
+    """The device expansion against its numpy twin, byte for byte: the
+    select form of 2 and 4 bits and the gather of 8, with one palette a
+    batch, one a row (one ``vmap``) and one a row of a chunk group (the
+    fused step's ``vmap(vmap(palette_expand))``)."""
+    expand, expand_np, packed, palette = _palette_case(bits, form, rank)
+    got = jax.jit(expand)(packed, palette)
+    want = expand_np(packed, palette)
+    assert got.dtype == np.uint8 and got.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def _primitives(jaxpr) -> set:
+    """Names of the primitives of ``jaxpr`` and of every jaxpr nested
+    in its equations' parameters."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names |= _primitives(sub)
+    return names
+
+
+@pytest.mark.parametrize("form", ["tiles", "frames"])
+@pytest.mark.parametrize("bits", [2, 4, 8])
+def test_palette_expand_gathers_only_at_8_bits(bits, form):
+    """A TPU looks a gather's indices up one at a time (the byte table of
+    2- and 4-bit indices was the step's largest operation), so the table
+    look-up must not come back unnoticed; 8-bit palettes keep theirs,
+    which also shows that this test would see one."""
+    expand, _, packed, palette = _palette_case(bits, form, "per_row_vmap2")
+    prims = _primitives(jax.make_jaxpr(expand)(packed, palette).jaxpr)
+    assert ("gather" in prims) == (bits == 8), sorted(prims)
+
+
 def test_stream_pipeline_pal_encoding_end_to_end():
     """--encoding pal -> ONE packed transfer per batch, decoded by a
     device gather to bit-exact full frames (the lossless non-sparse

@@ -229,6 +229,19 @@ def test_gamma_normalize_compiles(topo):
     _assert_fits_with_kernel(compiled, kernel=False)
 
 
+def _assert_no_gather_in_palette_expand(text):
+    """The chip's compiler kept the 4-bit expansion dense: operations
+    traced under ``palette_expand``, fused ones included, and no gather
+    among them (it ran one index at a time: 49 ms a dispatch)."""
+    from blendjax.utils.metrics import SCOPE_PALETTE_EXPAND
+
+    ops = [
+        ln for ln in text.splitlines()
+        if SCOPE_PALETTE_EXPAND in ln.partition("op_name=")[2]
+    ]
+    assert ops and not [ln for ln in ops if " gather(" in ln]
+
+
 def _lower_fused_tile(step, state, chunk, sharding, plan):
     row_bytes, spec, names, geoms, ref_shape = plan
     return step.jits["tile"].lower(
@@ -254,7 +267,7 @@ def test_fused_tile_step_compiles_with_the_kernel(
 ):
     """``make_fused_tile_step`` whole at the bench's K=16 — unpack,
     palette expand, Pallas decode, 16 scanned updates — with the kernel
-    branch actually taken."""
+    branch actually taken and the expansion without a gather."""
     one = SingleDeviceSharding(topo.devices[0])
     model, loss_fn = model_and_loss()
     step = make_fused_tile_step(loss_fn=loss_fn)
@@ -262,7 +275,7 @@ def test_fused_tile_step_compiles_with_the_kernel(
         step, _abstract_state(model, one), bench.CHUNK, one,
         _tile_plan((16, 32)),
     ).compile()
-    _assert_fits_with_kernel(compiled)
+    _assert_no_gather_in_palette_expand(_assert_fits_with_kernel(compiled))
 
 
 def test_fused_tile_step_names_the_decode_kernel(topo, tpu_branches):
@@ -388,6 +401,7 @@ def test_four_chip_fused_step_has_kernel_and_all_reduce(
         step, state, 2, rep, _tile_plan((16, 32))
     ).compile()
     text = _assert_fits_with_kernel(compiled)
+    _assert_no_gather_in_palette_expand(text)
     assert "all-reduce(" in text or "all-reduce-start(" in text
 
 
@@ -435,7 +449,7 @@ def test_echo_fused_step_compiles(topo):
 @pytest.mark.slow
 def test_fused_palette_group_compiles(topo):
     """The full-frame palette codec's fused form (``_pal`` groups):
-    byte-LUT gather decode + K'=8 scanned updates."""
+    palette expansion + K'=8 scanned updates."""
     one = SingleDeviceSharding(topo.devices[0])
     frames = np.random.default_rng(0).integers(
         0, 12, (B, H, W, 1), np.uint8
