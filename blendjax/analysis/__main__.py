@@ -112,8 +112,7 @@ def build_parser() -> argparse.ArgumentParser:
     )
     parser.add_argument(
         "paths", nargs="*", default=None,
-        help="files or directories to analyze (default: blendjax; "
-        "with --contracts: blendjax plus bench.py)",
+        help="files or directories to analyze (default: blendjax)",
     )
     parser.add_argument(
         "--select", default=None,
@@ -177,14 +176,7 @@ def main(argv: list[str] | None = None) -> int:
         if unknown:
             print(f"unknown rule ids: {sorted(unknown)}", file=sys.stderr)
             return 2
-    paths = args.paths
-    if not paths:
-        # The contracts gate audits bench.py's env knobs too — it is
-        # the repo's biggest knob surface and lives outside the
-        # package tree.
-        paths = ["blendjax"]
-        if args.contracts and os.path.exists("bench.py"):
-            paths.append("bench.py")
+    paths = args.paths or ["blendjax"]
     missing = [p for p in paths if not os.path.exists(p)]
     if missing:
         print(f"no such path: {missing}", file=sys.stderr)

@@ -248,7 +248,7 @@ def documented_knobs(lines: list[str]) -> dict[str, int]:
     out: dict[str, int] = {}
     for i, line in enumerate(lines, 1):
         for m in _KNOB_RE.finditer(line):
-            # "BLENDJAX_BENCH_*" family references leave a trailing
+            # "BLENDJAX_SHM_*" family references leave a trailing
             # underscore once the regex stops at the wildcard — not a
             # knob name.
             if m.group(0).endswith("_"):

@@ -7,7 +7,7 @@ params/optimizer state back into the buffers it consumed, so the
 run's device memory is ONE copy of the state instead of two and no
 per-step reallocation happens (the runtime donation audit,
 :mod:`blendjax.testing.donation`, pins the pointer-stability this
-buys; ``train.donation_reuse`` surfaces it in bench records). A
+buys). A
 ``jax.jit`` on a step-like function that OMITS the donation keyword
 silently doubles state memory and re-allocates every step — it still
 trains correctly, which is exactly why it needs a lint, not a test.

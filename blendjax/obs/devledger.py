@@ -269,10 +269,10 @@ def default_peak_flops() -> tuple | None:
     return chip_peak_flops(jax.devices()[0].device_kind)
 
 
-# -- the cost-model FLOPs probe (moved here from bench.py) --------------------
+# -- the cost-model FLOPs probe -------------------------------------------------
 
 #: Memo for :func:`measure_model_flops`, keyed by (model class, shape,
-#: batch) so a bench run pays one extra lowering per model/geometry.
+#: batch) so a run pays one extra lowering per model/geometry.
 _FLOPS_MEMO: dict = {}
 
 
@@ -282,9 +282,8 @@ def measure_model_flops(model=None, loss_fn=None,
                         memo: bool = True) -> dict:
     """Fwd+bwd FLOPs per image of the supervised step, from the
     compiled executable's own cost analysis (XLA's count, not a hand
-    estimate). The one home for the cost-model path — ``bench.py``
-    imports it back, and the driver builds derive ``flops_per_image``
-    from the same figure via the ledger.
+    estimate). The one home for the cost-model path: the driver builds
+    derive ``flops_per_image`` from the same figure via the ledger.
 
     Always lowers the UNCHUNKED per-batch step: the per-image math is
     identical at any chunk, and XLA's cost model counts a ``lax.scan``

@@ -3,8 +3,8 @@
 Every ``interval_s`` it takes one consistent metrics snapshot, runs the
 stall doctor over it, logs the one-line verdict, and (optionally)
 appends the full snapshot to a JSONL archive — the always-on version of
-what ``bench.py`` stamps into its stage breakdowns, for long training
-runs that never go through the bench harness.
+what a benchmark run writes into its detail file, for long training
+runs.
 
 Since the SLO watchdog landed the reporter is also the evaluation
 cadence for declarative health rules: pass ``slos=[...]`` (specs or

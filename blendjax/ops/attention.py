@@ -21,7 +21,7 @@ Net-new vs the reference (blendtorch has no sequence models, SURVEY.md
 ``auto`` policy: one algorithm that wants a different path by size, so
 it reads the bytes of f32 scores a materialised call would write
 (:func:`scores_residual_bytes`) on a TPU. Measured on one TPU v5e
-(my chip run, PR 26; ``scripts/attn_core_bench.py``: the core alone,
+(my chip run, PR 26; ``scripts/attn_core_time.py``: the core alone,
 forward + backward, bf16, 12 chained calls a dispatch, ms a call):
 
 ====================  ============  ======  ======  =========

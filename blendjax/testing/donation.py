@@ -18,10 +18,7 @@ realloc stall:
 
 :class:`DonationAudit` tracks ``unsafe_buffer_pointer()`` snapshots
 per labeled pytree across the feeder -> reservoir insert -> fused
-draw/step chain and asserts pointer stability; the bench's driver rows
-surface the same check as the ``train.donation_reuse`` gauge
-(docs/observability.md) so a donation regression shows up in the
-record, not just in a test run.
+draw/step chain and asserts pointer stability.
 
 Pointer reads are host-side metadata (no device sync); arrays whose
 backend can't expose a pointer audit as ``None`` and are skipped
@@ -98,8 +95,7 @@ class DonationAudit:
     True
 
     ``report()`` summarizes every label (snapshot count, distinct
-    pointer sets, stability verdict) — the dict the bench embeds
-    beside the ``train.donation_reuse`` gauge. ``assert_stable()``
+    pointer sets, stability verdict). ``assert_stable()``
     raises with the offending leaves named, for test use."""
 
     def __init__(self) -> None:

@@ -1,8 +1,8 @@
 """The quickest proof that blendjax still starts on the chip.
 
 ``python3 chip_smoke.py`` drives the stream -> train path once, through
-the entry points a user calls, on ONE TPU chip, at the bench's geometry
-(``bench.py``: batch 8, 480x640 RGBA, tile stream, chunk 16) with random
+the entry points a user calls, on ONE TPU chip, at the stream's reference
+sizes (``REAL``: batch 8, 480x640 RGBA, tile stream, chunk 16) with random
 weights from ``--seed``, and checks what comes out:
 
 1. *kernels*: the two Pallas tile decodes bit-exact against the numpy
@@ -47,7 +47,10 @@ if ROOT not in sys.path:  # the package is not installed; children get
 
 import numpy as np  # noqa: E402
 
-import bench  # noqa: E402  (geometry, tile capacity, the StreamFormer row)
+# The cube scene's most changed tiles in one 480x640 frame (285 at 16x16
+# tiles, 156 at 16x32, over 4,000 frames of four seeds) rounded up to 32.
+# A smaller capacity grows mid-run and compiles the decode again.
+TILE_CAPACITY = {(16, 16): "288", (16, 32): "160"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,14 +58,21 @@ class Sizes:
     """What one run streams and trains. ``REAL`` is what the chip gets;
     the CPU rehearsal in tests/ passes a tiny one."""
 
-    shape: tuple = bench.SHAPE
-    batch: int = bench.BATCH
-    chunk: int = bench.CHUNK
-    tile_capacity: str = bench.tile_capacity_default(16, 32)
+    shape: tuple = (480, 640)
+    batch: int = 8
+    chunk: int = 16
+    tile_capacity: str = TILE_CAPACITY[16, 32]
+    # Three faces and the background are 4 colours (2 bits), but about one
+    # frame in 200 holds a fifth, and a batch with a wider index is another
+    # wire shape: it breaks the chunk group and compiles a second step.
+    tile_pal_bits: str = "4"
     producers: int = 2
     cnn_steps: int = 8        # driver steps after warm-up (x chunk updates)
     former_steps: int = 4
-    former: dict | None = None  # StreamFormer kwargs; None = bench's row
+    # ViT-S-class: patch 20 is 768 tokens at 480x640, 4 heads of 128 lanes
+    former: dict = dataclasses.field(default_factory=lambda: dict(
+        patch=20, dim=512, depth=8, num_heads=4, num_outputs=16
+    ))
     flash_shape: tuple = (4, 3072, 4, 128)
     attn_shape: tuple = (8, 1200, 12, 64)  # the benchmark's, through auto
     rl_steps: int = 12
@@ -264,7 +274,7 @@ def _producer_args(sizes: Sizes) -> list:
         "--batch", str(sizes.batch), "--encoding", "tile",
         "--tile", "16", "32", "--tile-rgba",
         "--tile-capacity", sizes.tile_capacity,
-        "--tile-pal-bits", bench.TILE_PAL_BITS,
+        "--tile-pal-bits", sizes.tile_pal_bits,
         "--trace-every", "8",
     ]
 
@@ -390,6 +400,17 @@ def _train_on_stream(it, pipe, model, loss_fn, steps: int, seed: int,
     }
 
 
+def former_loss(state, params, batch):
+    """The StreamFormer's 16 outputs held to the cube's 8 corners."""
+    from blendjax.train import corner_loss
+
+    pred = state.apply_fn({"params": params}, batch["image"])
+    return corner_loss(
+        pred.reshape(-1, 8, 2), batch["xy"],
+        image_shape=batch["image"].shape[1:3],
+    )
+
+
 def phase_headline(sizes: Sizes, seed: int) -> dict:
     from blendjax.data import StreamDataPipeline
     from blendjax.launcher import PythonProducerLauncher
@@ -419,12 +440,9 @@ def phase_headline(sizes: Sizes, seed: int) -> dict:
         )
         out["cnn"] = cnn
         _assert_native_producers(sizes.producers)
-        former, former_loss = bench._transformer_model_and_loss()
-        if sizes.former is not None:
-            former = StreamFormer(**sizes.former)
         out["streamformer"] = _train_on_stream(
-            it, pipe, former, former_loss, sizes.former_steps, seed, sizes,
-            "StreamFormer",
+            it, pipe, StreamFormer(**sizes.former), former_loss,
+            sizes.former_steps, seed, sizes, "StreamFormer",
         )
     return out
 

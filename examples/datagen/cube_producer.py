@@ -2,7 +2,7 @@
 
 The headless counterpart of the reference's ``examples/datagen/
 cube.blend.py:6-39`` (randomize in pre_frame, publish in post_frame) and
-the producer used by ``bench.py``. Launch it with
+the producer ``chip_smoke.py`` streams from. Launch it with
 :class:`blendjax.launcher.PythonProducerLauncher`; it reads the handshake
 (btid/seed/sockets) exactly like a Blender scene script would.
 

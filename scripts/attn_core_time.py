@@ -3,7 +3,7 @@
 fused, on the chip: the measurement ``FLASH_RESIDUAL_BYTES`` in
 ``blendjax/ops/attention.py`` is set from.
 
-    python scripts/attn_core_bench.py [B,T,H,D ...]
+    python scripts/attn_core_time.py [B,T,H,D ...]
 
 One JSON line a shape and backend: ms a call (12 chained calls a
 dispatch, bf16, host clock around ``block_until_ready``) and the bytes
@@ -46,7 +46,7 @@ def ms_per_call(backend, q, k, v, w):
 
 def main(argv):
     if jax.default_backend() != "tpu":
-        print("attn_core_bench: no TPU here", file=sys.stderr)
+        print("attn_core_time: no TPU here", file=sys.stderr)
         return 2
     shapes = [tuple(int(n) for n in a.split(",")) for a in argv] or SHAPES
     for shape in shapes:

@@ -19,7 +19,7 @@ if _repo_root not in _pp:
     os.environ["PYTHONPATH"] = os.pathsep.join([_repo_root] + _pp)
 
 # The CURRENT interpreter also needs the repo root importable (tests
-# import repo-root modules like `bench`): the bare `pytest` entry point
+# import repo-root modules like `chip_smoke`): the bare `pytest` entry point
 # does not put the cwd on sys.path the way `python -m pytest` does.
 import sys
 

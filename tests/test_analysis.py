@@ -2504,7 +2504,7 @@ def test_contracts_stamp_and_knob_extraction():
 
 def test_contracts_doc_matching_grammar():
     """Doc-side parsing: wildcard families, trailing-N families,
-    artifact filenames excluded, and the ``BLENDJAX_BENCH_*`` family
+    artifact filenames excluded, and a ``BLENDJAX_MY_*`` family
     reference not read as a knob named with a trailing underscore."""
     from blendjax.analysis.contracts import (
         _doc_metric_live,
@@ -2517,8 +2517,8 @@ def test_contracts_doc_matching_grammar():
     lines = [
         "Counters: `wire.frames`, the `echo.*` family, and per-shard",
         "`ingest.recv.shardN` spans; traces export to `trace.json`.",
-        "Every switch is a `BLENDJAX_BENCH_*` variable —",
-        "`BLENDJAX_BENCH_CHUNK` (default 16).",
+        "Every switch is a `BLENDJAX_MY_*` variable —",
+        "`BLENDJAX_MY_KNOB` (default 16).",
     ]
     docs = documented_metrics(lines)
     assert "wire.frames" in docs and "echo.*" in docs
@@ -2527,7 +2527,7 @@ def test_contracts_doc_matching_grammar():
     assert _metric_documented("echo.fresh", docs)
     assert not _metric_documented("rl.fresh", docs)
     knobs = documented_knobs(lines)
-    assert knobs == {"BLENDJAX_BENCH_CHUNK": 4}
+    assert knobs == {"BLENDJAX_MY_KNOB": 4}
     cat = extract_metrics(_mods(("pkg/m.py", """
         def f(metrics, i):
             metrics.span(f"ingest.recv.shard{i}")
@@ -2644,7 +2644,6 @@ def test_repo_suppressions_all_justified():
     from blendjax.analysis.core import check_suppression_hygiene, parse_paths
 
     paths = [os.path.join(REPO_ROOT, p) for p in ("blendjax", "scripts")]
-    paths.append(os.path.join(REPO_ROOT, "bench.py"))
     modules, errors = parse_paths(paths, root=REPO_ROOT)
     assert not errors
     got = check_suppression_hygiene(modules)

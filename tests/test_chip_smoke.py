@@ -5,10 +5,12 @@ shows.)"""
 
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
 
+import numpy as np
 import pytest
 
 import chip_smoke
@@ -116,3 +118,64 @@ def test_a_chip_without_a_peak_on_record_is_an_error(monkeypatch):
     )
     with pytest.raises(KeyError, match="TPU v9 mystery"):
         chip_smoke.main([])
+
+
+@pytest.mark.parametrize("tile", sorted(chip_smoke.TILE_CAPACITY))
+def test_tile_capacity_is_the_cube_scene_s_measured_fit(tile):
+    """The capacity pinned for a geometry holds the most tiles the cube
+    scene changes in one frame at ``REAL.shape`` and is that count
+    rounded up to 32: under it the stream grows mid-run and the decode
+    compiles again, far over it the wire carries padding."""
+    from blendjax.ops.tiles import TileDeltaEncoder
+    from blendjax.producer.sim import CubeScene
+
+    shape = chip_smoke.REAL.shape
+    most = 0
+    for seed in (0, 1):
+        scene = CubeScene(shape=shape, seed=seed)
+        enc = TileDeltaEncoder(scene.background_image(), tile=tile)
+        frame = np.empty((*shape, 4), np.uint8)
+        for f in range(1, 1001):
+            scene.step(f)
+            scene.render(out=frame)
+            most = max(most, len(enc.encode(frame)[0]))
+    capacity = int(chip_smoke.TILE_CAPACITY[tile])
+    assert capacity % 32 == 0 and capacity - 32 < most <= capacity
+
+
+def test_no_environment_variable_changes_the_real_sizes():
+    """What the chip streams is what the file says: the knobs that used
+    to reach it through the old benchmark's module change nothing."""
+    env = dict(os.environ)
+    env["BLENDJAX_" + "BENCH_CHUNK"] = "2"
+    env["BLENDJAX_" + "BENCH_TILE"] = "32x32"
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import chip_smoke as c, dataclasses, json;"
+         "print(json.dumps(dataclasses.asdict(c.REAL)))"],
+        cwd=chip_smoke.ROOT, env=env, check=True, capture_output=True,
+        text=True, timeout=120,
+    ).stdout
+    real = json.loads(out)
+    assert (real["shape"], real["batch"], real["chunk"]) == ([480, 640], 8, 16)
+    assert (real["tile_capacity"], real["tile_pal_bits"]) == ("160", "4")
+    assert real["former"] == dict(
+        patch=20, dim=512, depth=8, num_heads=4, num_outputs=16
+    )
+
+
+def test_nothing_names_the_deleted_benchmark():
+    """The first benchmark's module and its environment knobs are gone
+    (PR 32): no code, test, example or script imports the one or reads
+    the others."""
+    gone = re.compile("BLENDJAX_" + r"BENCH_|^\s*(import|from) bench\b", re.M)
+    found = []
+    files = [os.path.join(chip_smoke.ROOT, "chip_smoke.py")]
+    for top in ("blendjax", "tests", "examples", "scripts"):
+        for base, _, names in os.walk(os.path.join(chip_smoke.ROOT, top)):
+            files += [os.path.join(base, n) for n in names if n.endswith(".py")]
+    for path in files:
+        with open(path, encoding="utf-8") as f:
+            if gone.search(f.read()):
+                found.append(os.path.relpath(path, chip_smoke.ROOT))
+    assert not found

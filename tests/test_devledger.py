@@ -485,3 +485,48 @@ def test_measure_model_flops_memo_and_small_geometry():
     assert out["flops_per_image"] > 0
     assert ("CubeRegressor", (16, 16), 2, None) in _FLOPS_MEMO
     assert measure_model_flops(shape=(16, 16), batch=2) == out  # memo hit
+
+
+def _cnn_forward_flops(h, w):
+    """CubeRegressor's matmul FLOPs an image, forward: four stride-2 3x3
+    convolutions (32, 64, 128, 256 features) and the dense head."""
+    flops, cin = 0, 4
+    for f in (32, 64, 128, 256):
+        h, w = h // 2, w // 2
+        flops += 2 * 9 * cin * f * h * w
+        cin = f
+    return flops + 2 * 256 * 256 + 2 * 256 * 16
+
+
+def _former_forward_flops(h, w, patch, dim, depth):
+    """StreamFormer's matmul FLOPs an image, forward: the patch
+    embedding, then per block qkv, scores, values, projection and the
+    4x MLP, then the 16-output head."""
+    t = (h // patch) * (w // patch)
+    block = 2 * t * dim * 3 * dim + 4 * t * t * dim + 2 * t * dim * dim \
+        + 4 * t * dim * 4 * dim
+    return 2 * t * patch * patch * 4 * dim + depth * block + 2 * dim * 16
+
+
+@pytest.mark.parametrize("model", ["CubeRegressor", "StreamFormer"])
+def test_measure_model_flops_matches_analytic_count(model):
+    """XLA's cost analysis of the unchunked step against a count from
+    shapes (forward + twice that backward), per model the probe serves:
+    a ``lax.scan`` body counted once, or a model whose shapes drifted,
+    moves the MFU denominator without failing anything else."""
+    pytest.importorskip("jax")
+    if model == "CubeRegressor":
+        shape = (480, 640)
+        got = measure_model_flops(shape=shape, memo=False)
+        want = 3 * _cnn_forward_flops(*shape)
+    else:
+        import chip_smoke
+        from blendjax.models import StreamFormer
+
+        shape, former = (64, 96), dict(patch=16, dim=64, depth=2)
+        got = measure_model_flops(
+            model=StreamFormer(num_heads=2, num_outputs=16, **former),
+            loss_fn=chip_smoke.former_loss, shape=shape, memo=False,
+        )
+        want = 3 * _former_forward_flops(*shape, **former)
+    assert 0.7 * want < got["flops_per_image"] < 1.3 * want, (got, want)

@@ -425,6 +425,79 @@ def test_driver_device_timeline_and_mfu_land_in_report():
     assert report["histograms"]["train.step_device_ms"]["count"] == 1
 
 
+def _rle_wire_frames(n, B, H, W):
+    """``n`` prebatched messages of run-heavy frames as run-length "ndr"
+    wire frames at one pinned cap (one packed spec, one compile)."""
+    from blendjax.transport.wire import encode_message
+
+    frames = []
+    for i in range(n):
+        img = np.zeros((B, H, W, 4), np.uint8)
+        img[:, 4 + i:14 + i, 6:22] = (i % 3) + 1
+        xy = np.full((B, 8, 2), float(i % 9), np.float32)
+        frames.append(encode_message(
+            {"btid": 0, "_prebatched": True, "image": img, "xy": xy},
+            compress_rle=True, rle_cap=128, compress_min_bytes=512,
+        ))
+    return frames
+
+
+@pytest.mark.parametrize("chunk", [1, 2])
+def test_deferred_rle_trains_the_same_losses_as_host_inflate(chunk):
+    """The SAME recorded "ndr" wire bytes, expanded inside the fused
+    dispatch (``defer_rle``) or inflated on the host, placed the same
+    way, train identical f32 losses update for update: device
+    decompression changes where the bytes expand, never what the step
+    computes. At ``chunk=2`` the deferred frames ride the palette path's
+    K-group program (two scanned updates a dispatch) while inflated raw
+    frames cannot be grouped and keep K'=1."""
+    import jax
+
+    from blendjax.data import StreamDataPipeline
+    from blendjax.models.cnn import CubeRegressor
+    from blendjax.train.steps import make_fused_tile_step, make_train_state
+    from blendjax.transport.wire import decode_message
+
+    B, H, W = 4, 32, 32
+    frames = _rle_wire_frames(6, B, H, W)
+
+    def run(deferred):
+        msgs = [decode_message(f, defer_rle=deferred) for f in frames]
+        assert ("image__ndr" in msgs[0]) == deferred
+        pipe = StreamDataPipeline(
+            iter(msgs), batch_size=B, chunk=chunk, emit_packed=True,
+            place_in_driver=True,
+        )
+        state = make_train_state(
+            CubeRegressor(), np.zeros((B, H, W, 4), np.uint8),
+            rng=jax.random.key(0),
+        )
+        sink = []
+        fused = make_fused_tile_step()
+
+        def step(state, batch):
+            state, m = fused(state, batch)
+            sink.append(m["loss"])  # the K losses of every dispatch
+            return state, m
+
+        drv = TrainDriver(
+            step, state, inflight=2, sync_every=0, place=pipe.feeder.place
+        )
+        with pipe:
+            for b in pipe:
+                drv.submit(b)
+        drv.finish()
+        assert drv.steps == len(frames) // (chunk if deferred else 1)
+        return np.concatenate([np.asarray(v).reshape(-1) for v in sink])
+
+    reg.reset()
+    on_device = run(True)
+    assert "decode.dispatch" not in reg.report()["spans"]
+    on_host = run(False)
+    assert on_device.dtype == np.float32 and on_device.shape == (len(frames),)
+    np.testing.assert_array_equal(on_device, on_host)
+
+
 def test_driver_place_mode_matches_feeder_path():
     """Lever 3 (placement folded into the dispatch): a pipeline in
     place_in_driver mode yields HOST batches, the driver commits the
@@ -436,18 +509,10 @@ def test_driver_place_mode_matches_feeder_path():
     from blendjax.data import StreamDataPipeline
     from blendjax.models.cnn import CubeRegressor
     from blendjax.train.steps import make_fused_tile_step, make_train_state
-    from blendjax.transport.wire import decode_message, encode_message
+    from blendjax.transport.wire import decode_message
 
     B, H, W = 4, 32, 32
-    frames = []
-    for i in range(6):
-        img = np.zeros((B, H, W, 4), np.uint8)
-        img[:, 4 + i:14 + i, 6:22] = (i % 3) + 1
-        xy = np.full((B, 8, 2), float(i % 9), np.float32)
-        frames.append(encode_message(
-            {"btid": 0, "_prebatched": True, "image": img, "xy": xy},
-            compress_rle=True, rle_cap=128, compress_min_bytes=512,
-        ))
+    frames = _rle_wire_frames(6, B, H, W)
 
     def run(place_in_driver):
         msgs = [

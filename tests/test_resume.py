@@ -24,11 +24,6 @@ import numpy as np
 
 WORKER = os.path.join(os.path.dirname(__file__), "ckpt_worker.py")
 
-# same-mesh resume replays identical float ops -> exact equality;
-# cross-mesh resume inherits the collective-reduction-reorder bar the
-# 1-vs-8 equality tests pin (tests/test_mesh_driver.py)
-F32_EXACT_ATOL = 5e-6
-
 
 def _env():
     env = dict(os.environ)
@@ -46,9 +41,13 @@ def _run(args, timeout=300):
     return proc.stdout
 
 
-def _losses(path):
+def _result(path):
     with open(path) as f:
-        return json.load(f)["losses"]
+        return json.load(f)
+
+
+def _losses(path):
+    return _result(path)["losses"]
 
 
 def _wait_committed(directory, timeout=180):
@@ -128,12 +127,31 @@ def test_kill9_resume_8dev_mesh_and_elastic_8_to_4(tmp_path):
 
     # elastic: the preempted 8-chip job continues on 4 chips — the
     # snapshot's global arrays re-place under the 4-way shardings
-    # (state_shardings on the new mesh) and the trajectory matches to
-    # the established f32 collective-reorder bar
+    # (state_shardings on the new mesh). Another mesh re-orders the
+    # gradient's reductions, so the bar is what that costs with no
+    # restart at all: the same stream trained uninterrupted on 4 devices.
+    # Measured here (PR 32): 1.478e-5 at update 7, under 1.6e-6 at every
+    # other one (2 devices 1.571e-5, 1 device 3.606e-5, the same update);
+    # the resumed run 1.416e-5 from the snapshot of step 2 and 0.0 from
+    # those of steps 4 and 6. The worker's model computes in bf16 (the
+    # package's default policy): a last-bit difference in an f32 gradient
+    # flips a bf16 rounding in update 7's activations. With
+    # ``dtype=float32`` the same legs differ by 9e-8, which is why the
+    # f32 twins of tests/test_mesh_driver.py and chip_smoke.py hold 5e-6
+    # and this stream cannot. The resumed run is step ``start``'s 8-device
+    # state continued on 4 devices: the same re-ordering over fewer
+    # updates, so it may drift as far as the uninterrupted run and no
+    # further; a restore that lost a moment or a digit would.
+    ref4_out = tmp_path / "ref4.json"
+    _run([str(tmp_path / "ref4"), "--steps", str(steps), "--mesh", "4",
+          "--ckpt-every", "2", "--out", str(ref4_out)])
+    layout_drift = np.max(np.abs(np.subtract(_losses(ref4_out), ref)))
     res4_out = tmp_path / "res4.json"
     out = _run([elastic_dir, "--steps", str(steps), "--mesh", "4",
                 "--resume", "--out", str(res4_out)])
     assert "ckpt_worker done" in out
-    res4 = _losses(res4_out)
-    assert len(res4) == steps
-    np.testing.assert_allclose(res4, ref, rtol=0, atol=F32_EXACT_ATOL)
+    res4 = _result(res4_out)
+    start, res4 = res4["start"], res4["losses"]
+    assert len(res4) == steps and 0 < start < steps
+    assert res4[:start] == ref[:start]  # trained on 8, kept by the session
+    assert np.max(np.abs(np.subtract(res4, ref))) <= layout_drift

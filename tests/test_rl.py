@@ -319,6 +319,34 @@ def test_dqn_step_one_dispatch_updates_state_and_priorities():
     )
 
 
+def test_dqn_step_updates_params_ring_and_priorities_in_place():
+    """The learner step donates the state and the priority vector and
+    only reads the ring: across steps and inserts none of the three is
+    copied (a copy trains the same and doubles the memory)."""
+    from blendjax.testing.donation import DonationAudit
+
+    res, model, state, step = _train_setup(prioritized=True)
+    _insert_batch(res, 32)
+    audit = DonationAudit()
+
+    def mark():
+        audit.snapshot("params", state.params)
+        audit.snapshot("ring", res._buffers)
+        audit.snapshot("priorities", res._priorities)
+
+    state, _ = step(state, res.draw_token(*res.compose(16)))  # compile
+    state, _ = step(state, res.draw_token(*res.compose(16)))  # settle
+    for i in range(4):
+        mark()
+        _insert_batch(res, 8, seed=i + 1)
+        state, m = step(state, res.draw_token(*res.compose(16)))
+    jax.block_until_ready(m["loss"])
+    mark()
+    for label in ("params", "ring", "priorities"):
+        audit.assert_stable(label)
+        assert audit.report()[label]["snapshots"] == 5
+
+
 def test_dqn_target_polyak_moves_inside_the_same_dispatch():
     res, model, state, step = _train_setup()
     _insert_batch(res, 32)
@@ -347,22 +375,40 @@ def test_pg_step_trains_on_returns():
     assert np.isfinite(float(m["loss"]))
 
 
-def test_learner_driver_end_to_end_exact_accounting():
+@pytest.mark.parametrize("n_dev", [None, 8], ids=["one-device", "mesh8"])
+def test_learner_driver_end_to_end_exact_accounting(n_dev):
+    """Actors -> reservoir -> one-dispatch learner, on one device and
+    with ring, priorities and state laid out over an 8-device mesh
+    (``mesh_rl_step_kwargs``): the same counts either way."""
+    from blendjax.parallel import create_mesh
+    from blendjax.rl import mesh_rl_step_kwargs
+
     metrics.reset()
-    res = TrajectoryReservoir(128, rng=0, prioritized=True)
+    mesh = n_dev and create_mesh({"data": n_dev})
+    res = TrajectoryReservoir(128, rng=0, prioritized=True, mesh=mesh)
     env = FakeVecEnv(n=4, horizon=8)
     pool = ActorPool(env, res, HostQPolicy(3, eps_steps=64, seed=1))
     model = QNetwork(hidden=(16,), n_actions=3)
-    state = make_rl_train_state(model, np.zeros((1, 4), np.float32))
-    step = make_dqn_step(res, model.apply)
+    state = make_rl_train_state(
+        model, np.zeros((1, 4), np.float32), mesh=mesh
+    )
+    step = make_dqn_step(
+        res, model.apply,
+        **(mesh_rl_step_kwargs(state, mesh) if mesh else {}),
+    )
     driver = RLTrainDriver(
-        step, state, res, actors=pool, batch_size=16, min_fill=32,
-        sync_every=4, inflight=2,
+        step, state, res, actors=pool, mesh=mesh, batch_size=16,
+        min_fill=32, sync_every=4, inflight=2,
     )
     with pool:
         loss = driver.run_steps(12)
     assert np.isfinite(loss)
     assert driver.steps == 12 and driver.dispatches == 12
+    spans = metrics.report()["spans"]
+    assert spans["train.dispatch"]["count"] == 12  # and no gather beside it
+    assert "rl.sample" not in spans
+    for leaf in (res._priorities, *jax.tree.leaves(res._buffers)):
+        assert len(leaf.sharding.device_set) == (n_dev or 1)
     # the seq-style identity: every drawn row accounted exactly once
     assert res.fresh + res.replayed == 12 * 16
     # actors got >= 12/4 policy snapshots

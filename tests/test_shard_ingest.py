@@ -207,6 +207,43 @@ def test_pipeline_ingest_workers_integration():
         p.close()
 
 
+def test_pipeline_ingest_workers_name_their_shard_and_count_the_wire():
+    """Each of the pool's threads times its receives under its own span
+    (one hot shard beside idle peers means the partition is the problem,
+    not the pool) and nothing lands on the single thread's
+    ``ingest.recv``; the wire's byte pair counts the data stream, and a
+    compressing producer ships fewer bytes than it decodes to."""
+    from blendjax.data import StreamDataPipeline
+    from blendjax.utils.metrics import metrics
+
+    metrics.reset()
+    pubs = [
+        DataPublisherSocket(
+            WILD, btid=k, compress_level=1, compress_min_bytes=1024
+        )
+        for k in range(2)
+    ]
+    feeders = [
+        _publish_async(pub, [_item(k * 16 + i, 32, 32) for i in range(16)])
+        for k, pub in enumerate(pubs)
+    ]
+    with StreamDataPipeline(
+        [p.addr for p in pubs], batch_size=8, ingest_workers=2,
+        timeoutms=5000, max_items=32,
+    ) as pipe:
+        assert sum(len(np.asarray(b["frameid"])) for b in pipe) == 32
+    report = metrics.report()
+    spans, counters = report["spans"], report["counters"]
+    assert "ingest.recv" not in spans
+    assert spans["ingest.recv.shard0"]["count"] >= 16
+    assert spans["ingest.recv.shard1"]["count"] >= 16
+    assert counters["wire.raw_bytes"] > counters["wire.compressed_bytes"] > 0
+    for t in feeders:
+        t.join(timeout=10)
+    for p in pubs:
+        p.close()
+
+
 def test_pipeline_single_worker_keeps_host_ingest():
     from blendjax.data import StreamDataPipeline
 
@@ -266,7 +303,7 @@ def test_pipeline_sharded_max_items_is_global_across_unequal_shards():
 
 def test_wire_counters_scoped_to_data_stream():
     """Control/RPC channels decode through the same codec but must not
-    pollute the wire.raw/compressed byte pair the bench publishes."""
+    pollute the data stream's wire.raw/compressed byte pair."""
     from blendjax.transport import PairChannel
     from blendjax.utils.metrics import metrics
 

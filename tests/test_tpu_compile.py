@@ -2,9 +2,9 @@
 
 libtpu compiles for a *described* TPU v5e (``on-chip-measurement`` guide
 §2.3), so every Pallas kernel of the main path and the jitted steps
-around them are lowered here at the widths the bench streams (480x640x4
-frames, 16x32 and 16x16 tiles) and must come back from the TPU compiler
-with the kernel inside. Nothing runs: this guards against a kernel the
+around them are lowered here at the widths ``chip_smoke.py`` streams
+(``REAL``: 480x640x4 frames, 16x32 and 16x16 tiles) and must come back
+from the TPU compiler with the kernel inside. Nothing runs: this guards against a kernel the
 chip refuses (interpret mode accepts far more), a program that does not
 fit 16 GB, and a mesh step that lost its collective — not against a
 wrong result, which only ``chip_smoke.py`` on the chip can show.
@@ -30,8 +30,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
-import bench
-from blendjax.models import CubeRegressor
+import chip_smoke
+from blendjax.models import CubeRegressor, StreamFormer
 from blendjax.ops import tiles as T
 from blendjax.train import (
     make_echo_fused_step,
@@ -43,8 +43,9 @@ from blendjax.train.mesh_driver import (
     make_mesh_supervised_step,
 )
 
-H, W, C = (*bench.SHAPE, 4)
-B = bench.BATCH
+REAL = chip_smoke.REAL
+H, W, C = (*REAL.shape, 4)
+B = REAL.batch
 V5E_HBM_BYTES = 16 * 2**30
 
 
@@ -98,15 +99,15 @@ def _abstract_state(model, sharding):
 
 
 def _tile_plan(tile, capacity=None):
-    """The packed layout + decode plan of one tile batch at bench
+    """The packed layout + decode plan of one tile batch at ``REAL``
     geometry, as the pipeline's host stage hands it to the fused step —
     the wire shape the cube producers ship (full-channel tiles as 4-bit
-    per-frame palette indices, ``bench.TILE_PAL_BITS``):
+    per-frame palette indices, ``REAL.tile_pal_bits``):
     ``(row_bytes, spec, names, geoms, ref_shape)``."""
     th, tw = T.tile_hw(tile)
-    cap = int(capacity or bench.tile_capacity_default(th, tw))
+    cap = int(capacity or chip_smoke.TILE_CAPACITY[th, tw])
     n = (H // th) * (W // tw)
-    bits = int(bench.TILE_PAL_BITS)
+    bits = int(REAL.tile_pal_bits)
     buf, spec = T.pack_fields({
         "image" + T.TILEIDX_SUFFIX: np.zeros((B, cap), np.int32),
         "image" + T.TILEPAL_SUFFIXES[bits]: np.zeros(
@@ -173,8 +174,8 @@ def _attn_loss(backend, causal=False):
 )
 def test_flash_attention_fwd_bwd_compiles(topo, tpu_branches, shape, causal):
     """The fused kernel under the blocks ``flash_block_sizes`` computes
-    from the shape, at the bench's longseq and live-row attention
-    shapes, a padded causal one, and the most keys it admits (bf16)."""
+    from the shape, at ``chip_smoke.py``'s flash shape, the StreamFormer's
+    768 tokens, a padded causal one, and the most keys it admits (bf16)."""
     q = _sds(shape, jnp.bfloat16, SingleDeviceSharding(topo.devices[0]))
     compiled = _attn_loss("flash", causal).lower(q, q, q).compile()
     _assert_fits_with_kernel(compiled)
@@ -257,7 +258,8 @@ def _lower_fused_tile(step, state, chunk, sharding, plan):
     [
         lambda: (CubeRegressor(), None),
         pytest.param(
-            bench._transformer_model_and_loss, marks=pytest.mark.slow
+            lambda: (StreamFormer(**REAL.former), chip_smoke.former_loss),
+            marks=pytest.mark.slow,
         ),
     ],
     ids=["cnn", "streamformer"],
@@ -265,14 +267,14 @@ def _lower_fused_tile(step, state, chunk, sharding, plan):
 def test_fused_tile_step_compiles_with_the_kernel(
     topo, tpu_branches, model_and_loss
 ):
-    """``make_fused_tile_step`` whole at the bench's K=16 — unpack,
+    """``make_fused_tile_step`` whole at ``REAL``'s K=16 — unpack,
     palette expand, Pallas decode, 16 scanned updates — with the kernel
     branch actually taken and the expansion without a gather."""
     one = SingleDeviceSharding(topo.devices[0])
     model, loss_fn = model_and_loss()
     step = make_fused_tile_step(loss_fn=loss_fn)
     compiled = _lower_fused_tile(
-        step, _abstract_state(model, one), bench.CHUNK, one,
+        step, _abstract_state(model, one), REAL.chunk, one,
         _tile_plan((16, 32)),
     ).compile()
     _assert_no_gather_in_palette_expand(_assert_fits_with_kernel(compiled))
@@ -336,7 +338,7 @@ def test_four_chip_sharded_decode_keeps_the_kernel(topo, tpu_branches, mesh4):
     rep = NamedSharding(mesh4, P())
     by_batch = NamedSharding(mesh4, P("data"))
     th, tw = 16, 32
-    cap = int(bench.tile_capacity_default(th, tw))
+    cap = int(chip_smoke.TILE_CAPACITY[th, tw])
     n = (H // th) * (W // tw)
     fn = jax.jit(
         lambda r, i, tl: T.decode_tile_delta(r, i, tl, (H, W, C), mesh=mesh4)
@@ -463,7 +465,7 @@ def test_fused_palette_group_compiles(topo):
     step = make_fused_tile_step()
     compiled = step.jits["pal"].lower(
         _abstract_state(CubeRegressor(), one),
-        _sds((bench.RAW_CHUNK, buf.shape[0]), jnp.uint8, one),
+        _sds((8, buf.shape[0]), jnp.uint8, one),
         spec, (("image", (H, W, C, bits)),), (),
     ).compile()
     _assert_fits_with_kernel(compiled, kernel=False)
