@@ -14,15 +14,20 @@ Net-new vs the reference (blendtorch has no sequence models, SURVEY.md
   saved log-sum-exp. No (B, H, T, T) tensor reaches HBM, forward or
   backward. It works on (B, T, H·D), the layout the projections
   around it read and write, 128 lanes — 128 // D whole heads — a
-  block, so nothing is transposed. Any T: Q/K/V are padded to the
-  blocks, padded keys masked. The same precision as ``xla``:
-  input-dtype MXU operands, f32 accumulation, f32 max/sum/exp.
+  block, so nothing is transposed. Any T, at its own length: K/V are
+  padded to the score tile's 128 lanes in VMEM, inside the kernel
+  (padded keys masked), and Q runs unpadded wherever a block of whole
+  sublane tiles divides T (1,200 tokens run as 1,200; no pad and no
+  slice in HBM). Only a T no such block divides (197) pads Q in HBM.
+  The same precision as ``xla``: input-dtype MXU operands, f32
+  accumulation, f32 max/sum/exp.
 
 ``auto`` policy: one algorithm that wants a different path by size, so
 it reads the bytes of f32 scores a materialised call would write
 (:func:`scores_residual_bytes`) on a TPU. Measured on one TPU v5e
-(my chip run, PR 26; ``scripts/attn_core_time.py``: the core alone,
-forward + backward, bf16, 12 chained calls a dispatch, ms a call):
+(my chip runs, PR 26, the last two rows again in PR 33 with today's
+geometry; ``scripts/attn_core_time.py``: the core alone, forward +
+backward, bf16, 12 chained calls a dispatch, ms a call):
 
 ====================  ============  ======  ======  =========
 shape (B, T, H, D)    score bytes   xla     flash   xla/flash
@@ -33,8 +38,8 @@ shape (B, T, H, D)    score bytes   xla     flash   xla/flash
 (8, 320, 12, 64)      39.3 MB       0.343   0.251   1.37
 (8, 384, 12, 64)      56.6 MB       0.549   0.213   2.58
 (8, 768, 4, 128)      75.5 MB       0.871   0.253   3.4
-(8, 1200, 12, 64)     553 MB        8.959   1.889   4.7
-(4, 3072, 4, 128)     604 MB        9.094   1.688   5.4
+(8, 1200, 12, 64)     553 MB        8.960   1.628   5.5
+(4, 3072, 4, 128)     604 MB        9.100   1.675   5.4
 ====================  ============  ======  ======  =========
 
 The materialised path sits on the HBM's bandwidth (PERF.md §5: 84 % of
@@ -53,8 +58,9 @@ ids 15.8 at its default 128 blocks — what the old "in-model the
 materialised path keeps winning" note had measured — and 3.94 at the
 best of seven block choices (640/640/640); this kernel's first version
 on head-major (B, H, T, D) operands 2.07 alone, but 24.5 ms an update
-in the model against this one's 21.3, and 7.6 ms more outside the
-core, for the transposes it forced on its neighbours. Explicit
+in the model against this one's 21.3 (19.9 since PR 33 runs 1,200
+tokens as 1,200), and 7.6 ms more outside the core, for the
+transposes it forced on its neighbours. Explicit
 ``backend="flash"`` always takes the kernel.
 
 Under a multi-device mesh the kernel is a custom call GSPMD cannot
@@ -94,13 +100,19 @@ from blendjax.utils.metrics import (
 # Set from the measurement in the module docstring.
 FLASH_RESIDUAL_BYTES = 24 << 20
 # The kernel keeps one head's K and V in VMEM whole and works on
-# [block_q, padded_kv] score tiles of about FLASH_TILE_ELEMS (measured
-# at 1,200 tokens with the kernel's head-major first version: block_q
-# 128 / 256 / 640 / 1,280 took 2.55 / 2.23 / 2.07 / 2.08 ms a call).
-# FLASH_MAX_KV is what the chip's compiler accepts
-# under FLASH_VMEM_BYTES of the v5e's 128 MiB (tests/test_tpu_compile.py).
+# [block_q, padded_kv] score tiles of at most FLASH_TILE_ELEMS. Measured
+# at (8, 1200, 12, 64) with ``scripts/attn_core_time.py --block-q`` (my
+# chip run, PR 33; ms a call): unpadded blocks of 240 / 400 / 600 /
+# 1,200 rows 1.722 / 1.770 / 1.662 / 1.629, PR 26's 640 rows on Q
+# padded to 1,280 1.857. One block of 1,200 wins, so the bound admits
+# it; 400 loses to 240 because the backward's dK and dV contract over
+# the block's rows and the MXU runs that in 128s (400 as 512). At
+# (4, 3072, 4, 128) blocks of 256 / 512 / 768 rows took 1.687 / 1.673 /
+# 1.673. FLASH_MAX_KV (with a 128-row block, the same tile) is what the
+# chip's compiler accepts under FLASH_VMEM_BYTES of the v5e's 128 MiB
+# (tests/test_tpu_compile.py).
 FLASH_MAX_KV = 16384
-FLASH_TILE_ELEMS = 1 << 20
+FLASH_TILE_ELEMS = 1 << 21
 FLASH_VMEM_BYTES = 100 << 20
 _LANES = 128
 # exp(_MASK - lse) is exactly 0 and _MASK - _MASK is not NaN
@@ -113,26 +125,45 @@ def _round_up(n: int, m: int) -> int:
     return -(-int(n) // m) * m
 
 
+def _sublanes(dtype) -> int:
+    """Rows of one (sublane, 128) tile of ``dtype``: 8 for f32, 16 for
+    bf16. A block of whole tiles is what the chip's compiler takes
+    without a pad."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
 class FlashBlocks(NamedTuple):
-    """Launch geometry of one fused call: query rows a grid step and the
-    lengths Q and K/V are padded to."""
+    """Launch geometry of one fused call: query rows a grid step, the
+    rows Q has in HBM (``t_q`` itself where a block divides it) and the
+    keys of a score tile (K/V in VMEM, a multiple of 128 lanes)."""
 
     block_q: int
     padded_q: int
     padded_kv: int
 
 
-def flash_block_sizes(t_q: int, t_kv: int) -> FlashBlocks:
+def flash_block_sizes(t_q: int, t_kv: int, dtype=jnp.bfloat16) -> FlashBlocks:
     """Launch geometry from the shape — the one source of truth for
-    eligibility (:func:`flash_supported`) and launch. K/V are padded to
-    the next multiple of 128 (the score tile's lanes) and stay in VMEM
-    whole; the query block is the largest multiple of 128 whose
-    [block_q, padded_kv] score tile stays under FLASH_TILE_ELEMS, then
-    shrunk so the blocks pad ``t_q`` as little as they can."""
-    padded_kv = _round_up(t_kv, _LANES)
-    cap = max(_LANES, FLASH_TILE_ELEMS // padded_kv // _LANES * _LANES)
-    n = -(-int(t_q) // cap)
-    block_q = _round_up(-(-int(t_q) // n), _LANES)
+    eligibility (:func:`flash_supported`) and launch. K/V stay in VMEM
+    whole, padded there to the next multiple of 128 (the score tile's
+    lanes). The query block is the largest divisor of ``t_q`` made of
+    whole sublane tiles of ``dtype`` whose [block_q, padded_kv] score
+    tile stays under FLASH_TILE_ELEMS, so Q is not padded at all
+    (1,200 rows: one block; 3,072: six of 512), if it is ``t_q`` itself
+    or at least 128 rows; where ``t_q`` has no such divisor (197, 130;
+    600 in bf16), the largest multiple of 128 under the bound, shrunk
+    so the blocks pad ``t_q`` as little as they can."""
+    t_q, padded_kv = int(t_q), _round_up(t_kv, _LANES)
+    cap = max(_LANES, FLASH_TILE_ELEMS // padded_kv)
+    tile = _sublanes(dtype)
+    exact = max(
+        (b for b in range(tile, min(cap, t_q) + 1, tile) if t_q % b == 0),
+        default=0,
+    )
+    if exact == t_q or exact >= _LANES:
+        return FlashBlocks(exact, t_q, padded_kv)
+    n = -(-t_q // (cap // _LANES * _LANES))
+    block_q = _round_up(-(-t_q // n), _LANES)
     return FlashBlocks(block_q, n * block_q, padded_kv)
 
 
@@ -149,8 +180,9 @@ def scores_residual_bytes(q, k=None) -> int:
 
 def flash_supported(q, k=None) -> bool:
     """Whether the fused kernel can take these (B, T, H, D) inputs: any
-    sequence length (padded and masked inside :func:`local_attention`)
-    whose K/V stay in VMEM, and heads that fill 128-lane blocks whole
+    sequence length (K/V padded and masked inside the kernel, Q padded
+    around it where no block divides it) whose K/V stay in VMEM, and
+    heads that fill 128-lane blocks whole
     (D = 128, or a divisor of it with H a multiple of 128 // D)."""
     k = q if k is None else k
     if not (q.ndim == 4 and k.ndim == 4):
@@ -272,16 +304,39 @@ def _merge(parts, heads):
     return out
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, d, scale, t_kv,
+def _kv_in_vmem(k_ref, v_ref, pads, first):
+    """One head block's K and V as [padded_kv, 128] values. Where
+    ``t_kv`` does not fill the score tile's lanes, the first grid step
+    of a (batch, head block) copies them into the ``pads`` scratch and
+    zeroes its tail: the padding is made here, not in HBM. (A padded
+    key is masked, but its product must be finite.)"""
+    from jax.experimental import pallas as pl
+
+    if not pads:
+        return k_ref[0], v_ref[0]
+    t_kv = k_ref.shape[1]
+
+    @pl.when(first)
+    def _():
+        for ref, pad in zip((k_ref, v_ref), pads):
+            pad[:t_kv] = ref[0]
+            pad[t_kv:] = jnp.zeros((pad.shape[0] - t_kv, _LANES), pad.dtype)
+
+    return pads[0][...], pads[1][...]
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *kv_pads, d, scale,
                 causal):
     from jax.experimental import pallas as pl
 
-    q, k, v = q_ref[0], k_ref[0], v_ref[0]
+    i = pl.program_id(2)
+    q = q_ref[0]
+    k, v = _kv_in_vmem(k_ref, v_ref, kv_pads, i == 0)
     heads = _head_lanes(d)
     outs, lses = [], []
     for lanes in heads:
-        s = _scores(_only(q, lanes), k, scale=scale, t_kv=t_kv,
-                    causal=causal, row0=pl.program_id(2) * q.shape[0])
+        s = _scores(_only(q, lanes), k, scale=scale, t_kv=k_ref.shape[1],
+                    causal=causal, row0=i * q.shape[0])
         m = jnp.max(s, axis=1, keepdims=True)
         p = jnp.exp(s - m)
         l = jnp.sum(p, axis=1, keepdims=True)
@@ -293,7 +348,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, d, scale, t_kv,
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, d, scale, t_kv,
+                dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *kv_pads, d, scale,
                 causal):
     from jax.experimental import pallas as pl
 
@@ -304,13 +359,14 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    q, k, v = q_ref[0], k_ref[0], v_ref[0]
+    q = q_ref[0]
+    k, v = _kv_in_vmem(k_ref, v_ref, kv_pads, i == 0)
     o, lse = o_ref[0].astype(jnp.float32), lse_ref[0]
     heads = _head_lanes(d)
     dqs = []
     for h, lanes in enumerate(heads):
         qh, doh = _only(q, lanes), _only(do_ref[0], lanes)
-        s = _scores(qh, k, scale=scale, t_kv=t_kv, causal=causal,
+        s = _scores(qh, k, scale=scale, t_kv=k_ref.shape[1], causal=causal,
                     row0=i * q.shape[0])
         p = jnp.exp(s - lse[:, h * d:h * d + 1])
         dp = lax.dot_general(doh, v, _NT, preferred_element_type=jnp.float32)
@@ -329,19 +385,29 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
 
     @pl.when(i == pl.num_programs(2) - 1)
     def _():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+        t_kv = dk_ref.shape[1]
+        dk_ref[0] = dk_acc[:t_kv].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:t_kv].astype(dv_ref.dtype)
 
 
 def _specs(q, k, block_q):
+    """Block specs, grid and the K/V scratch of one launch: Q-shaped
+    operands by ``block_q`` rows, K/V-shaped ones whole, and, where K/V
+    do not fill the score tile's lanes, the [padded_kv, 128] VMEM
+    buffers the kernel pads them into."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     rows = pl.BlockSpec((1, block_q, _LANES), lambda b, j, i: (b, i, j))
     whole = pl.BlockSpec(
         (1, k.shape[1], _LANES), lambda b, j, i: (b, 0, j)
     )
     grid = (q.shape[0], q.shape[2] // _LANES, q.shape[1] // block_q)
-    return rows, whole, grid
+    padded_kv = _round_up(k.shape[1], _LANES)
+    kv_pads = [] if padded_kv == k.shape[1] else [
+        pltpu.VMEM((padded_kv, _LANES), k.dtype)
+    ] * 2
+    return rows, whole, grid, padded_kv, kv_pads
 
 
 def _compiler_params(*semantics):
@@ -352,13 +418,12 @@ def _compiler_params(*semantics):
     )
 
 
-def _flash_fwd(q, k, v, d, t_kv, causal, scale, block_q):
+def _flash_fwd(q, k, v, d, causal, scale, block_q):
     from jax.experimental import pallas as pl
 
-    rows, whole, grid = _specs(q, k, block_q)
+    rows, whole, grid, _, kv_pads = _specs(q, k, block_q)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, d=d, scale=scale, t_kv=t_kv,
-                          causal=causal),
+        functools.partial(_fwd_kernel, d=d, scale=scale, causal=causal),
         grid=grid,
         in_specs=[rows, whole, whole],
         out_specs=[rows, rows],
@@ -366,48 +431,50 @@ def _flash_fwd(q, k, v, d, t_kv, causal, scale, block_q):
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct(q.shape, jnp.float32),
         ],
-        compiler_params=_compiler_params("parallel", "parallel", "parallel"),
+        scratch_shapes=kv_pads,
+        # the K/V scratch is filled at a (batch, head block)'s first
+        # query block, so those run in order on one core
+        compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
         interpret=jax.default_backend() != "tpu",
         name=KERNEL_FLASH_FWD,
     )(q, k, v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_core(q, k, v, d, t_kv, causal, scale, block_q):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_core(q, k, v, d, causal, scale, block_q):
     """softmax(q kᵀ · scale) v per head of width ``d`` over (B, T, H·D)
-    operands already padded to :func:`flash_block_sizes`; keys from
-    ``t_kv`` on are padding. Scores, softmax and both matmuls of a
-    [block_q, padded_kv] tile happen in VMEM: no [B, H, T, T] tensor
-    reaches HBM, forward or backward. bf16 (input dtype) MXU operands,
-    f32 accumulation, f32 max/sum/exp, probabilities cast only for the
-    matmuls that consume them."""
-    return _flash_fwd(q, k, v, d, t_kv, causal, scale, block_q)[0]
+    operands: Q in whole blocks of ``block_q`` rows, K/V at their own
+    length. Scores, softmax and both matmuls of a [block_q, padded_kv]
+    tile happen in VMEM: no [B, H, T, T] tensor reaches HBM, forward or
+    backward. bf16 (input dtype) MXU operands, f32 accumulation, f32
+    max/sum/exp, probabilities cast only for the matmuls that consume
+    them."""
+    return _flash_fwd(q, k, v, d, causal, scale, block_q)[0]
 
 
-def _flash_core_fwd(q, k, v, d, t_kv, causal, scale, block_q):
-    o, lse = _flash_fwd(q, k, v, d, t_kv, causal, scale, block_q)
+def _flash_core_fwd(q, k, v, d, causal, scale, block_q):
+    o, lse = _flash_fwd(q, k, v, d, causal, scale, block_q)
     return o, (q, k, v, o, lse)
 
 
-def _flash_core_bwd(d, t_kv, causal, scale, block_q, res, do):
+def _flash_core_bwd(d, causal, scale, block_q, res, do):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     q, k, v, o, lse = res
-    rows, whole, grid = _specs(q, k, block_q)
+    rows, whole, grid, padded_kv, kv_pads = _specs(q, k, block_q)
     # a custom_vjp's backward is traced outside the forward's scope
     with jax.named_scope(SCOPE_ATTN_CORE):
         return tuple(pl.pallas_call(
-            functools.partial(_bwd_kernel, d=d, scale=scale, t_kv=t_kv,
-                              causal=causal),
+            functools.partial(_bwd_kernel, d=d, scale=scale, causal=causal),
             grid=grid,
             in_specs=[rows, whole, whole, rows, rows, rows],
             out_specs=[rows, whole, whole],
             out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
                        for x in (q, k, v)],
             scratch_shapes=[
-                pltpu.VMEM((k.shape[1], _LANES), jnp.float32)
-            ] * 2,
+                pltpu.VMEM((padded_kv, _LANES), jnp.float32)
+            ] * 2 + kv_pads,
             compiler_params=_compiler_params(
                 "parallel", "parallel", "arbitrary"
             ),
@@ -420,23 +487,24 @@ _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
 def _flash_attention(q, k, v, causal, scale):
-    """(B, T, H, D) in and out around :func:`_flash_core`: pad T to the
-    kernel's blocks and fold heads into lanes — (B, T, H·D), the layout
-    the projections around the core read and write, so nothing is
-    transposed — then slice the padding off. Padded query rows get zero
-    cotangents and padded keys probability 0, so the gradients are
-    exact."""
+    """(B, T, H, D) in and out around :func:`_flash_core`: fold heads
+    into lanes — (B, T, H·D), the layout the projections around the core
+    read and write, so nothing is transposed. K and V always go in at
+    their own length. Where a block divides ``t_q`` (1,200 tokens) so
+    does Q, and the reshape is all: the operands, the residuals and the
+    gradients keep the input's length. Otherwise Q is padded to its
+    blocks in HBM and the padding sliced off the output; padded query
+    rows get zero cotangents, so the gradients are exact either way."""
     b, t_q, h, d = q.shape
-    blocks = flash_block_sizes(t_q, k.shape[1])
-
-    def lay(x, padded):
-        x = jnp.pad(x, ((0, 0), (0, padded - x.shape[1]), (0, 0), (0, 0)))
-        return x.reshape(b, padded, h * d)
-
+    blocks = flash_block_sizes(t_q, k.shape[1], q.dtype)
+    if blocks.padded_q == t_q:
+        metrics.count("attn.path.flash_exact")
+    else:
+        metrics.count("attn.path.flash_padded")
+        q = jnp.pad(q, ((0, 0), (0, blocks.padded_q - t_q), (0, 0), (0, 0)))
     o = _flash_core(
-        lay(q, blocks.padded_q), lay(k, blocks.padded_kv),
-        lay(v, blocks.padded_kv), d, k.shape[1], causal, scale,
-        blocks.block_q,
+        *(x.reshape(b, x.shape[1], h * d) for x in (q, k, v)),
+        d, causal, scale, blocks.block_q,
     )
     return o[:, :t_q].reshape(b, t_q, h, d)
 
@@ -450,8 +518,10 @@ def local_attention(q, k, v, causal: bool = False, scale=None,
     silently measuring xla — same explicitness contract as the tile
     decode's ``use_pallas`` — and off a TPU runs the kernel in
     interpreter mode. The path traced is counted (once per trace)
-    under ``attn.path.xla`` or ``attn.path.flash``, plus
-    ``attn.path.shard_map`` when the kernel was wrapped for a mesh.
+    under ``attn.path.xla`` or ``attn.path.flash``, the kernel's also
+    under ``attn.path.flash_exact`` (no operand padded in HBM) or
+    ``attn.path.flash_padded``, plus ``attn.path.shard_map`` when the
+    kernel was wrapped for a mesh.
     """
     if backend not in ("auto", "flash", "xla"):
         # ValueError, not assert: a typo'd backend under `python -O`

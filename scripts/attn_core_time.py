@@ -3,12 +3,17 @@
 fused, on the chip: the measurement ``FLASH_RESIDUAL_BYTES`` in
 ``blendjax/ops/attention.py`` is set from.
 
-    python scripts/attn_core_time.py [B,T,H,D ...]
+    python scripts/attn_core_time.py [--block-q N,N,...] [B,T,H,D ...]
 
 One JSON line a shape and backend: ms a call (12 chained calls a
-dispatch, bf16, host clock around ``block_until_ready``) and the bytes
-of scores the ``auto`` policy reads. Exits 2 off a TPU: a CPU time says
-nothing about either path.
+dispatch, bf16, host clock around ``block_until_ready``), the bytes of
+scores the ``auto`` policy reads and, for the kernel, the launch
+geometry. ``--block-q`` times the kernel at each of the given query
+blocks in place of the one ``flash_block_sizes`` computes — the sweep
+FLASH_TILE_ELEMS is set from; the library itself has no such argument,
+the script replaces the function for the run. A block that does not
+divide T pads Q to it. Exits 2 off a TPU: a CPU time says nothing about
+either path.
 """
 
 import json
@@ -21,6 +26,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 
+from blendjax.ops import attention as A
 from blendjax.ops.attention import local_attention, scores_residual_bytes
 
 SHAPES = [(8, 197, 12, 64), (8, 768, 4, 128), (8, 1200, 12, 64),
@@ -44,23 +50,44 @@ def ms_per_call(backend, q, k, v, w):
     return (time.perf_counter() - start) / CALLS / LAYERS * 1e3
 
 
+def with_block_q(block_q):
+    """``flash_block_sizes`` with the query block replaced."""
+    rule = A.flash_block_sizes
+
+    def forced(t_q, t_kv, dtype=jnp.bfloat16):
+        padded_q = -(-t_q // block_q) * block_q
+        return A.FlashBlocks(block_q, padded_q, rule(t_q, t_kv, dtype).padded_kv)
+
+    return forced
+
+
 def main(argv):
     if jax.default_backend() != "tpu":
         print("attn_core_time: no TPU here", file=sys.stderr)
         return 2
+    sweep = []
+    if argv and argv[0] == "--block-q":
+        sweep, argv = [int(n) for n in argv[1].split(",")], argv[2:]
     shapes = [tuple(int(n) for n in a.split(",")) for a in argv] or SHAPES
+    rule = A.flash_block_sizes
     for shape in shapes:
         keys = jax.random.split(jax.random.key(0), 4)
         q, k, v = (jax.random.normal(key, shape, jnp.bfloat16)
                    for key in keys[:3])
         w = jax.random.normal(keys[3], shape, jnp.float32)
-        for backend in ("xla", "flash"):
+        runs = [("flash", n) for n in sweep] or [("xla", None), ("flash", None)]
+        for backend, block_q in runs:
+            A.flash_block_sizes = with_block_q(block_q) if block_q else rule
             print(json.dumps({
                 "shape": shape, "backend": backend,
                 "scores_bytes": scores_residual_bytes(q),
+                "blocks": tuple(
+                    A.flash_block_sizes(shape[1], shape[1], q.dtype)
+                ) if backend == "flash" else None,
                 "ms_per_call": ms_per_call(backend, q, k, v, w),
                 "device": jax.devices()[0].device_kind,
             }), flush=True)
+        A.flash_block_sizes = rule
     return 0
 
 

@@ -1,7 +1,8 @@
 """Local-attention backend dispatch (blendjax.ops.attention).
 
 The fused kernel runs here in Pallas interpreter mode, so the whole
-flash path — pad, mask, kernel forward and backward, slice — is compared
+flash path — mask, kernel forward and backward, and the pad and slice
+of the lengths no block divides — is compared
 with ``reference_attention`` on the CPU; its speed and the chip's
 compiler are tests/test_tpu_compile.py's and chip_smoke.py's.
 """
@@ -96,26 +97,54 @@ def test_flash_support_checks_kv_length_too():
     assert not flash_supported(_Shape(128, 2, 64))
 
 
+def _aligned_divisor(t_q, tile):
+    return any(t_q % b == 0 for b in range(tile, t_q + 1, tile))
+
+
 @pytest.mark.parametrize(
-    "t_q, t_kv",
-    [(64, 64), (130, 130), (197, 197), (1200, 1200), (3072, 3072),
-     (256, 1000), (5000, 16384)],
+    "t_q, t_kv, dtype",
+    [(64, 64, jnp.bfloat16), (130, 130, jnp.bfloat16),
+     (197, 197, jnp.bfloat16), (1200, 1200, jnp.bfloat16),
+     (3072, 3072, jnp.bfloat16), (256, 1000, jnp.bfloat16),
+     (5000, 16384, jnp.bfloat16), (1200, 1200, jnp.float32),
+     (400, 1200, jnp.bfloat16)],
 )
-def test_flash_block_sizes_from_the_shape(t_q, t_kv):
+def test_flash_block_sizes_from_the_shape(t_q, t_kv, dtype):
     """Eligibility and launch share one source of truth, computed from
-    the shape: the blocks tile the padded lengths, the padding is less
-    than one block (q) and one lane tile (kv), and the score tile stays
+    the shape: whole sublane tiles a block, blocks that tile the rows Q
+    has in HBM — ``t_q`` itself, unpadded, wherever it has an aligned
+    divisor worth taking; elsewhere 128-row blocks and less than one of
+    padding — one lane tile of key padding at most, and a score tile
     within FLASH_TILE_ELEMS wherever a 128-row block allows."""
-    block_q, padded_q, padded_kv = flash_block_sizes(t_q, t_kv)
-    assert block_q % 128 == 0 and padded_kv % 128 == 0
+    tile = A._sublanes(dtype)
+    block_q, padded_q, padded_kv = flash_block_sizes(t_q, t_kv, dtype)
+    assert block_q % tile == 0 and padded_kv % 128 == 0
     assert padded_q % block_q == 0
     assert t_q <= padded_q < t_q + block_q
     assert t_kv <= padded_kv < t_kv + 128
-    assert block_q == 128 or block_q * padded_kv <= FLASH_TILE_ELEMS
+    assert block_q <= 128 or block_q * padded_kv <= FLASH_TILE_ELEMS
+    if padded_q != t_q:
+        assert block_q % 128 == 0
+    if _aligned_divisor(t_q, tile) and t_q * padded_kv <= FLASH_TILE_ELEMS:
+        assert (block_q, padded_q) == (t_q, t_q)
+    # the seven shapes of PR 26 with their geometry, by name
+    assert (padded_q == t_q) == (t_q not in (130, 197, 5000))
 
 
 def test_flash_block_sizes_at_the_benchmark_shape():
-    assert flash_block_sizes(1200, 1200) == (640, 1280, 1280)
+    """1,200 tokens are one block of 1,200 rows against 1,280 key lanes;
+    bf16 blocks are whole 16-row tiles, so 600 rows pad where f32's
+    8-row tiles do not; 768 keeps PR 26's geometry and 3,072 takes the
+    512 rows the raised bound admits."""
+    assert flash_block_sizes(1200, 1200) == (1200, 1200, 1280)
+    assert flash_block_sizes(1200, 1200, jnp.float32) == (1200, 1200, 1280)
+    assert flash_block_sizes(400, 1200) == (400, 400, 1280)
+    assert flash_block_sizes(600, 1200) == (640, 640, 1280)
+    assert flash_block_sizes(600, 1200, jnp.float32) == (600, 600, 1280)
+    assert flash_block_sizes(2400, 2400) == (800, 2400, 2432)
+    assert flash_block_sizes(3072, 3072) == (512, 3072, 3072)
+    assert flash_block_sizes(768, 768) == (768, 768, 768)
+    assert flash_block_sizes(197, 197) == (256, 256, 256)
 
 
 @pytest.mark.parametrize(
@@ -184,6 +213,76 @@ def test_flash_pad_and_mask_matches_reference(t, t_kv, causal):
     for got, ref in zip(grads, want_grads):
         assert got.shape == ref.shape
         np.testing.assert_allclose(got, ref, atol=5e-6)
+
+
+def _primitives_around_the_core(jaxpr):
+    """Names of what a traced flash call does outside the kernels'
+    ``custom_vjp_call``, through the ``jit`` of ``jnp.pad``."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        inner = eqn.params.get("jaxpr")
+        if eqn.primitive.name == "jit" and inner is not None:
+            names |= _primitives_around_the_core(inner.jaxpr)
+        else:
+            names.add(eqn.primitive.name)
+    return names
+
+
+@pytest.mark.parametrize(
+    "t, t_kv, path",
+    [(1200, None, "exact"), (197, None, "padded"), (400, 1200, "exact"),
+     (64, 300, "exact"), (130, 256, "padded")],
+    ids=["T1200", "T197", "cross-400x1200", "cross-64x300", "cross-130x256"],
+)
+def test_flash_path_counter_says_whether_hbm_holds_padding(t, t_kv, path):
+    """``attn.path.flash_exact`` where every operand enters the kernel
+    at its own length (K/V always do: 300 keys are padded in VMEM),
+    ``flash_padded`` where no block divides ``t_q`` and Q is padded in
+    HBM — and no ``pad`` and no ``slice`` in the traced program in the
+    first case."""
+    q, k, v = _qkv(t=t, t_kv=t_kv, b=1)
+    before = _attn_counts()
+    jaxpr = jax.make_jaxpr(
+        lambda *a: local_attention(*a, backend="flash")
+    )(q, k, v)
+    after = _attn_counts()
+    other = {"exact": "padded", "padded": "exact"}[path]
+    moved = {
+        name: after.get(f"attn.path.{name}", 0)
+        - before.get(f"attn.path.{name}", 0)
+        for name in ("flash", f"flash_{path}", f"flash_{other}")
+    }
+    assert moved == {"flash": 1, f"flash_{path}": 1, f"flash_{other}": 0}
+    outside = _primitives_around_the_core(jaxpr.jaxpr)
+    assert ("pad" in outside) == (path == "padded"), outside
+    assert ("slice" in outside) == (path == "padded"), outside
+
+
+@pytest.mark.parametrize(
+    "t, t_kv, dtype",
+    [(1200, None, jnp.float32), (400, 1200, jnp.float32),
+     (1200, None, jnp.bfloat16)],
+    ids=["T1200", "cross-400x1200", "T1200-bf16"],
+)
+def test_flash_exact_length_gradients_match_reference(t, t_kv, dtype):
+    """An exact-length call (no operand padded in HBM, K/V padded in
+    VMEM): the gradients have the inputs' shapes and dtypes and equal
+    ``reference_attention``'s."""
+    q, k, v = _qkv(t=t, t_kv=t_kv, dtype=dtype)
+    assert flash_block_sizes(t, t_kv or t, dtype).padded_q == t
+    w = jax.random.normal(jax.random.key(9), q.shape, jnp.float32)
+    loss, grads = _loss_and_grads(
+        lambda *a: local_attention(*a, backend="flash"), q, k, v, w
+    )
+    want, want_grads = _loss_and_grads(reference_attention, q, k, v, w)
+    f32 = dtype == jnp.float32
+    np.testing.assert_allclose(loss, want, rtol=1e-5 if f32 else 2e-2)
+    for got, ref, x in zip(grads, want_grads, (q, k, v)):
+        assert got.shape == x.shape and got.dtype == x.dtype
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(ref, np.float32),
+            atol=5e-6 if f32 else 2e-2,
+        )
 
 
 @pytest.mark.parametrize("h, d", [(1, 128), (4, 32)], ids=["D128", "D32"])

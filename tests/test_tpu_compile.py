@@ -163,22 +163,44 @@ def _attn_loss(backend, causal=False):
     return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
 
 
+def _under_attn_core(text, *ops):
+    """The compiled program's lines whose operation is one of ``ops``
+    (``pad``, ``slice``) and carries the ``attn_core`` scope."""
+    from blendjax.utils.metrics import SCOPE_ATTN_CORE
+
+    return [
+        ln.strip()[:200] for ln in text.splitlines()
+        if SCOPE_ATTN_CORE in ln and any(f" {op}(" in ln for op in ops)
+    ]
+
+
 @pytest.mark.parametrize(
-    "shape, causal",
+    "shape, causal, dtype, hbm_pads",
     [
-        ((4, 3072, 4, 128), False), ((8, 768, 4, 128), False),
-        ((2, 1200, 2, 64), True),
-        pytest.param((1, 16384, 4, 128), False, marks=pytest.mark.slow),
+        ((4, 3072, 4, 128), False, jnp.bfloat16, False),
+        ((8, 768, 4, 128), False, jnp.bfloat16, False),
+        ((2, 1200, 2, 64), True, jnp.bfloat16, False),
+        ((2, 1200, 12, 64), False, jnp.bfloat16, False),
+        ((2, 1200, 12, 64), False, jnp.float32, False),
+        ((2, 197, 12, 64), False, jnp.bfloat16, True),
+        pytest.param((1, 16384, 4, 128), False, jnp.bfloat16, False,
+                     marks=pytest.mark.slow),
     ],
-    ids=["T3072", "T768", "T1200-causal", "T16384"],
+    ids=["T3072", "T768", "T1200-causal", "T1200", "T1200-f32",
+         "T197-unaligned", "T16384"],
 )
-def test_flash_attention_fwd_bwd_compiles(topo, tpu_branches, shape, causal):
+def test_flash_attention_fwd_bwd_compiles(
+    topo, tpu_branches, shape, causal, dtype, hbm_pads
+):
     """The fused kernel under the blocks ``flash_block_sizes`` computes
     from the shape, at ``chip_smoke.py``'s flash shape, the StreamFormer's
-    768 tokens, a padded causal one, and the most keys it admits (bf16)."""
-    q = _sds(shape, jnp.bfloat16, SingleDeviceSharding(topo.devices[0]))
+    768 tokens, 1,200 at their own length (K/V padded in VMEM; causal,
+    and in f32), 197 tokens no sublane tile divides (Q padded in HBM,
+    K/V copied by part of a tile), and the most keys it admits."""
+    q = _sds(shape, dtype, SingleDeviceSharding(topo.devices[0]))
     compiled = _attn_loss("flash", causal).lower(q, q, q).compile()
-    _assert_fits_with_kernel(compiled)
+    text = _assert_fits_with_kernel(compiled)
+    assert bool(_under_attn_core(text, "pad")) == hbm_pads
 
 
 def test_auto_attention_at_the_benchmark_shape_is_fused(
@@ -186,7 +208,8 @@ def test_auto_attention_at_the_benchmark_shape_is_fused(
 ):
     """(8, 1200, 12, 64) bf16 through ``auto`` on a one-chip machine:
     both kernels inside by name, the backward's under the ``attn_core``
-    scope too, and less temporary memory than the materialised path."""
+    scope too, no pad and no slice around them (1,200 tokens run as
+    1,200) and less temporary memory than the materialised path."""
     monkeypatch.setattr(jax, "device_count", lambda: 1)
     from blendjax.utils.metrics import (
         KERNEL_FLASH_BWD,
@@ -209,6 +232,7 @@ def test_auto_attention_at_the_benchmark_shape_is_fused(
     )
     assert SCOPE_ATTN_CORE in fwd and "transpose(" not in fwd, fwd
     assert SCOPE_ATTN_CORE in bwd and "transpose(jvp(" in bwd, bwd
+    assert not _under_attn_core(text, "pad", "slice")
     xla = _attn_loss("xla").lower(q, q, q).compile()
     _assert_fits_with_kernel(xla, kernel=False)
     assert (
@@ -371,7 +395,7 @@ def test_four_chip_attention_runs_per_shard(topo, tpu_branches, mesh4):
     text = _assert_fits_with_kernel(compiled)
     assert "all-gather" not in text
     assert "all-reduce(" in text or "all-reduce-start(" in text
-    assert "bf16[8,1280,768]" in text  # one shard, padded, heads in lanes
+    assert "bf16[8,1200,768]" in text  # one shard, its own length, heads in lanes
 
 
 def test_undeclared_attention_in_a_partitioned_program(
