@@ -22,7 +22,7 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from blendjax.ops.attention import local_attention
-from blendjax.ops.image import maybe_normalize_uint8
+from blendjax.ops.image import embed_patches
 from blendjax.parallel.ring import ring_attention
 from blendjax.parallel.ulysses import ulysses_attention
 from blendjax.precision import default_compute_dtype
@@ -128,8 +128,43 @@ class Block(nn.Module):
         return x + y
 
 
+class PatchEmbed(nn.Module):
+    """Frames ``(B, H, W, C)`` -> patch tokens ``(B, H/p, W/p, features)``:
+    the parameters of the ``p`` x ``p`` stride-``p`` ``nn.Conv`` this was
+    (``kernel`` ``(p, p, C, features)`` and ``bias``, float32, same
+    initializers, so a seeded init and a saved state are unchanged),
+    applied by :func:`blendjax.ops.image.embed_patches` as one matrix
+    product over the flattened patches. The module's name puts the whole
+    input side of the model under one scope in a trace."""
+
+    features: int
+    patch: int
+    dtype: Any = None  # None -> the precision policy's compute dtype
+
+    @nn.compact
+    def __call__(self, images):
+        kernel = self.param(
+            "kernel", nn.initializers.lecun_normal(),
+            (self.patch, self.patch, images.shape[-1], self.features),
+            jnp.float32,
+        )
+        bias = self.param(
+            "bias", nn.initializers.zeros_init(), (self.features,),
+            jnp.float32,
+        )
+        return embed_patches(
+            images, kernel, bias, default_compute_dtype(self.dtype)
+        )
+
+
 class StreamFormer(nn.Module):
     """Patchify -> transformer blocks -> head.
+
+    The patch embedding (``patch_embed``, :class:`PatchEmbed`) is a
+    matrix product over the flattened ``patch`` x ``patch`` patches of
+    the frames, not a strided convolution: with the stream's 4 u8 input
+    channels XLA ran the convolution at 19 GB/s on a v5e, the largest
+    single operation of the step. Same parameters as the convolution had.
 
     ``num_outputs=16`` regresses cube corners like
     :class:`~blendjax.models.cnn.CubeRegressor` so it can train on the
@@ -180,12 +215,9 @@ class StreamFormer(nn.Module):
     @nn.compact
     def __call__(self, images):
         dtype = default_compute_dtype(self.dtype)
-        x = maybe_normalize_uint8(images, dtype)
-        x = nn.Conv(
-            self.dim, (self.patch, self.patch),
-            strides=(self.patch, self.patch), dtype=dtype,
-            param_dtype=jnp.float32, name="patch_embed",
-        )(x)
+        x = PatchEmbed(
+            self.dim, self.patch, dtype=dtype, name="patch_embed"
+        )(images)
         b, hh, ww, c = x.shape
         x = x.reshape(b, hh * ww, c)
         pos = self.param(

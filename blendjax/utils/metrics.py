@@ -43,9 +43,12 @@ SCOPE_PALETTE_EXPAND = "palette_expand"  # inside decode: palette -> RGBA
 SCOPE_RESHARD = "reshard"          # mesh path: re-shard the decoded batch
 SCOPE_OPTIMIZER = "optimizer"      # apply_gradients
 SCOPE_ATTN_CORE = "attn_core"      # scores -> softmax -> weighted sum
+# StreamFormer's input side, frames -> patch tokens: the flax module's own
+# name (``jvp(StreamFormer)/patch_embed/dot_general``), no named_scope
+SCOPE_PATCH_EMBED = "patch_embed"
 STEP_SCOPES = (
     SCOPE_DECODE, SCOPE_PALETTE_EXPAND, SCOPE_RESHARD, SCOPE_OPTIMIZER,
-    SCOPE_ATTN_CORE,
+    SCOPE_ATTN_CORE, SCOPE_PATCH_EMBED,
 )
 # The Pallas decode kernels: each is the ``name=`` of its ``pallas_call``
 # and a scope around the call (inside ``decode``).

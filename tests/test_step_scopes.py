@@ -49,6 +49,7 @@ from blendjax.utils.metrics import (
     SCOPE_DECODE,
     SCOPE_OPTIMIZER,
     SCOPE_PALETTE_EXPAND,
+    SCOPE_PATCH_EMBED,
     STEP_SCOPES,
 )
 from blendjax.utils.metrics import metrics as reg
@@ -171,6 +172,25 @@ def test_fused_tile_step_names_its_parts(which):
         )
     else:
         assert not under(names, SCOPE_ATTN_CORE)
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_patch_embedding_is_a_product_under_its_name(direction):
+    """The input side of the StreamFormer is one name in a trace,
+    ``patch_embed`` (the flax module's): the fused step holds no
+    ``conv_general_dilated``, the embedding's product and the kernel's
+    gradient are ``dot_general``s under that name, forward and backward."""
+    model, loss_fn = _streamformer("xla")
+    step = make_fused_tile_step(loss_fn=loss_fn)
+    names = op_names(_lower_fused_tile(step, _state(model)).compile())
+    assert not [n for n in names if n.endswith("/conv_general_dilated")]
+    backward = direction == "backward"
+    embed = {
+        n for n in under(names, SCOPE_PATCH_EMBED)
+        if "jvp(StreamFormer)" in n and ("transpose(jvp(" in n) == backward
+    }
+    primitives = {n.rsplit("/", 1)[1] for n in embed}
+    assert "dot_general" in primitives, primitives
 
 
 @pytest.mark.parametrize(

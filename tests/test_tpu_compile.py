@@ -304,6 +304,53 @@ def test_fused_tile_step_compiles_with_the_kernel(
     _assert_no_gather_in_palette_expand(_assert_fits_with_kernel(compiled))
 
 
+def _assert_embedding_is_a_product(text):
+    """In the step compiled for the described v5e: nothing traced as a
+    ``conv_general_dilated``, the embedding's product and the kernel's
+    gradient are ``dot_general``s under ``patch_embed``, and no operation
+    of the scan's body copies one update's u8 frames into another layout
+    (the convolution asked for them batch-minor: ``%copy.894``, 0.47 ms
+    an update on the chip; the product reads the scan's slice through the
+    fusion that scales it)."""
+    import re
+
+    from blendjax.utils.metrics import SCOPE_PATCH_EMBED
+
+    op_names = re.findall(r'op_name="([^"]*)"', text)
+    assert not [n for n in op_names if n.endswith("/conv_general_dilated")]
+    products = [
+        n for n in op_names
+        if SCOPE_PATCH_EMBED in n.split("/") and n.endswith("/dot_general")
+    ]
+    assert [n for n in products if "transpose(jvp(" not in n]
+    assert [n for n in products if "transpose(jvp(" in n]
+    frame_copies = [
+        ln.strip()[:160] for ln in text.splitlines()
+        if re.search(rf" = u8\[1,\d+,{H},{W},{C}\]\S* copy\(", ln)
+    ]
+    assert not frame_copies, frame_copies
+
+
+def _vit_stem_former():
+    """``vit_b16``'s input side and one of its blocks: the embedding's
+    program does not depend on the depth, and one block compiles in
+    seconds."""
+    return StreamFormer(patch=16, dim=768, depth=1, num_heads=12,
+                        num_outputs=16)
+
+
+def test_fused_step_embeds_patches_by_a_product(topo, tpu_branches):
+    """One chip, ``REAL`` frames, the benchmark's patch 16 and width 768:
+    a small chunk, since the scan's body is the same at any."""
+    one = SingleDeviceSharding(topo.devices[0])
+    step = make_fused_tile_step(loss_fn=chip_smoke.former_loss)
+    compiled = _lower_fused_tile(
+        step, _abstract_state(_vit_stem_former(), one), 2, one,
+        _tile_plan((16, 32)),
+    ).compile()
+    _assert_embedding_is_a_product(_assert_fits_with_kernel(compiled))
+
+
 def test_fused_tile_step_names_the_decode_kernel(topo, tpu_branches):
     """In the step compiled for the described v5e the Pallas decode is
     found by name, not by shape: the custom call's ``op_name`` carries
@@ -428,6 +475,26 @@ def test_four_chip_fused_step_has_kernel_and_all_reduce(
     ).compile()
     text = _assert_fits_with_kernel(compiled)
     _assert_no_gather_in_palette_expand(text)
+    assert "all-reduce(" in text or "all-reduce-start(" in text
+
+
+@pytest.mark.slow
+def test_four_chip_fused_step_embeds_patches_by_a_product(
+    topo, tpu_branches, mesh4
+):
+    """The same on the 2x2 data mesh (each chip cuts its own two
+    frames): GSPMD partitions the product over the batch, and no shard
+    copies its frames either."""
+    rep = NamedSharding(mesh4, P())
+    state = _abstract_state(_vit_stem_former(), rep)
+    step = make_mesh_fused_step(
+        state, mesh4, loss_fn=chip_smoke.former_loss
+    )
+    compiled = _lower_fused_tile(
+        step, state, 2, rep, _tile_plan((16, 32))
+    ).compile()
+    text = _assert_fits_with_kernel(compiled)
+    _assert_embedding_is_a_product(text)
     assert "all-reduce(" in text or "all-reduce-start(" in text
 
 
