@@ -21,11 +21,48 @@ from typing import Any
 import flax.linen as nn
 import jax.numpy as jnp
 
-from blendjax.ops.attention import local_attention
+from blendjax.ops.attention import (
+    attention_reads_packed,
+    local_attention,
+    local_attention_packed,
+    packed_qkv_projection,
+)
 from blendjax.ops.image import embed_patches
 from blendjax.parallel.ring import ring_attention
 from blendjax.parallel.ulysses import ulysses_attention
 from blendjax.precision import default_compute_dtype
+
+
+class PackedQKV(nn.Module):
+    """The ``qkv`` projection where the attention kernels read its
+    product packed (:func:`blendjax.ops.attention.attention_reads_packed`):
+    the parameters of the ``nn.DenseGeneral((3, H, D))`` it stands in
+    for (``kernel`` ``(C, 3, H, D)`` and ``bias`` ``(3, H, D)``,
+    float32, drawn the same way: the kernel in its flattened
+    ``(C, 3·H·D)`` shape, so a seeded init and a saved state are
+    unchanged), applied as one flat product. Returns the product
+    ``(B, T, 3·H·D)`` and the flat bias, which the packed entry adds."""
+
+    num_heads: int
+    dtype: Any = None  # None -> the precision policy's compute dtype
+
+    @nn.compact
+    def __call__(self, x):
+        dtype = default_compute_dtype(self.dtype)
+        c, h = x.shape[-1], self.num_heads
+        shape = (c, 3, h, c // h)
+        kernel = self.param(
+            "kernel",
+            lambda key, shape, dtype: nn.initializers.lecun_normal()(
+                key, (c, 3 * c), dtype
+            ).reshape(shape),
+            shape, jnp.float32,
+        )
+        bias = self.param(
+            "bias", nn.initializers.zeros_init(), shape[1:], jnp.float32
+        )
+        return (packed_qkv_projection(x, kernel, dtype),
+                bias.reshape(-1).astype(dtype))
 
 
 class MultiHeadAttention(nn.Module):
@@ -45,18 +82,32 @@ class MultiHeadAttention(nn.Module):
         b, t, c = x.shape
         h = self.num_heads
         d = c // h
-        qkv = nn.DenseGeneral(
-            (3, h, d), axis=-1, dtype=dtype, param_dtype=jnp.float32,
-            name="qkv",
-        )(x)
-        q, k, v = (qkv[:, :, i] for i in range(3))  # (B, T, H, D)
-        assert self.sp_mode in ("ring", "ulysses"), (
-            f"unknown sp_mode {self.sp_mode!r}; use 'ring' or 'ulysses'"
-        )
         # use_ring gates sequence parallelism for back-compat; explicitly
         # requesting the non-default strategy also enables it.
         use_sp = self.use_ring or self.sp_mode == "ulysses"
-        if use_sp:
+        # where the fused kernels run at the tokens' own length they
+        # read the projection's product packed, with no layout copy
+        # between the two (six a layer otherwise): a flat product then
+        packed = not use_sp and attention_reads_packed(
+            b, t, h, d, dtype, self.attn_backend
+        )
+        if packed:
+            qkv, bias = PackedQKV(h, dtype=dtype, name="qkv")(x)
+        else:
+            qkv = nn.DenseGeneral(
+                (3, h, d), axis=-1, dtype=dtype, param_dtype=jnp.float32,
+                name="qkv",
+            )(x)
+            q, k, v = (qkv[:, :, i] for i in range(3))  # (B, T, H, D)
+        assert self.sp_mode in ("ring", "ulysses"), (
+            f"unknown sp_mode {self.sp_mode!r}; use 'ring' or 'ulysses'"
+        )
+        if packed:
+            o = local_attention_packed(
+                qkv, h, bias=bias, causal=self.causal,
+                backend=self.attn_backend,
+            )
+        elif use_sp:
             # Precision is the kernels' concern: the local path and
             # ulysses' per-device body go through local_attention,
             # whose xla backend does f32 score accumulation + f32

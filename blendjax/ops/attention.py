@@ -14,7 +14,18 @@ Net-new vs the reference (blendtorch has no sequence models, SURVEY.md
   saved log-sum-exp. No (B, H, T, T) tensor reaches HBM, forward or
   backward. It works on (B, T, H·D), the layout the projections
   around it read and write, 128 lanes — 128 // D whole heads — a
-  block, so nothing is transposed. Any T, at its own length: K/V are
+  block. For self-attention from one projection nothing is transposed
+  or copied either side of it (:func:`local_attention_packed`): the
+  ``qkv`` product, written flat as (B, T, 3·H·D), is the kernels' one
+  operand — q, k and v are column blocks of it, a third of its width
+  apart — and the backward writes one gradient of that shape, which
+  the projection's transposes read as it is, with the bias's gradient
+  (its column sums) beside it. Three separate tensors
+  (:func:`local_attention`: ulysses' per-device body, cross-attention)
+  run the same kernels, and XLA copies each into and out of the
+  row-major layout a custom call takes where its own layout differs
+  (six copies a layer in ``vit_b16`` until PR 35: 1.38 ms an update,
+  and 0.79 more in the slices that fed them). Any T, at its own length: K/V are
   padded to the score tile's 128 lanes in VMEM, inside the kernel
   (padded keys masked), and Q runs unpadded wherever a block of whole
   sublane tiles divides T (1,200 tokens run as 1,200; no pad and no
@@ -347,12 +358,29 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *kv_pads, d, scale,
     lse_ref[0] = _merge(lses, heads)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *kv_pads, d, scale,
-                causal):
+def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, *rest, d, scale,
+                causal, packed):
+    """One query block's part of dQ, dK and dV. ``rest``: the outputs,
+    the f32 dK/dV accumulators and the K/V padding scratch. With three
+    tensors the outputs are ``dq`` (a block of rows), ``dk`` and ``dv``
+    (whole). ``packed``: one array shaped like the ``qkv`` product, a
+    batch row of it resident over the head blocks and query blocks —
+    each gradient is stored at its own column block of it, so the
+    array leaves the kernel as the projection's transposes read it —
+    and the column sums of what is stored there (the projection's bias
+    gradient, for the price of a sublane reduction)."""
     from jax.experimental import pallas as pl
 
-    i = pl.program_id(2)
+    j, i = pl.program_id(1), pl.program_id(2)
+    if packed:
+        dqkv_ref, sums_ref, dk_acc, dv_acc, *kv_pads = rest
+        head_blocks = dqkv_ref.shape[2] // (3 * _LANES)
+
+        @pl.when((j == 0) & (i == 0))
+        def _():
+            sums_ref[...] = jnp.zeros_like(sums_ref)
+    else:
+        dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *kv_pads = rest
 
     @pl.when(i == 0)
     def _():
@@ -381,33 +409,77 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
         dk_acc[...] += lax.dot_general(
             ds, qh, _TN, preferred_element_type=jnp.float32
         )
-    dq_ref[0] = _merge(dqs, heads).astype(dq_ref.dtype)
+    dq, t_kv = _merge(dqs, heads), k_ref.shape[1]
+    last = i == pl.num_programs(2) - 1
+    if not packed:
+        dq_ref[0] = dq.astype(dq_ref.dtype)
 
-    @pl.when(i == pl.num_programs(2) - 1)
+        @pl.when(last)
+        def _():
+            dk_ref[0] = dk_acc[:t_kv].astype(dk_ref.dtype)
+            dv_ref[0] = dv_acc[:t_kv].astype(dv_ref.dtype)
+
+        return
+
+    def store(part, grad, rows=slice(None)):
+        """``grad`` into q's, k's or v's (``part`` 0, 1, 2) column
+        block of this head block, and its column sums beside it."""
+        cols = pl.ds(
+            pl.multiple_of((part * head_blocks + j) * _LANES, _LANES), _LANES
+        )
+        dqkv_ref[0, rows, cols] = grad.astype(dqkv_ref.dtype)
+        sums_ref[0, :, cols] += jnp.sum(grad, axis=0, keepdims=True)
+
+    block_q = q.shape[0]
+    store(0, dq, slice(None) if block_q == dqkv_ref.shape[1] else pl.ds(
+        pl.multiple_of(i * block_q, block_q), block_q))
+
+    @pl.when(last)
     def _():
-        t_kv = dk_ref.shape[1]
-        dk_ref[0] = dk_acc[:t_kv].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:t_kv].astype(dv_ref.dtype)
+        store(1, dk_acc[:t_kv])
+        store(2, dv_acc[:t_kv])
 
 
-def _specs(q, k, block_q):
-    """Block specs, grid and the K/V scratch of one launch: Q-shaped
-    operands by ``block_q`` rows, K/V-shaped ones whole, and, where K/V
-    do not fill the score tile's lanes, the [padded_kv, 128] VMEM
-    buffers the kernel pads them into."""
+class _Launch(NamedTuple):
+    """Block specs, grid and scratch of one launch over (B, T, ·)
+    operands, 128 lanes — one head block — a grid step."""
+
+    grid: tuple
+    qkv: list         # the specs of q (by rows), k and v (whole)
+    rows: object      # ``block_q`` query rows of a (B, T, H·D) operand
+    whole: object     # all ``t_kv`` rows of one
+    padded_kv: int
+    kv_pads: list     # where K/V do not fill the score tile's lanes,
+    #                   the [padded_kv, 128] VMEM buffers they are padded into
+
+
+def _launch(batch, t_q, t_kv, width, block_q, dtype, packed):
+    """``packed``: q, k and v are one (B, T, 3·H·D) array, columns
+    ``[q | k | v][H·D]``; each is read at its own column blocks of it,
+    a third of its width (``width`` = H·D) apart."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    rows = pl.BlockSpec((1, block_q, _LANES), lambda b, j, i: (b, i, j))
-    whole = pl.BlockSpec(
-        (1, k.shape[1], _LANES), lambda b, j, i: (b, 0, j)
-    )
-    grid = (q.shape[0], q.shape[2] // _LANES, q.shape[1] // block_q)
-    padded_kv = _round_up(k.shape[1], _LANES)
-    kv_pads = [] if padded_kv == k.shape[1] else [
-        pltpu.VMEM((padded_kv, _LANES), k.dtype)
+    head_blocks = width // _LANES
+
+    def rows(part=0):
+        at = part * head_blocks
+        return pl.BlockSpec((1, block_q, _LANES),
+                            lambda b, j, i: (b, i, at + j))
+
+    def whole(part=0):
+        at = part * head_blocks
+        return pl.BlockSpec((1, t_kv, _LANES), lambda b, j, i: (b, 0, at + j))
+
+    padded_kv = _round_up(t_kv, _LANES)
+    kv_pads = [] if padded_kv == t_kv else [
+        pltpu.VMEM((padded_kv, _LANES), dtype)
     ] * 2
-    return rows, whole, grid, padded_kv, kv_pads
+    qkv = [rows(0), whole(1), whole(2)] if packed else [
+        rows(), whole(), whole()
+    ]
+    return _Launch((batch, head_blocks, t_q // block_q), qkv, rows(),
+                   whole(), padded_kv, kv_pads)
 
 
 def _compiler_params(*semantics):
@@ -418,26 +490,72 @@ def _compiler_params(*semantics):
     )
 
 
-def _flash_fwd(q, k, v, d, causal, scale, block_q):
+def _flash_fwd(q, k, v, d, causal, scale, block_q, packed=False):
+    """``packed``: q, k and v are one (B, T, 3·H·D) array, read by
+    column block."""
     from jax.experimental import pallas as pl
 
-    rows, whole, grid, _, kv_pads = _specs(q, k, block_q)
+    width = q.shape[2] // (3 if packed else 1)
+    launch = _launch(q.shape[0], q.shape[1], k.shape[1], width, block_q,
+                     q.dtype, packed)
+    out = (*q.shape[:2], width)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, d=d, scale=scale, causal=causal),
-        grid=grid,
-        in_specs=[rows, whole, whole],
-        out_specs=[rows, rows],
+        grid=launch.grid,
+        in_specs=launch.qkv,
+        out_specs=[launch.rows, launch.rows],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct(q.shape, jnp.float32),
+            jax.ShapeDtypeStruct(out, q.dtype),
+            jax.ShapeDtypeStruct(out, jnp.float32),
         ],
-        scratch_shapes=kv_pads,
+        scratch_shapes=launch.kv_pads,
         # the K/V scratch is filled at a (batch, head block)'s first
         # query block, so those run in order on one core
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
         interpret=jax.default_backend() != "tpu",
         name=KERNEL_FLASH_FWD,
     )(q, k, v)
+
+
+def _flash_bwd(q, k, v, o, lse, do, d, causal, scale, block_q, packed=False):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    launch = _launch(*o.shape[:2], k.shape[1], o.shape[2], block_q, q.dtype,
+                     packed)
+    if packed:
+        # a batch row of the packed gradient and of its column sums
+        # stays in VMEM over the head blocks, which therefore run in order
+        def row(shape):
+            return pl.BlockSpec((1, *shape[1:]), lambda b, j, i: (b, 0, 0))
+
+        sums = (q.shape[0], 8, q.shape[2])
+        out_specs = [row(q.shape), row(sums)]
+        out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype),
+                     jax.ShapeDtypeStruct(sums, jnp.float32)]
+    else:
+        out_specs = [launch.rows, launch.whole, launch.whole]
+        out_shape = [jax.ShapeDtypeStruct(x.shape, x.dtype)
+                     for x in (q, k, v)]
+    # a custom_vjp's backward is traced outside the forward's scope
+    with jax.named_scope(SCOPE_ATTN_CORE):
+        return pl.pallas_call(
+            functools.partial(_bwd_kernel, d=d, scale=scale, causal=causal,
+                              packed=packed),
+            grid=launch.grid,
+            in_specs=launch.qkv + [launch.rows] * 3,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=[
+                pltpu.VMEM((launch.padded_kv, _LANES), jnp.float32)
+            ] * 2 + launch.kv_pads,
+            compiler_params=_compiler_params(
+                "parallel", "arbitrary" if packed else "parallel",
+                "arbitrary",
+            ),
+            interpret=jax.default_backend() != "tpu",
+            name=KERNEL_FLASH_BWD,
+        )(q, k, v, o, lse, do)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -458,38 +576,46 @@ def _flash_core_fwd(q, k, v, d, causal, scale, block_q):
 
 
 def _flash_core_bwd(d, causal, scale, block_q, res, do):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    q, k, v, o, lse = res
-    rows, whole, grid, padded_kv, kv_pads = _specs(q, k, block_q)
-    # a custom_vjp's backward is traced outside the forward's scope
-    with jax.named_scope(SCOPE_ATTN_CORE):
-        return tuple(pl.pallas_call(
-            functools.partial(_bwd_kernel, d=d, scale=scale, causal=causal),
-            grid=grid,
-            in_specs=[rows, whole, whole, rows, rows, rows],
-            out_specs=[rows, whole, whole],
-            out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
-                       for x in (q, k, v)],
-            scratch_shapes=[
-                pltpu.VMEM((padded_kv, _LANES), jnp.float32)
-            ] * 2 + kv_pads,
-            compiler_params=_compiler_params(
-                "parallel", "parallel", "arbitrary"
-            ),
-            interpret=jax.default_backend() != "tpu",
-            name=KERNEL_FLASH_BWD,
-        )(q, k, v, o, lse, do))
+    return tuple(_flash_bwd(*res, do, d, causal, scale, block_q))
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
+def _flash_core_packed(qkv, bias, d, causal, scale, block_q):
+    """:func:`_flash_core` for self-attention from one projection:
+    ``qkv`` (B, T, 3·H·D), the projection's product with columns
+    ``[q | k | v][H·D]``, and its ``bias`` (3·H·D,), added here. The
+    same kernels; their block specs point into the one array, the
+    backward writes the one gradient of its shape, and the bias's
+    gradient is that gradient's column sums, which the kernel has in
+    VMEM as it stores them."""
+    return _flash_core_packed_fwd(qkv, bias, d, causal, scale, block_q)[0]
+
+
+def _flash_core_packed_fwd(qkv, bias, d, causal, scale, block_q):
+    qkv = qkv + bias  # the projection's: fused into its product, no scope
+    with jax.named_scope(SCOPE_ATTN_CORE):
+        o, lse = _flash_fwd(qkv, qkv, qkv, d, causal, scale, block_q,
+                            packed=True)
+    return o, (qkv, o, lse)
+
+
+def _flash_core_packed_bwd(d, causal, scale, block_q, res, do):
+    qkv, o, lse = res
+    dqkv, sums = _flash_bwd(qkv, qkv, qkv, o, lse, do, d, causal, scale,
+                            block_q, packed=True)
+    return dqkv, jnp.sum(sums[:, 0], axis=0).astype(qkv.dtype)
+
+
+_flash_core_packed.defvjp(_flash_core_packed_fwd, _flash_core_packed_bwd)
+
+
 def _flash_attention(q, k, v, causal, scale):
     """(B, T, H, D) in and out around :func:`_flash_core`: fold heads
     into lanes — (B, T, H·D), the layout the projections around the core
-    read and write, so nothing is transposed. K and V always go in at
+    read and write. K and V always go in at
     their own length. Where a block divides ``t_q`` (1,200 tokens) so
     does Q, and the reshape is all: the operands, the residuals and the
     gradients keep the input's length. Otherwise Q is padded to its
@@ -509,9 +635,45 @@ def _flash_attention(q, k, v, causal, scale):
     return o[:, :t_q].reshape(b, t_q, h, d)
 
 
+def _flash_attention_packed(qkv, bias, num_heads, causal, scale):
+    """(B, T, 3·H·D) in, (B, T, H, D) out around
+    :func:`_flash_core_packed`, where a query block divides T."""
+    b, t, width = qkv.shape
+    d = width // (3 * num_heads)
+    blocks = flash_block_sizes(t, t, qkv.dtype)
+    o = _flash_core_packed(qkv, bias, d, causal, scale, blocks.block_q)
+    return o.reshape(b, t, num_heads, d)
+
+
+def _per_batch_shard(fn, batch, n_sharded, n_whole=0):
+    """``fn`` as the program being traced may call a kernel
+    (:func:`_placement`): as it is, or through ``shard_map`` over a
+    declared mesh's batch axes, its first ``n_sharded`` arguments cut
+    along their leading (batch) dimension and the ``n_whole`` after
+    them given to every shard whole."""
+    placed = _placement()
+    if not isinstance(placed, tuple):
+        return fn
+    from jax.sharding import PartitionSpec as P
+
+    from blendjax.parallel.collectives import _shard_map
+
+    mesh, axes, n = placed
+    # an explicit "flash" the axes do not divide runs replicated
+    spec = P(axes) if n > 1 and batch % n == 0 else P()
+    metrics.count("attn.path.shard_map")
+    # check=False: pallas_call's out_shape carries no varying-
+    # mesh-axes annotation, which the VMA checker requires
+    return _shard_map(
+        fn, mesh, in_specs=(spec,) * n_sharded + (P(),) * n_whole,
+        out_specs=spec, check=False,
+    )
+
+
 def local_attention(q, k, v, causal: bool = False, scale=None,
                     backend: str = "auto"):
-    """Exact multi-head attention over (B, T, H, D) tensors.
+    """Exact multi-head attention over (B, T, H, D) tensors. (One
+    projection's packed ``qkv``: :func:`local_attention_packed`.)
 
     ``backend``: ``"xla"`` | ``"flash"`` | ``"auto"`` (the policy
     above). ``"flash"`` raises on an ineligible input instead of
@@ -547,18 +709,103 @@ def local_attention(q, k, v, causal: bool = False, scale=None,
         metrics.count("attn.path.flash")
         scale = float(scale if scale is not None else q.shape[-1] ** -0.5)
         fn = functools.partial(_flash_attention, causal=causal, scale=scale)
-        placed = _placement()
-        if isinstance(placed, tuple):
-            from jax.sharding import PartitionSpec as P
+        return _per_batch_shard(fn, q.shape[0], 3)(q, k, v)
 
-            from blendjax.parallel.collectives import _shard_map
 
-            mesh, axes, n = placed
-            # an explicit "flash" the axes do not divide runs replicated
-            spec = P(axes) if n > 1 and q.shape[0] % n == 0 else P()
-            # check=False: pallas_call's out_shape carries no varying-
-            # mesh-axes annotation, which the VMA checker requires
-            fn = _shard_map(fn, mesh, in_specs=(spec,) * 3, out_specs=spec,
-                            check=False)
-            metrics.count("attn.path.shard_map")
-        return fn(q, k, v)
+def attention_reads_packed(b, t, h, d, dtype, backend="auto") -> bool:
+    """Whether self-attention from one projection at this shape takes
+    the kernels on the packed ``qkv`` product
+    (:func:`local_attention_packed`): where ``backend`` resolves to the
+    fused kernel, a query block divides ``t`` (the ``flash_exact``
+    geometry: 1,200 and 768 tokens, not 197) and a batch row of the
+    packed gradient, which the backward keeps in VMEM twice (the block
+    and its write-back), stays within a quarter of FLASH_VMEM_BYTES
+    (5.5 MB at 1,200 tokens of 768 in bf16; about 4,700 tokens at that
+    width). A model asks before it computes the projection, which it
+    writes as a flat product only where this holds."""
+    q = jax.ShapeDtypeStruct((b, t, h, d), dtype)
+    if backend == "flash":
+        takes_kernel = flash_supported(q)
+    else:
+        takes_kernel = backend == "auto" and auto_picks_flash(q)
+    row_bytes = t * 3 * h * d * jnp.dtype(dtype).itemsize
+    return bool(
+        takes_kernel
+        and flash_block_sizes(t, t, dtype).padded_q == t
+        and 2 * row_bytes <= FLASH_VMEM_BYTES // 4
+    )
+
+
+@jax.custom_vjp
+def _flat_product(x, kernel):
+    return x @ kernel
+
+
+def _flat_product_fwd(x, kernel):
+    return x @ kernel, (x, kernel)
+
+
+def _flat_product_bwd(res, dy):
+    """The kernel's gradient as ``dyᵀ x``, (3·H·D, C) row-major, which
+    is the layout the (C, 3, H, D) parameter has in the fused step's
+    carry (C minor: 64 as the minor dimension would pad), computed from
+    row-major ``x`` and ``dy`` as they are, and held there by the
+    barrier. Left to itself XLA folds the parameter's reshape into the
+    product, and that five-dimensional product with 64 output lanes
+    wants ``dy`` token-minor: one ``copy`` of the packed gradient a
+    layer (compiled for a v5e, PR 35); written the other way round it
+    transposes the f32 result twice in the optimizer instead."""
+    x, kernel = res
+    dx = lax.dot_general(dy, kernel, (((2,), (1,)), ((), ())))
+    dkernel = lax.dot_general(dy, x, (((0, 1), (0, 1)), ((), ())))
+    return dx, lax.optimization_barrier(dkernel).T
+
+
+_flat_product.defvjp(_flat_product_fwd, _flat_product_bwd)
+
+
+def packed_qkv_projection(x, kernel, dtype):
+    """``x`` (B, T, C) times a ``DenseGeneral((3, H, D))`` kernel
+    (C, 3, H, D) as one flat product: (B, T, 3·H·D), columns
+    ``[q | k | v][H][D]``, row-major as the product writes it — the
+    operand of :func:`local_attention_packed`, with nothing between the
+    two. (``DenseGeneral``'s own five-dimensional result XLA lays out
+    token-minor, and each slice of it is copied into the row-major
+    layout a custom call takes.) No bias: the packed entry adds it."""
+    c = kernel.shape[0]
+    return _flat_product(
+        x.astype(dtype), kernel.reshape(c, -1).astype(dtype)
+    )
+
+
+def local_attention_packed(qkv, num_heads, bias=None, causal: bool = False,
+                           scale=None, backend: str = "auto"):
+    """:func:`local_attention` for self-attention from one projection:
+    ``qkv`` (B, T, 3·H·D), columns ``[q | k | v][H][D]``
+    (:func:`packed_qkv_projection`), and optionally the projection's
+    ``bias`` (3·H·D,), still to be added. Returns (B, T, H, D).
+
+    Where :func:`attention_reads_packed` holds the fused kernels read
+    q, k and v out of the one array by column block and the backward
+    writes one gradient of its shape (and the bias's, its column sums),
+    so no operation of activation size stands between the projection,
+    or its transposes, and the kernels; counted under
+    ``attn.path.flash_packed`` beside ``flash`` and ``flash_exact``.
+    Elsewhere the array is sliced and :func:`local_attention` takes the
+    three tensors: the same numbers either way."""
+    b, t, width = qkv.shape
+    d = width // (3 * num_heads)
+    if bias is None:
+        bias = jnp.zeros((width,), qkv.dtype)
+    if not attention_reads_packed(b, t, num_heads, d, qkv.dtype, backend):
+        q, k, v = (x.reshape(b, t, num_heads, d)
+                   for x in jnp.split(qkv + bias, 3, axis=2))
+        return local_attention(q, k, v, causal, scale, backend)
+    metrics.count("attn.path.flash")
+    metrics.count("attn.path.flash_exact")
+    metrics.count("attn.path.flash_packed")
+    fn = functools.partial(
+        _flash_attention_packed, num_heads=num_heads, causal=causal,
+        scale=float(scale if scale is not None else d ** -0.5),
+    )
+    return _per_batch_shard(fn, b, 1, 1)(qkv, bias)

@@ -23,6 +23,7 @@ from blendjax.ops.attention import (  # noqa: E402
     flash_block_sizes,
     flash_supported,
     local_attention,
+    local_attention_packed,
     scores_residual_bytes,
 )
 from blendjax.parallel.ring import reference_attention  # noqa: E402
@@ -362,6 +363,292 @@ def test_auto_takes_the_kernel_only_where_it_knows_the_program(monkeypatch):
     assert A._PROGRAM_MESH.get() is None
     monkeypatch.setattr(jax, "device_count", lambda: 1)
     assert auto_picks_flash(shape)
+
+
+def _counted(before, *names):
+    """How far each ``attn.path.<name>`` counter moved since ``before``."""
+    after = _attn_counts()
+    return {
+        name: after.get(f"attn.path.{name}", 0)
+        - before.get(f"attn.path.{name}", 0)
+        for name in names
+    }
+
+
+def _packed(t, h, d, dtype, b=2):
+    """One projection's result as the packed entry takes it:
+    ``(B, T, 3·H·D)``, columns ``[q | k | v][head][d]``."""
+    return jax.random.normal(jax.random.key(4), (b, t, 3 * h * d), dtype)
+
+
+def _split(qkv, h):
+    b, t, w = qkv.shape
+    return tuple(x.reshape(b, t, h, w // (3 * h))
+                 for x in jnp.split(qkv, 3, axis=2))
+
+
+def _packed_value_and_grad(fn, qkv, w):
+    """``fn(qkv)`` in float32 and the gradient of its ``w``-weighted
+    sum with respect to the packed array."""
+    def loss(a):
+        out = fn(a).astype(jnp.float32)
+        return jnp.sum(out * w), out
+
+    (_, out), grad = jax.value_and_grad(loss, has_aux=True)(qkv)
+    return out, grad
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("blocks", [1, 2], ids=["one-block", "two-blocks"])
+@pytest.mark.parametrize("h, d", [(2, 64), (2, 128)], ids=["D64", "D128"])
+def test_packed_entry_matches_reference(monkeypatch, h, d, blocks, causal,
+                                        dtype):
+    """``local_attention_packed`` reads q, k, v out of the one packed
+    array and writes one packed gradient: value and the gradient with
+    respect to the packed array against ``reference_attention`` on the
+    three slices, for two heads a lane block and one, one query block
+    and several (the gradient's dq rows land a block at a time, dk and
+    dv at the last), causal or not, f32 and bf16."""
+    t = 256
+    if blocks > 1:  # a 128-row block: the bound admits no larger tile
+        monkeypatch.setattr(A, "FLASH_TILE_ELEMS", 128 * t)
+    assert flash_block_sizes(t, t, dtype).block_q == t // blocks
+    qkv = _packed(t, h, d, dtype)
+    w = jax.random.normal(jax.random.key(9), (2, t, h, d), jnp.float32)
+    before = _attn_counts()
+    out, grad = _packed_value_and_grad(
+        lambda a: local_attention_packed(a, h, causal=causal,
+                                         backend="flash"), qkv, w)
+    assert _counted(
+        before, "flash", "flash_exact", "flash_packed", "flash_padded"
+    ) == {"flash": 1, "flash_exact": 1, "flash_packed": 1, "flash_padded": 0}
+    want, want_grad = _packed_value_and_grad(
+        lambda a: reference_attention(*_split(a, h), causal=causal), qkv, w)
+    f32 = dtype == jnp.float32
+    assert out.shape == (2, t, h, d)
+    np.testing.assert_allclose(out, want, atol=5e-6 if f32 else 3e-2)
+    assert grad.shape == qkv.shape and grad.dtype == qkv.dtype
+    np.testing.assert_allclose(
+        np.asarray(grad, np.float32), np.asarray(want_grad, np.float32),
+        atol=5e-6 if f32 else 3e-2,
+    )
+
+
+@pytest.mark.parametrize("t, backend, path", [
+    (197, "flash", "flash_padded"), (130, "flash", "flash_padded"),
+    (128, "xla", "xla"),
+])
+def test_packed_entry_elsewhere_takes_the_three_tensor_path(t, backend,
+                                                            path):
+    """A length no query block divides (Q padded in HBM) and the XLA
+    backend have nothing to gain from the packed array: the entry
+    slices it and calls ``local_attention`` — the same numbers, counted
+    as that path and not as ``flash_packed``."""
+    h, d = 2, 64
+    qkv = _packed(t, h, d, jnp.float32, b=1)
+    w = jax.random.normal(jax.random.key(9), (1, t, h, d), jnp.float32)
+    before = _attn_counts()
+    out, grad = _packed_value_and_grad(
+        lambda a: local_attention_packed(a, h, backend=backend), qkv, w)
+    assert _counted(before, path, "flash_packed") == {
+        path: 1, "flash_packed": 0}
+    want, want_grad = _packed_value_and_grad(
+        lambda a: reference_attention(*_split(a, h)), qkv, w)
+    np.testing.assert_allclose(out, want, atol=5e-6)
+    np.testing.assert_allclose(grad, want_grad, atol=5e-6)
+
+
+def test_packed_entry_runs_per_batch_shard_under_a_declared_mesh():
+    """The packed call goes through ``shard_map`` over the batch axis
+    as the three-tensor call does: the packed gradient stays sharded by
+    batch, and the replicated bias's gradient, which each shard's
+    kernel sums over its own rows, is summed over the shards."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    h, d, t = 2, 64, 128
+    mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+    qkv = jax.device_put(_packed(t, h, d, jnp.float32, b=4),
+                         NamedSharding(mesh, P("data")))
+    bias = jax.device_put(
+        jax.random.normal(jax.random.key(6), (3 * h * d,), jnp.float32),
+        NamedSharding(mesh, P()),
+    )
+    w = jax.random.normal(jax.random.key(9), (4, t, h, d), jnp.float32)
+
+    def run(backend):
+        def loss(qkv, bias):
+            with batch_sharded_over(mesh, "data"):
+                out = local_attention_packed(qkv, h, bias=bias,
+                                             backend=backend)
+            return jnp.sum(out * w)
+
+        return jax.jit(jax.value_and_grad(loss, (0, 1)))
+
+    before = _attn_counts()
+    loss, (dqkv, dbias) = run("flash")(qkv, bias)
+    assert _counted(before, "flash_packed", "shard_map") == {
+        "flash_packed": 1, "shard_map": 1}
+    want, (want_dqkv, want_dbias) = run("xla")(qkv, bias)
+    np.testing.assert_allclose(loss, want, rtol=1e-5)
+    np.testing.assert_allclose(dqkv, want_dqkv, atol=5e-6)
+    np.testing.assert_allclose(dbias, want_dbias, atol=5e-5)
+    assert dqkv.sharding.spec == P("data")
+    assert "all-gather" not in run("flash").lower(qkv, bias).compile().as_text()
+
+
+def test_packed_entry_refuses_what_the_kernel_cannot_take():
+    """An explicit ``flash`` on heads that do not fill the lanes fails
+    as loudly through the packed entry as through the three tensors."""
+    with pytest.raises(ValueError, match="flash attention backend"):
+        local_attention_packed(_packed(128, 3, 64, jnp.float32), 3,
+                               backend="flash")
+
+
+@pytest.mark.parametrize("t, causal, packed", [
+    (128, False, 1), (128, True, 1), (130, False, 0),
+], ids=["T128", "T128-causal", "T130-padded"])
+def test_multi_head_attention_flash_equals_xla(t, causal, packed):
+    """The module on the same parameters under ``attn_backend="flash"``
+    (the flat ``qkv`` product into the packed kernels where a query
+    block divides the tokens; ``DenseGeneral`` and three tensors where
+    none does) and ``"xla"``: the output and every parameter's
+    gradient."""
+    from blendjax.models.transformer import MultiHeadAttention
+
+    x = jax.random.normal(jax.random.key(1), (2, t, 128), jnp.float32)
+    w = jax.random.normal(jax.random.key(2), x.shape, jnp.float32)
+    modules = {
+        backend: MultiHeadAttention(2, dtype=jnp.float32, causal=causal,
+                                    attn_backend=backend)
+        for backend in ("xla", "flash")
+    }
+    params = modules["xla"].init(jax.random.key(0), x)["params"]
+    params["qkv"]["bias"] = 0.1 * jax.random.normal(
+        jax.random.key(3), params["qkv"]["bias"].shape, jnp.float32
+    )
+
+    def value_and_grads(backend):
+        def loss(p):
+            y = modules[backend].apply({"params": p}, x)
+            return jnp.sum(y * w), y
+
+        (_, y), grads = jax.value_and_grad(loss, has_aux=True)(params)
+        return y, grads
+
+    before = _attn_counts()
+    got, got_grads = value_and_grads("flash")
+    assert _counted(before, "flash_packed") == {"flash_packed": packed}
+    want, want_grads = value_and_grads("xla")
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    assert (jax.tree_util.tree_structure(got_grads)
+            == jax.tree_util.tree_structure(want_grads))
+    for (path, a), b in zip(
+        jax.tree_util.tree_leaves_with_path(got_grads),
+        jax.tree_util.tree_leaves(want_grads),
+    ):
+        assert a.shape == b.shape and a.dtype == b.dtype, path
+        np.testing.assert_allclose(
+            a, b, atol=2e-5 * float(jnp.abs(b).max()), err_msg=str(path)
+        )
+
+
+@pytest.mark.parametrize("side, tokens, packed", [
+    ((480, 640), 1200, 12), ((224, 224), 196, 0),
+], ids=["vit_b16-1200", "vit_b16-224px"])
+def test_vit_b16_counts_one_packed_call_a_block(one_tpu, side, tokens,
+                                                packed):
+    """Traced (``eval_shape``: nothing runs) as a one-chip TPU process
+    would: ``vit_b16`` at the stream's 1,200 tokens takes the packed
+    kernels in each of its 12 blocks, at 224 px none (the XLA side of
+    ``auto``). No field chooses: the shape does."""
+    from blendjax.models import StreamFormer
+
+    model = StreamFormer(patch=16, dim=768, depth=12, num_heads=12,
+                         num_outputs=16)
+    images = jax.ShapeDtypeStruct((8, *side, 4), jnp.uint8)
+    params = jax.eval_shape(model.init, jax.random.key(0), images)
+    before = _attn_counts()
+    jax.eval_shape(model.apply, params, images)
+    assert _counted(before, "flash_packed", "flash", "xla") == {
+        "flash_packed": packed, "flash": packed, "xla": 12 - packed}
+    assert tokens == (side[0] // 16) * (side[1] // 16)
+
+
+@pytest.mark.parametrize("c, h", [(128, 2), (768, 12)])
+def test_qkv_parameters_are_dense_generals(c, h):
+    """``qkv/kernel`` ``(c, 3, h, d)`` and ``qkv/bias`` ``(3, h, d)``,
+    float32, drawn as ``nn.DenseGeneral`` draws them (the flattened
+    ``(c, 3·h·d)`` shape, reshaped): the packed path's module and the
+    other one give the same tree from the same key, to the last bit, so
+    a seeded run starts from the same numbers whichever path its shape
+    takes (``benchmark/reference.py``'s checksum depends on it)."""
+    import flax.linen as nn
+
+    from blendjax.models.transformer import PackedQKV
+
+    x = jnp.zeros((1, 16, c), jnp.float32)
+    old = nn.DenseGeneral(
+        (3, h, c // h), axis=-1, dtype=jnp.bfloat16, param_dtype=jnp.float32
+    ).init(jax.random.key(7), x)
+    new = PackedQKV(h, dtype=jnp.bfloat16).init(jax.random.key(7), x)
+    assert jax.tree_util.tree_structure(old) == jax.tree_util.tree_structure(new)
+    assert new["params"]["kernel"].shape == (c, 3, h, c // h)
+    assert new["params"]["bias"].shape == (3, h, c // h)
+    for a, b in zip(jax.tree_util.tree_leaves(old),
+                    jax.tree_util.tree_leaves(new)):
+        assert a.dtype == b.dtype == jnp.float32
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_a_state_saved_with_dense_general_restores_into_the_packed_path(
+    tmp_path,
+):
+    """``tests/fixtures/streamformer_dense_qkv_snapshot`` was written by
+    the tree before this change (PR 34's, ``nn.DenseGeneral`` and three
+    tensors: the state after one sgd update, the frames it saw and what
+    it answered). It restores leaf for leaf into today's model, which
+    answers the same through the packed kernels."""
+    import os
+    import shutil
+
+    import optax
+
+    from blendjax.checkpoint import SnapshotManager
+    from blendjax.models import StreamFormer
+    from blendjax.train import make_train_state
+
+    fixture = os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), "fixtures",
+        "streamformer_dense_qkv_snapshot",
+    )
+    directory = tmp_path / "snapshot"
+    shutil.copytree(fixture, directory)  # the manager sweeps what it opens
+    model = StreamFormer(patch=8, dim=128, depth=1, num_heads=2,
+                         num_outputs=16, dtype=jnp.float32,
+                         attn_backend="flash")
+    template = make_train_state(
+        model, np.zeros((2, 32, 32, 4), np.uint8),
+        optimizer=optax.sgd(1e-2), rng=jax.random.key(0),
+    )
+    mgr = SnapshotManager(str(directory), keep=1)
+    try:
+        restored = mgr.restore(template)
+    finally:
+        mgr.close()
+    assert restored is not None and restored.step == 1
+    kernel = restored.state.params["block0"]["MultiHeadAttention_0"]["qkv"][
+        "kernel"]
+    assert kernel.shape == (128, 3, 2, 64) and kernel.dtype == jnp.float32
+    before = _attn_counts()
+    out = model.apply(
+        {"params": restored.state.params}, restored.session["images"]
+    )
+    assert _counted(before, "flash_packed") == {"flash_packed": 1}
+    np.testing.assert_allclose(
+        np.asarray(out), restored.session["outputs"], rtol=1e-4, atol=1e-5
+    )
 
 
 @pytest.mark.tpu

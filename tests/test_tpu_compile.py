@@ -203,6 +203,46 @@ def test_flash_attention_fwd_bwd_compiles(
     assert bool(_under_attn_core(text, "pad")) == hbm_pads
 
 
+@pytest.mark.parametrize(
+    "shape, causal, dtype",
+    [
+        ((8, 1200, 12, 64), False, jnp.bfloat16),
+        ((2, 1200, 12, 64), True, jnp.float32),
+        ((8, 768, 4, 128), False, jnp.bfloat16),
+        ((4, 3072, 4, 128), False, jnp.bfloat16),
+    ],
+    ids=["T1200", "T1200-causal-f32", "T768", "T3072-six-blocks"],
+)
+def test_packed_attention_fwd_bwd_compiles(
+    topo, tpu_branches, shape, causal, dtype
+):
+    """The kernels on the packed ``(B, T, 3·H·D)`` product: q, k and v
+    read by column block, the backward's stores at a lane offset the
+    head block decides, a batch row of the packed gradient in VMEM
+    (22 MB with its write-back at 1,200 tokens of 768 in f32) — at the
+    benchmark's shape, ``chip_smoke.py``'s 768 tokens, and six query
+    blocks whose dq rows land one block at a time."""
+    from blendjax.ops.attention import (
+        attention_reads_packed,
+        local_attention_packed,
+    )
+
+    b, t, h, d = shape
+    assert attention_reads_packed(b, t, h, d, dtype, "flash")
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def loss(qkv, bias):
+        out = local_attention_packed(qkv, h, bias=bias, causal=causal,
+                                     backend="flash")
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        _sds((b, t, 3 * h * d), dtype, one), _sds((3 * h * d,), dtype, one)
+    ).compile()
+    text = _assert_fits_with_kernel(compiled)
+    assert not _under_attn_core(text, "pad", "slice", "copy", "transpose")
+
+
 def test_auto_attention_at_the_benchmark_shape_is_fused(
     topo, tpu_branches, monkeypatch
 ):
@@ -351,6 +391,68 @@ def test_fused_step_embeds_patches_by_a_product(topo, tpu_branches):
     _assert_embedding_is_a_product(_assert_fits_with_kernel(compiled))
 
 
+def _assert_attention_reads_the_packed_product(text, batch):
+    """In the step compiled for the described v5e the fused kernels sit
+    directly on the ``qkv`` product and its transposes: both are inside
+    by name under ``attn_core``, the backward's first result is the
+    one packed gradient (the bias's column sums beside it), and no ``copy``, ``transpose``, ``concatenate`` or
+    ``dynamic-update-slice`` of one q, k or v's size (``batch`` images a
+    device x 1,200 tokens x 768) or more carries the ``attn_core``
+    scope or the ``qkv`` module's name (six layout copies a layer stood
+    there: 1.38 ms an update on the chip)."""
+    import re
+
+    from blendjax.utils.metrics import (
+        KERNEL_FLASH_BWD,
+        KERNEL_FLASH_FWD,
+        SCOPE_ATTN_CORE,
+    )
+
+    calls = {
+        kernel: [ln for ln in text.splitlines()
+                 if "tpu_custom_call" in ln
+                 and ln.strip().startswith(f"%{kernel}")]
+        for kernel in (KERNEL_FLASH_FWD, KERNEL_FLASH_BWD)
+    }
+    assert all(calls.values()), {k: len(v) for k, v in calls.items()}
+    for lines in calls.values():
+        assert all(SCOPE_ATTN_CORE in ln for ln in lines)
+    packed = f"bf16[{batch},1200,2304]{{2,1,0"
+    assert all(ln.split(" = ")[1].lstrip("(").startswith(packed)
+               for ln in calls[KERNEL_FLASH_BWD])
+    moved = []
+    for ln in text.splitlines():
+        m = re.match(
+            r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]+)\]\S* "
+            r"(copy|transpose|concatenate|dynamic-update-slice)\(", ln
+        )
+        name = ln.partition('op_name="')[2].partition('"')[0]
+        if m and (SCOPE_ATTN_CORE in name or "/qkv/" in name):
+            if np.prod([int(n) for n in m.group(1).split(",")]) >= (
+                batch * 1200 * 768
+            ):
+                moved.append(ln.strip()[:200])
+    assert not moved, moved
+
+
+def test_fused_step_attention_reads_the_packed_product(
+    topo, tpu_branches, monkeypatch
+):
+    """One chip, the benchmark's width and 1,200 tokens, one block (the
+    layers are alike), a small chunk (the scan's body is the same at
+    any)."""
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    one = SingleDeviceSharding(topo.devices[0])
+    step = make_fused_tile_step(loss_fn=chip_smoke.former_loss)
+    compiled = _lower_fused_tile(
+        step, _abstract_state(_vit_stem_former(), one), 2, one,
+        _tile_plan((16, 32)),
+    ).compile()
+    _assert_attention_reads_the_packed_product(
+        _assert_fits_with_kernel(compiled), B
+    )
+
+
 def test_fused_tile_step_names_the_decode_kernel(topo, tpu_branches):
     """In the step compiled for the described v5e the Pallas decode is
     found by name, not by shape: the custom call's ``op_name`` carries
@@ -495,6 +597,25 @@ def test_four_chip_fused_step_embeds_patches_by_a_product(
     ).compile()
     text = _assert_fits_with_kernel(compiled)
     _assert_embedding_is_a_product(text)
+    assert "all-reduce(" in text or "all-reduce-start(" in text
+
+
+def test_four_chip_fused_step_attention_reads_the_packed_product(
+    topo, tpu_branches, mesh4
+):
+    """The same on the 2x2 data mesh: the packed call goes through
+    ``shard_map`` over the batch axis as the three-tensor call does,
+    each chip's two images."""
+    rep = NamedSharding(mesh4, P())
+    state = _abstract_state(_vit_stem_former(), rep)
+    step = make_mesh_fused_step(
+        state, mesh4, loss_fn=chip_smoke.former_loss
+    )
+    compiled = _lower_fused_tile(
+        step, state, 2, rep, _tile_plan((16, 32))
+    ).compile()
+    text = _assert_fits_with_kernel(compiled)
+    _assert_attention_reads_the_packed_product(text, B // 4)
     assert "all-reduce(" in text or "all-reduce-start(" in text
 
 
