@@ -12,21 +12,33 @@ JAX-native equivalents plus the models the TPU train loops need:
 - :class:`StreamFormer` — a compact vision transformer over image streams
   with optional ring attention (sequence-parallel) and tensor-parallel
   friendly dims; the multi-chip sharding showcase.
+- :class:`StreamHybrid` — a stack by pattern string, one mixer a layer:
+  Mamba-2 (:class:`Mamba2Mixer`), routed experts without drops as one
+  chip's share (:class:`RoutedExperts`), grouped-query attention.
 """
 
 from blendjax.models.cnn import CubeRegressor
 from blendjax.models.discriminator import Discriminator
-from blendjax.models.moe import MoEMLP, apply_with_aux, collect_aux_loss
+from blendjax.models.hybrid import Mamba2Mixer, StreamHybrid
+from blendjax.models.moe import (
+    MoEMLP,
+    RoutedExperts,
+    apply_with_aux,
+    collect_aux_loss,
+)
 from blendjax.models.policy import PolicyValueNet, QNetwork
 from blendjax.models.transformer import StreamFormer
 
 __all__ = [
     "CubeRegressor",
     "Discriminator",
+    "Mamba2Mixer",
     "MoEMLP",
+    "RoutedExperts",
     "apply_with_aux",
     "collect_aux_loss",
     "PolicyValueNet",
     "QNetwork",
     "StreamFormer",
+    "StreamHybrid",
 ]

@@ -16,9 +16,18 @@ from __future__ import annotations
 from typing import Any
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
+from jax import lax
 
 from blendjax.precision import default_compute_dtype
+from blendjax.utils.metrics import (
+    SCOPE_MOE,
+    SCOPE_MOE_EXPERTS,
+    SCOPE_MOE_ROUTE,
+    SCOPE_MOE_SHARED,
+    metrics,
+)
 
 
 def collect_aux_loss(intermediates) -> jnp.ndarray:
@@ -110,3 +119,105 @@ class MoEMLP(nn.Module):
         combine = dispatch * gate[:, None, None]
         y = jnp.einsum("nec,ecd->nd", combine.astype(dtype), ye)
         return y.reshape(b, t, c)
+
+
+# -- routed experts without drops, one chip's share ----------------------------
+
+
+def relu2(x):
+    return jnp.square(nn.relu(x))
+
+
+class RoutedExperts(nn.Module):
+    """An expert layer as the sparse models of 2025 publish it, and one
+    chip's share of it: ``(B, T, C) -> (B, T, C)``.
+
+    Every token scores all ``num_experts`` routed experts
+    (``sigmoid(x W_r)``, float32), picks the ``experts_per_token`` with
+    the largest score plus selection bias (``e_score_correction_bias``:
+    read by the selection alone, so its gradient is zero), and weighs
+    them by their scores, normalised to sum to 1 and times ``scaling``.
+    An expert is ``W_down relu(W_up x)^2``, no gate and no bias; a shared
+    expert of the same form (``shared_width``) sees every token.
+
+    The module holds the ``experts_held`` experts from ``expert_offset``
+    on (all of them by default), stacked on a leading dimension, and
+    computes the part of the result they give: what expert parallelism
+    leaves on one chip. The picks of absent experts are left out, not
+    re-routed. No pick is dropped and no shape depends on the routing:
+    every held expert runs over every token, times the token's weight for
+    it (0 where it was not chosen), as two plain products whose cost is
+    ``experts_held x N`` rows wherever the picks fall. The load a chip
+    sees is anywhere between nothing and every pick at a seeded
+    initialisation on near-identical tokens (PERF.md, PR 37), so a cost
+    that followed the rows would make the step's time the seed's. A
+    token's parts are summed in float32.
+    """
+
+    num_experts: int
+    experts_per_token: int
+    expert_width: int
+    shared_width: int = 0
+    experts_held: int | None = None
+    expert_offset: int = 0
+    scaling: float = 1.0
+    dtype: Any = None  # None -> the precision policy's compute dtype
+
+    @nn.compact
+    def __call__(self, x):
+        dtype = default_compute_dtype(self.dtype)
+        b, t, c = x.shape
+        n, k, f = b * t, self.experts_per_token, self.expert_width
+        held = self.num_experts if self.experts_held is None else (
+            self.experts_held
+        )
+        router = self.param(
+            "router", nn.initializers.lecun_normal(),
+            (c, self.num_experts), jnp.float32,
+        )
+        bias = self.param(
+            "e_score_correction_bias", nn.initializers.zeros_init(),
+            (self.num_experts,), jnp.float32,
+        )
+        stacked = nn.initializers.lecun_normal(batch_axis=0)
+        w_up = self.param("experts_up", stacked, (held, c, f), jnp.float32)
+        w_down = self.param("experts_down", stacked, (held, f, c), jnp.float32)
+        w_up, w_down = w_up.astype(dtype), w_down.astype(dtype)
+        tokens = x.reshape(n, c).astype(dtype)
+
+        metrics.count("moe.path.dense")
+        with jax.named_scope(SCOPE_MOE):
+            with jax.named_scope(SCOPE_MOE_ROUTE):
+                scores = nn.sigmoid(jnp.dot(
+                    tokens.astype(jnp.float32), router,
+                    precision=lax.Precision.HIGHEST,
+                ))
+                _, experts = lax.top_k(scores + lax.stop_gradient(bias), k)
+                weights = jnp.take_along_axis(scores, experts, axis=1)
+                weights = self.scaling * weights / (
+                    weights.sum(axis=1, keepdims=True) + 1e-20
+                )
+                local = experts[:, :, None] - self.expert_offset
+                gate = jnp.sum(
+                    jnp.where(local == jnp.arange(held), weights[:, :, None],
+                              0.0), axis=1,
+                )                                              # (N, held)
+            with jax.named_scope(SCOPE_MOE_EXPERTS):
+                up = jnp.einsum("nc,ecf->nef", tokens, w_up,
+                                preferred_element_type=jnp.float32)
+                y = jnp.einsum(
+                    "nef,efc->nc",
+                    (gate[:, :, None] * relu2(up)).astype(dtype), w_down,
+                    preferred_element_type=jnp.float32,
+                )
+            if self.shared_width:
+                with jax.named_scope(SCOPE_MOE_SHARED):
+                    hidden = nn.Dense(
+                        self.shared_width, use_bias=False, dtype=dtype,
+                        param_dtype=jnp.float32, name="shared_up",
+                    )(tokens)
+                    y = y + nn.Dense(
+                        c, use_bias=False, dtype=dtype,
+                        param_dtype=jnp.float32, name="shared_down",
+                    )(relu2(hidden)).astype(jnp.float32)
+        return y.astype(dtype).reshape(b, t, c)

@@ -31,6 +31,7 @@ from blendjax.ops.image import embed_patches
 from blendjax.parallel.ring import ring_attention
 from blendjax.parallel.ulysses import ulysses_attention
 from blendjax.precision import default_compute_dtype
+from blendjax.utils.metrics import metrics
 
 
 class PackedQKV(nn.Module):
@@ -66,6 +67,14 @@ class PackedQKV(nn.Module):
 
 
 class MultiHeadAttention(nn.Module):
+    """Self-attention with its projections. By default ``num_heads`` equal
+    heads of ``C // num_heads`` from one fused ``qkv`` projection, with
+    biases. ``num_kv_heads`` fewer key/value heads (grouped-query
+    attention: query head ``i`` reads key/value head
+    ``i // (num_heads // num_kv_heads)``) and ``head_dim`` a head width
+    of its own take separate ``q``, ``k``, ``v`` projections;
+    ``use_bias=False`` drops every bias."""
+
     num_heads: int
     dtype: Any = None  # None -> the precision policy's compute dtype
     use_ring: bool = False
@@ -75,13 +84,18 @@ class MultiHeadAttention(nn.Module):
     causal: bool = False
     sp_mode: str = "ring"  # 'ring' | 'ulysses' (when use_ring=True)
     attn_backend: str = "auto"  # local path: 'auto' | 'flash' | 'xla'
+    num_kv_heads: int | None = None  # None -> num_heads, the fused qkv
+    head_dim: int | None = None  # None -> C // num_heads
+    use_bias: bool = True
 
     @nn.compact
     def __call__(self, x):
         dtype = default_compute_dtype(self.dtype)
         b, t, c = x.shape
         h = self.num_heads
-        d = c // h
+        d = self.head_dim or c // h
+        if self.num_kv_heads is not None or not self.use_bias or d * h != c:
+            return self._grouped(x, dtype, d)
         # use_ring gates sequence parallelism for back-compat; explicitly
         # requesting the non-default strategy also enables it.
         use_sp = self.use_ring or self.sp_mode == "ulysses"
@@ -137,6 +151,37 @@ class MultiHeadAttention(nn.Module):
         o = o.astype(dtype).reshape(b, t, c)
         return nn.Dense(c, dtype=dtype, param_dtype=jnp.float32,
                         name="proj")(o)
+
+    def _grouped(self, x, dtype, d):
+        """Separate projections: ``q`` to ``num_heads`` heads, ``k`` and
+        ``v`` to ``num_kv_heads``, each key/value head broadcast to the
+        query heads that read it, then the three-tensor
+        :func:`local_attention` (the fused kernels where ``auto`` takes
+        them: one body, K and V repeated in HBM, their gradient summed
+        over the group by the broadcast's transpose)."""
+        assert not (self.use_ring or self.sp_mode == "ulysses"), (
+            "sequence parallelism takes the fused qkv projection"
+        )
+        b, t, c = x.shape
+        h, kv = self.num_heads, self.num_kv_heads or self.num_heads
+        assert h % kv == 0, f"{kv} key/value heads do not divide {h}"
+
+        def project(name, heads):
+            return nn.DenseGeneral(
+                (heads, d), axis=-1, use_bias=self.use_bias, dtype=dtype,
+                param_dtype=jnp.float32, name=name,
+            )(x)
+
+        q, k, v = project("q", h), project("k", kv), project("v", kv)
+        if kv != h:
+            metrics.count("attn.path.gqa")
+            k, v = (jnp.repeat(a, h // kv, axis=2) for a in (k, v))
+        o = local_attention(q, k, v, causal=self.causal,
+                            backend=self.attn_backend)
+        return nn.Dense(c, use_bias=self.use_bias, dtype=dtype,
+                        param_dtype=jnp.float32, name="proj")(
+            o.astype(dtype).reshape(b, t, h * d)
+        )
 
 
 class Block(nn.Module):

@@ -46,9 +46,21 @@ SCOPE_ATTN_CORE = "attn_core"      # scores -> softmax -> weighted sum
 # StreamFormer's input side, frames -> patch tokens: the flax module's own
 # name (``jvp(StreamFormer)/patch_embed/dot_general``), no named_scope
 SCOPE_PATCH_EMBED = "patch_embed"
+# StreamHybrid's mixers (models/hybrid.py, models/moe.py, ops/ssd.py): a
+# Mamba-2 layer's whole mixer and, inside it, the state-space scan alone;
+# an expert layer's whole mixer and, inside it, the router with the
+# selection and the per-expert gate, the held experts' two products with
+# their activation, and the shared expert
+SCOPE_SSM_MIXER = "ssm_mixer"
+SCOPE_SSD = "ssd"
+SCOPE_MOE = "moe"
+SCOPE_MOE_ROUTE = "moe_route"
+SCOPE_MOE_EXPERTS = "moe_experts"
+SCOPE_MOE_SHARED = "moe_shared"
 STEP_SCOPES = (
     SCOPE_DECODE, SCOPE_PALETTE_EXPAND, SCOPE_RESHARD, SCOPE_OPTIMIZER,
-    SCOPE_ATTN_CORE, SCOPE_PATCH_EMBED,
+    SCOPE_ATTN_CORE, SCOPE_PATCH_EMBED, SCOPE_SSM_MIXER, SCOPE_SSD,
+    SCOPE_MOE, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS, SCOPE_MOE_SHARED,
 )
 # The Pallas decode kernels: each is the ``name=`` of its ``pallas_call``
 # and a scope around the call (inside ``decode``).

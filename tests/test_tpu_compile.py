@@ -183,11 +183,13 @@ def _under_attn_core(text, *ops):
         ((2, 1200, 12, 64), False, jnp.bfloat16, False),
         ((2, 1200, 12, 64), False, jnp.float32, False),
         ((2, 197, 12, 64), False, jnp.bfloat16, True),
+        ((8, 1200, 32, 128), True, jnp.bfloat16, False),
         pytest.param((1, 16384, 4, 128), False, jnp.bfloat16, False,
                      marks=pytest.mark.slow),
     ],
-    ids=["T3072", "T768", "T1200-causal", "T1200", "T1200-f32",
-         "T197-unaligned", "T16384"],
+    ids=["T3072", "T768", "T1200-causal", "T1200",
+                           "T1200-f32", "T197-unaligned",
+                           "T1200-causal-32x128", "T16384"],
 )
 def test_flash_attention_fwd_bwd_compiles(
     topo, tpu_branches, shape, causal, dtype, hbm_pads
@@ -196,7 +198,8 @@ def test_flash_attention_fwd_bwd_compiles(
     from the shape, at ``chip_smoke.py``'s flash shape, the StreamFormer's
     768 tokens, 1,200 at their own length (K/V padded in VMEM; causal,
     and in f32), 197 tokens no sublane tile divides (Q padded in HBM,
-    K/V copied by part of a tile), and the most keys it admits."""
+    K/V copied by part of a tile), the hybrid cell's 32 causal heads of
+    128 (K and V broadcast from 2), and the most keys it admits."""
     q = _sds(shape, dtype, SingleDeviceSharding(topo.devices[0]))
     compiled = _attn_loss("flash", causal).lower(q, q, q).compile()
     text = _assert_fits_with_kernel(compiled)
@@ -279,6 +282,57 @@ def test_auto_attention_at_the_benchmark_shape_is_fused(
         auto.memory_analysis().temp_size_in_bytes
         < xla.memory_analysis().temp_size_in_bytes / 2
     )
+
+
+@pytest.mark.slow  # 5 to 18 s of the TPU compiler on every core, each
+@pytest.mark.parametrize("kind, must_hold", [
+    ("M", ()),
+    ("E", ()),
+    ("*", ("flash_attention_fwd", "flash_attention_bwd")),
+], ids=["mamba2", "experts", "gqa"])
+def test_hybrid_layer_compiles_at_published_widths(
+    topo, tpu_branches, monkeypatch, kind, must_hold
+):
+    """One layer of each kind of ``nemotron3_nano_30b_a3b`` (the
+    benchmark's configuration file's own arguments), forward and
+    backward over 8 x 1,200 tokens of width 2688: the chunked scan, the
+    held experts, grouped-query attention through the fused kernels.
+    The whole fused step is ``benchmark/compile_rehearsal.py``'s."""
+    import json
+    import os
+
+    from blendjax.models import StreamHybrid
+
+    monkeypatch.setattr(jax, "device_count", lambda: 1)  # a one-chip machine
+    with open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "configs", "nemotron3_nano_30b_a3b.json",
+    )) as f:
+        kwargs = json.load(f)["model"]["kwargs"]
+    model = StreamHybrid(**{
+        **kwargs, "pattern": kind, "remat": False,
+    })
+    placed = SingleDeviceSharding(topo.devices[0])
+    images = _sds((B, H, W, C), jnp.uint8, placed)
+    params = jax.tree_util.tree_map(
+        lambda x: _sds(x.shape, x.dtype, placed),
+        jax.eval_shape(
+            lambda: model.init(jax.random.key(0), np.zeros((B, H, W, C),
+                                                           np.uint8))
+        )["params"],
+    )
+
+    def loss(p, x):
+        return jnp.sum(model.apply({"params": p}, x))
+
+    compiled = jax.jit(jax.value_and_grad(loss)).lower(
+        params, images
+    ).compile()
+    text = _assert_fits_with_kernel(
+        compiled, kernel=bool(must_hold)
+    )
+    for name in must_hold:
+        assert name in text, name
 
 
 def test_gamma_normalize_compiles(topo):
