@@ -142,10 +142,20 @@ class Mamba2Mixer(nn.Module):
                 param_dtype=jnp.float32, name="in_proj",
             )(x)
             z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + bc], axis=-1)
-            xbc = nn.silu(
-                causal_depthwise_conv(xbc, conv_kernel, conv_bias)
-            ).astype(dtype)
-            xs, b_in, c_in = jnp.split(xbc, [inner, inner + g * n], axis=-1)
+            # the convolution is a channel's own: x, B and C go through
+            # it apart, so that each is written once, as the array the
+            # scan reads (one convolution over all three, split
+            # afterwards, leaves a slice of its output in HBM in front
+            # of the scan's kernel)
+            edges = [inner, inner + g * n]
+            xs, b_in, c_in = (
+                nn.silu(causal_depthwise_conv(part, kernel, bias)).astype(dtype)
+                for part, kernel, bias in zip(
+                    jnp.split(xbc, edges, axis=-1),
+                    jnp.split(conv_kernel, edges, axis=-1),
+                    jnp.split(conv_bias, edges),
+                )
+            )
             dt = nn.softplus(dt.astype(jnp.float32) + dt_bias)
             y = ssd_chunked(
                 xs.reshape(b, t, h, p), dt, -jnp.exp(a_log),

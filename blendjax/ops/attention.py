@@ -647,21 +647,27 @@ def _flash_attention_packed(qkv, bias, num_heads, causal, scale):
 
 def _per_batch_shard(fn, batch, n_sharded, n_whole=0):
     """``fn`` as the program being traced may call a kernel
-    (:func:`_placement`): as it is, or through ``shard_map`` over a
-    declared mesh's batch axes, its first ``n_sharded`` arguments cut
-    along their leading (batch) dimension and the ``n_whole`` after
-    them given to every shard whole."""
+    (:func:`_placement`): as it is, or through
+    :func:`shard_over_batch` on a declared mesh."""
     placed = _placement()
     if not isinstance(placed, tuple):
         return fn
+    metrics.count("attn.path.shard_map")
+    return shard_over_batch(fn, placed, batch, n_sharded, n_whole)
+
+
+def shard_over_batch(fn, placed, batch, n_sharded, n_whole=0):
+    """``fn`` through ``shard_map`` over a declared mesh's batch axes
+    (``placed``: :func:`_placement`'s tuple), its first ``n_sharded``
+    arguments and its result cut along their leading (batch) dimension
+    and the ``n_whole`` after them given to every shard whole."""
     from jax.sharding import PartitionSpec as P
 
     from blendjax.parallel.collectives import _shard_map
 
     mesh, axes, n = placed
-    # an explicit "flash" the axes do not divide runs replicated
+    # an explicit kernel backend the axes do not divide runs replicated
     spec = P(axes) if n > 1 and batch % n == 0 else P()
-    metrics.count("attn.path.shard_map")
     # check=False: pallas_call's out_shape carries no varying-
     # mesh-axes annotation, which the VMA checker requires
     return _shard_map(

@@ -69,9 +69,12 @@ KERNEL_TILE_DECODE_SCATTER = "tile_decode_scatter"
 # The fused attention core's forward and backward (inside ``attn_core``).
 KERNEL_FLASH_FWD = "flash_attention_fwd"
 KERNEL_FLASH_BWD = "flash_attention_bwd"
+# The state-space scan's forward and backward (inside ``ssd``).
+KERNEL_SSD_FWD = "ssd_scan_fwd"
+KERNEL_SSD_BWD = "ssd_scan_bwd"
 KERNEL_NAMES = (
     KERNEL_TILE_DECODE_SPATIAL, KERNEL_TILE_DECODE_SCATTER,
-    KERNEL_FLASH_FWD, KERNEL_FLASH_BWD,
+    KERNEL_FLASH_FWD, KERNEL_FLASH_BWD, KERNEL_SSD_FWD, KERNEL_SSD_BWD,
 )
 
 

@@ -284,9 +284,65 @@ def test_auto_attention_at_the_benchmark_shape_is_fused(
     )
 
 
+def _hbm_lines(text, *shapes):
+    """The compiled program's instructions (not the lines inside a
+    fusion's computation, which live in registers) whose result has one
+    of ``shapes`` (``f32[8,1200,64`` ...)."""
+    entry = text[text.index("ENTRY "):]
+    return [
+        ln.strip()[:200] for ln in entry.splitlines()
+        if " = " in ln and any(
+            ln.split(" = ", 1)[1].lstrip("(").startswith(s) for s in shapes
+        )
+    ]
+
+
+def test_ssd_scan_fwd_bwd_compiles(topo, tpu_branches, monkeypatch):
+    """The state-space scan's kernel pair at ``nemotron3nano_replay``'s
+    layer shape (8 x 1,200 tokens, 64 heads of 64 in 8 groups, state
+    128, chunk 128: a ragged last chunk of 48) through ``auto`` on a
+    one-chip machine: both kernels come back from the TPU compiler by
+    name under SSD_VMEM_BYTES, the backward's under the ``ssd`` scope
+    too, and nothing of a chunk's ``L x L`` size or of a padded length
+    is in HBM."""
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    from blendjax.ops.ssd import ssd_chunked
+    from blendjax.utils.metrics import (
+        KERNEL_SSD_BWD,
+        KERNEL_SSD_FWD,
+        SCOPE_SSD,
+    )
+
+    one = SingleDeviceSharding(topo.devices[0])
+    b, t, h, p, g, n = 8, 1200, 64, 64, 8, 128
+
+    def loss(*v):
+        return jnp.sum(ssd_chunked(*v, chunk=128).astype(jnp.float32))
+
+    compiled = jax.jit(jax.value_and_grad(loss, tuple(range(6)))).lower(
+        _sds((b, t, h, p), jnp.bfloat16, one), _sds((b, t, h), jnp.float32, one),
+        _sds((h,), jnp.float32, one), _sds((b, t, g, n), jnp.bfloat16, one),
+        _sds((b, t, g, n), jnp.bfloat16, one), _sds((h,), jnp.float32, one),
+    ).compile()
+    text = _assert_fits_with_kernel(compiled)
+    op_names = {
+        ln.split("=")[0].strip(): ln.split('op_name="')[1].split('"')[0]
+        for ln in text.splitlines() if "tpu_custom_call" in ln
+    }
+    fwd, bwd = (
+        next(op for call, op in op_names.items()
+             if call.startswith(f"%{kernel}"))
+        for kernel in (KERNEL_SSD_FWD, KERNEL_SSD_BWD)
+    )
+    assert SCOPE_SSD in fwd and "transpose(" not in fwd, fwd
+    assert SCOPE_SSD in bwd and "transpose(jvp(" in bwd, bwd
+    assert not _hbm_lines(text, "f32[8,10,8,8,128,128", "bf16[8,10,8,8,128,128",
+                          "bf16[8,1280", "f32[8,1280")
+
+
 @pytest.mark.slow  # 5 to 18 s of the TPU compiler on every core, each
 @pytest.mark.parametrize("kind, must_hold", [
-    ("M", ()),
+    ("M", ("ssd_scan_fwd", "ssd_scan_bwd")),
     ("E", ()),
     ("*", ("flash_attention_fwd", "flash_attention_bwd")),
 ], ids=["mamba2", "experts", "gqa"])
@@ -295,9 +351,12 @@ def test_hybrid_layer_compiles_at_published_widths(
 ):
     """One layer of each kind of ``nemotron3_nano_30b_a3b`` (the
     benchmark's configuration file's own arguments), forward and
-    backward over 8 x 1,200 tokens of width 2688: the chunked scan, the
-    held experts, grouped-query attention through the fused kernels.
-    The whole fused step is ``benchmark/compile_rehearsal.py``'s."""
+    backward over 8 x 1,200 tokens of width 2688: the chunked scan
+    through its kernel pair (no chunk's ``L x L`` decay tensor in HBM,
+    and x, B, C and y neither copied, padded, sliced nor broadcast to
+    heads in front of or behind the kernels), the held experts,
+    grouped-query attention through the fused kernels. The whole fused
+    step is ``benchmark/compile_rehearsal.py``'s."""
     import json
     import os
 
@@ -333,6 +392,33 @@ def test_hybrid_layer_compiles_at_published_widths(
     )
     for name in must_hold:
         assert name in text, name
+    if kind == "M":
+        assert not _hbm_lines(text, "f32[8,10,8,8,128,128",
+                              "bf16[8,10,8,8,128,128", "bf16[8,1280",
+                              "f32[8,1280", "bf16[8,1200,64,128")
+        # what the kernels read is what a fusion of the mixer wrote: no
+        # layout copy, slice or pad of an activation stands between (dt
+        # alone is re-laid out, f32[8,64,1200])
+        calls = [ln for ln in text.splitlines()
+                 if "tpu_custom_call" in ln and "%ssd_scan_" in ln]
+        names = {
+            name for ln in calls
+            for name in ln.split("custom-call(")[1].split(")")[0].split(", ")
+        }
+        made_by = {
+            ln.split(" = ")[0].strip(): ln.split(" = ")[1]
+            for ln in text[text.index("ENTRY "):].splitlines() if " = " in ln
+        }
+        for name in names:
+            name = name.split("*/")[-1]
+            made = made_by[name]
+            if made.startswith(("bf16[8,1200,4096]", "bf16[8,1200,1024]")):
+                # (a `copy-done` is XLA's prefetch into faster memory,
+                # the same layout, overlapped)
+                assert not any(f" {op}(" in made for op in (
+                    "copy", "slice", "pad", "transpose", "broadcast",
+                    "concatenate",
+                )), made
 
 
 def test_gamma_normalize_compiles(topo):
@@ -599,6 +685,32 @@ def test_four_chip_attention_runs_per_shard(topo, tpu_branches, mesh4):
     assert "all-gather" not in text
     assert "all-reduce(" in text or "all-reduce-start(" in text
     assert "bf16[8,1200,768]" in text  # one shard, its own length, heads in lanes
+
+
+def test_four_chip_ssd_scan_runs_per_shard(topo, tpu_branches, mesh4):
+    """The scan's kernel pair data-parallel at batch 32 with the mesh
+    declared: each chip runs it over its 8 rows (``shard_map`` over the
+    batch axis), nothing is gathered, and the per-head gradients of the
+    replicated ``a`` and ``d`` have their all-reduce."""
+    from blendjax.ops.attention import batch_sharded_over
+    from blendjax.ops.ssd import ssd_chunked
+
+    def loss(*v):
+        with batch_sharded_over(mesh4, "data"):
+            return jnp.sum(ssd_chunked(*v, chunk=128).astype(jnp.float32))
+
+    rows, whole = NamedSharding(mesh4, P("data")), NamedSharding(mesh4, P())
+    b, t, h, p, g, n = 32, 1200, 64, 64, 8, 128
+    compiled = jax.jit(jax.value_and_grad(loss, tuple(range(6)))).lower(
+        _sds((b, t, h, p), jnp.bfloat16, rows), _sds((b, t, h), jnp.float32, rows),
+        _sds((h,), jnp.float32, whole), _sds((b, t, g, n), jnp.bfloat16, rows),
+        _sds((b, t, g, n), jnp.bfloat16, rows), _sds((h,), jnp.float32, whole),
+    ).compile()
+    text = _assert_fits_with_kernel(compiled)
+    assert "%ssd_scan_fwd" in text and "%ssd_scan_bwd" in text
+    assert "all-gather" not in text
+    assert "all-reduce(" in text or "all-reduce-start(" in text
+    assert "bf16[8,1200,4096]" in text  # one shard, its own length
 
 
 def test_undeclared_attention_in_a_partitioned_program(
