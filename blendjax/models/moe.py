@@ -22,11 +22,11 @@ from jax import lax
 
 from blendjax.precision import default_compute_dtype
 from blendjax.utils.metrics import (
+    COUNTERS_COLLECTION,
     SCOPE_MOE,
     SCOPE_MOE_EXPERTS,
     SCOPE_MOE_ROUTE,
     SCOPE_MOE_SHARED,
-    metrics,
 )
 
 
@@ -152,6 +152,17 @@ class RoutedExperts(nn.Module):
     initialisation on near-identical tokens (PERF.md, PR 37), so a cost
     that followed the rows would make the step's time the seed's. A
     token's parts are summed in float32.
+
+    Every call also counts where its picks fell, in integers with no
+    gradient, and sows three scalars into the ``counters`` collection
+    (the step builders return them beside the loss,
+    :func:`blendjax.train.steps.counting`): ``rows_held``, the picks
+    on the held experts, which a drop-free sparse form would compute
+    here; ``rows_busiest_share``, the most picks any one share of
+    ``experts_held`` experts gets, the shares being the deployment's
+    chips by contiguous blocks of experts, which an expert-parallel
+    step waits on; and ``rows_even_share``, a share's even load
+    ``N k experts_held / num_experts`` (rounded down).
     """
 
     num_experts: int
@@ -185,7 +196,6 @@ class RoutedExperts(nn.Module):
         w_up, w_down = w_up.astype(dtype), w_down.astype(dtype)
         tokens = x.reshape(n, c).astype(dtype)
 
-        metrics.count("moe.path.dense")
         with jax.named_scope(SCOPE_MOE):
             with jax.named_scope(SCOPE_MOE_ROUTE):
                 scores = nn.sigmoid(jnp.dot(
@@ -193,6 +203,7 @@ class RoutedExperts(nn.Module):
                     precision=lax.Precision.HIGHEST,
                 ))
                 _, experts = lax.top_k(scores + lax.stop_gradient(bias), k)
+                self._count(experts, held)
                 weights = jnp.take_along_axis(scores, experts, axis=1)
                 weights = self.scaling * weights / (
                     weights.sum(axis=1, keepdims=True) + 1e-20
@@ -221,3 +232,26 @@ class RoutedExperts(nn.Module):
                         param_dtype=jnp.float32, name="shared_down",
                     )(relu2(hidden)).astype(jnp.float32)
         return y.astype(dtype).reshape(b, t, c)
+
+    def _count(self, experts, held):
+        """Sow how the picks ``experts`` (N, k) fall: see the class."""
+        e = self.num_experts
+        # the picks on the lanes and an expert a row: with the experts on
+        # the lanes instead, each pick is broadcast across them, and on
+        # the chip the count took 23.6 us a layer where this takes 2.9
+        # (9,600 tokens, 6 of 128; my chip run, PR 39)
+        per_expert = jnp.sum(
+            experts.T[None] == jnp.arange(e)[:, None, None], axis=(1, 2),
+            dtype=jnp.int32,
+        )  # what the selection bias's balancing update reads
+        shares = -(-e // held)
+        per_share = jnp.pad(per_expert, (0, shares * held - e)).reshape(
+            shares, held
+        ).sum(axis=1)
+        lo = self.expert_offset
+        for name, value in (
+            ("rows_held", per_expert[lo:lo + held].sum()),
+            ("rows_busiest_share", per_share.max()),
+            ("rows_even_share", jnp.int32(experts.size * held // e)),
+        ):
+            self.sow(COUNTERS_COLLECTION, name, value)

@@ -38,7 +38,7 @@ from blendjax.obs.trace import (
     stage as trace_stage,
     tracer,
 )
-from blendjax.utils.metrics import metrics
+from blendjax.utils.metrics import SOWN_COUNTERS, metrics
 
 logger = logging.getLogger(__name__)
 
@@ -156,7 +156,7 @@ class TrainDriver:
         # itself here; submit() honors the flag at the next step
         # boundary with a drain + synchronous snapshot.
         self.preempt = None
-        # ring entries: [loss, t_dispatch_mono, images, traces]
+        # ring entries: [loss, t_dispatch_mono, images, traces, counters]
         self._pending: collections.deque = collections.deque()
         self.losses: list = []
         self.steps = 0
@@ -314,10 +314,13 @@ class TrainDriver:
 
     def _retire(self, entry) -> None:
         """Account one completed ring entry: the dispatch->retirement
-        device-timeline histogram, the live MFU gauge, and the terminal
-        stamp of any frame trace riding the entry. Host bookkeeping
-        only — the loss value itself is NOT fetched here."""
-        _loss, t0, images, traces = entry
+        device-timeline histogram, the live MFU gauge, the terminal
+        stamp of any frame trace riding the entry, and the step's
+        in-program counts, booked under their registry names
+        (``SOWN_COUNTERS``). Host bookkeeping only — the loss value
+        itself is NOT fetched here, and the counts, outputs of a program
+        that has finished, were copied to the host since its dispatch."""
+        _loss, t0, images, traces, counters = entry
         now = time.monotonic()
         if self._t_first_retire is None:
             self._t_first_retire = now
@@ -343,6 +346,10 @@ class TrainDriver:
             for tr in traces:
                 trace_stage(tr, TERMINAL_STAGE)
                 tracer.complete(tr)
+        if counters:
+            for sown, name in SOWN_COUNTERS:
+                if sown in counters:
+                    metrics.count(name, int(counters[sown]))
 
     def _block_oldest(self) -> None:
         """Retire the oldest in-flight entry, blocking if needed. A
@@ -483,8 +490,14 @@ class TrainDriver:
             self.retrace_audit.observe(batch)
         self.dispatches += 1
         self.steps += 1
+        counters = m.get("counters")
+        if counters:
+            for v in counters.values():
+                v.copy_to_host_async()  # read at retirement, no wait there
         pending = self._pending
-        pending.append([m["loss"], time.monotonic(), images, traces])
+        pending.append(
+            [m["loss"], time.monotonic(), images, traces, counters]
+        )
         if len(pending) > self.inflight_hwm:
             self.inflight_hwm = len(pending)
         # Registry mirror runs UNCONDITIONALLY (gauge_max is already a

@@ -10,6 +10,7 @@ concrete state so the donated update can never drift layouts mid-run.
 
 from __future__ import annotations
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import optax
@@ -18,6 +19,7 @@ from flax.training.train_state import TrainState
 from blendjax.parallel.sharding import param_sharding_rules
 from blendjax.train.precision import policy_value_and_grad, resolve_policy
 from blendjax.utils.metrics import (
+    COUNTERS_COLLECTION,
     SCOPE_DECODE,
     SCOPE_OPTIMIZER,
     SCOPE_RESHARD,
@@ -135,6 +137,54 @@ def loss_on_mesh(loss_fn, mesh, data_axis: str = "data"):
     return on_mesh
 
 
+def counting(loss_fn):
+    """``loss_fn(state, params, batch) -> loss`` as ``-> (loss,
+    counters)``: the model is applied with its ``counters`` collection
+    mutable, and what its layers sow there (integers, e.g.
+    :class:`blendjax.models.moe.RoutedExperts`'s pick counts) comes out
+    summed by name over the layers, ``{name: int32 scalar}``. Where
+    nothing is sown the dict is empty and the program is the one
+    ``loss_fn`` alone traces. An ``apply_fn`` that is not a flax
+    module's is left as it is."""
+
+    def counted(state, params, batch):
+        apply = getattr(state, "apply_fn", None)
+        if not isinstance(getattr(apply, "__self__", None), nn.Module):
+            return loss_fn(state, params, batch), {}
+        sown = []
+
+        def apply_fn(variables, *args, **kwargs):
+            out, cols = apply(
+                variables, *args, mutable=[COUNTERS_COLLECTION], **kwargs
+            )
+            sown.append(cols.get(COUNTERS_COLLECTION, {}))
+            return out
+
+        loss = loss_fn(state.replace(apply_fn=apply_fn), params, batch)
+        totals: dict = {}
+        for path, leaf in jax.tree_util.tree_leaves_with_path(sown):
+            name = next(
+                k.key for k in reversed(path)
+                if isinstance(k, jax.tree_util.DictKey)
+            )
+            totals[name] = totals.get(name, 0) + leaf
+        return loss, totals
+
+    return counted
+
+
+def _step_metrics(loss, counters) -> dict:
+    """A step's metrics: ``{"loss": loss}``, and where the model counted
+    anything, ``"counters"`` summed over the step's updates (the leading
+    axis of a scan's or microbatches' stacked counts)."""
+    if not counters:
+        return {"loss": loss}
+    return {
+        "loss": loss,
+        "counters": {k: jnp.sum(v) for k, v in counters.items()},
+    }
+
+
 def _sharding_jit_kwargs(state_sharding, n_data_args: int = 1,
                          data_shardings: dict | None = None) -> dict:
     """jit kwargs pinning a state's layout: ``in_shardings``/
@@ -209,7 +259,7 @@ def make_supervised_step(
     # layouts ride on the arrays (see above); the mesh is only declared
     # to the kernels below the model
     data_axis = (getattr(batch_sharding, "spec", None) or ("data",))[0]
-    loss_fn = loss_on_mesh(loss_fn, mesh, data_axis)
+    loss_fn = counting(loss_on_mesh(loss_fn, mesh, data_axis))
     base_rng = _resolve_augment_rng(augment, augment_rng)
     policy = resolve_policy(precision)
     accum_steps = max(1, int(accum_steps))
@@ -223,8 +273,9 @@ def make_supervised_step(
             return loss_fn(state, params, b)
 
         if accum_steps == 1:
-            loss, grads = policy_value_and_grad(
-                lambda p: scalar_loss(p, batch), state.params, policy
+            (loss, counters), grads = policy_value_and_grad(
+                lambda p: scalar_loss(p, batch), state.params, policy,
+                has_aux=True,
             )
         else:
             # Split only the real batch tensors; scalar sidecar fields
@@ -263,25 +314,24 @@ def make_supervised_step(
                 # policy_value_and_grad hands back grads already cast
                 # to the master params' dtype (f32), so the zeros_like
                 # accumulator below IS the policy's f32 accum_dtype
-                loss, grads = policy_value_and_grad(
+                (loss, counters), grads = policy_value_and_grad(
                     lambda p: scalar_loss(p, {**side, **mb}),
-                    state.params, policy,
+                    state.params, policy, has_aux=True,
                 )
                 return (
                     loss_sum + loss,
                     jax.tree.map(jnp.add, grad_sum, grads),
-                ), None
+                ), counters
 
             zeros = jax.tree.map(jnp.zeros_like, state.params)
-            (loss_sum, grad_sum), _ = jax.lax.scan(
+            (loss_sum, grad_sum), counters = jax.lax.scan(
                 body, (jnp.zeros(()), zeros), micro
             )
             loss = loss_sum / accum_steps
             grads = jax.tree.map(lambda g: g / accum_steps, grad_sum)
         with jax.named_scope(SCOPE_OPTIMIZER):
             state = state.apply_gradients(grads=grads)
-        metrics = {"loss": loss}
-        return state, metrics
+        return state, _step_metrics(loss, counters)
 
     return jax.jit(
         step,
@@ -306,8 +356,10 @@ def _chunk_scan_body(loss_fn, augment, base_rng, policy=None):
     so K scanned updates replay the exact augmentation sequence K
     sequential per-batch calls would. ``policy`` routes the grad
     computation through :func:`policy_value_and_grad` (same rule as
-    the per-batch step: chunked runs must not train different math)."""
+    the per-batch step: chunked runs must not train different math).
+    An update's output is ``(loss, counters)`` (:func:`counting`)."""
     policy = resolve_policy(policy)
+    loss_fn = counting(loss_fn)
 
     def body(st, batch):
         if augment is not None:
@@ -317,9 +369,11 @@ def _chunk_scan_body(loss_fn, augment, base_rng, policy=None):
         def scalar_loss(params):
             return loss_fn(st, params, batch)
 
-        loss, grads = policy_value_and_grad(scalar_loss, st.params, policy)
+        out, grads = policy_value_and_grad(
+            scalar_loss, st.params, policy, has_aux=True
+        )
         with jax.named_scope(SCOPE_OPTIMIZER):
-            return st.apply_gradients(grads=grads), loss
+            return st.apply_gradients(grads=grads), out
 
     return body
 
@@ -341,7 +395,8 @@ def make_chunked_supervised_step(
     batch, which is the difference between working and crawling on
     high-latency device links (see docs/performance.md). Pairs with
     ``StreamDataPipeline(chunk=K)``. ``metrics['loss']`` is the K-vector
-    of per-update losses.
+    of per-update losses; ``metrics['counters']``, present where the
+    model sows counts (:func:`counting`), their sums over the K updates.
 
     ``augment``/``augment_rng`` mirror :func:`make_supervised_step`:
     the per-update key folds ``augment_rng`` with the state's step
@@ -353,11 +408,11 @@ def make_chunked_supervised_step(
     base_rng = _resolve_augment_rng(augment, augment_rng)
 
     def step(state, superbatch):
-        state, losses = jax.lax.scan(
+        state, (losses, counters) = jax.lax.scan(
             _chunk_scan_body(loss_fn, augment, base_rng, precision),
             state, superbatch,
         )
-        return state, {"loss": losses}
+        return state, _step_metrics(losses, counters)
 
     return jax.jit(
         step,
@@ -425,11 +480,11 @@ def make_fused_tile_step(
             )
         with jax.named_scope(SCOPE_RESHARD):
             superbatch = pin(superbatch)
-        state, losses = jax.lax.scan(
+        state, (losses, counters) = jax.lax.scan(
             _chunk_scan_body(loss_fn, augment, base_rng, precision), state,
             superbatch,
         )
-        return state, {"loss": losses}
+        return state, _step_metrics(losses, counters)
 
     fused = jax.jit(
         _fused,
@@ -447,11 +502,11 @@ def make_fused_tile_step(
             )
         with jax.named_scope(SCOPE_RESHARD):
             superbatch = pin(superbatch)
-        state, losses = jax.lax.scan(
+        state, (losses, counters) = jax.lax.scan(
             _chunk_scan_body(loss_fn, augment, base_rng, precision), state,
             superbatch,
         )
-        return state, {"loss": losses}
+        return state, _step_metrics(losses, counters)
 
     fused_pal = jax.jit(
         _fused_pal,
@@ -542,6 +597,7 @@ def make_echo_fused_step(
     loss_fn = loss_fn or _default_loss
     policy = resolve_policy(precision)
     pin = draw_constraint or (lambda b: b)
+    counted = counting(loss_fn)
     fallback = make_supervised_step(
         loss_fn=loss_fn, donate=donate, precision=precision,
         state_sharding=state_sharding,
@@ -551,14 +607,14 @@ def make_echo_fused_step(
         batch = pin(reservoir_draw(buffers, idx, counter))
 
         def scalar_loss(params):
-            return loss_fn(state, params, batch)
+            return counted(state, params, batch)
 
-        loss, grads = policy_value_and_grad(
-            scalar_loss, state.params, policy
+        (loss, counters), grads = policy_value_and_grad(
+            scalar_loss, state.params, policy, has_aux=True
         )
         with jax.named_scope(SCOPE_OPTIMIZER):
             state = state.apply_gradients(grads=grads)
-        return state, {"loss": loss}
+        return state, _step_metrics(loss, counters)
 
     jit_kwargs = _sharding_jit_kwargs(
         state_sharding, n_data_args=3,

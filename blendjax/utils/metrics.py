@@ -76,6 +76,17 @@ KERNEL_NAMES = (
     KERNEL_TILE_DECODE_SPATIAL, KERNEL_TILE_DECODE_SCATTER,
     KERNEL_FLASH_FWD, KERNEL_FLASH_BWD, KERNEL_SSD_FWD, KERNEL_SSD_BWD,
 )
+# Counts made inside the step: the flax collection a model's layers sow
+# integers into (models/moe.py ``RoutedExperts``), which the step
+# builders return summed beside the loss (train/steps.py ``counting``),
+# and the registry counter TrainDriver books each sown name under when
+# its dispatch retires.
+COUNTERS_COLLECTION = "counters"
+SOWN_COUNTERS = (
+    ("rows_held", "moe.rows_held"),
+    ("rows_busiest_share", "moe.rows_busiest_share"),
+    ("rows_even_share", "moe.rows_even_share"),
+)
 
 
 # ``jax.profiler.TraceAnnotation``, bound the first time a span opens

@@ -220,11 +220,10 @@ def test_the_paths_are_counted_once_a_trace():
     jax.jit(lambda p: model.apply({"params": p}, images))(params)
     after = metrics.report()["counters"]
     moved = {k: after.get(k, 0) - before.get(k, 0) for k in (
-        "ssm.path.chunked", "moe.path.dense", "attn.path.gqa",
+        "ssm.path.chunked", "attn.path.gqa",
     )}
     # init traces the model once more than the jit does
-    assert moved == {"ssm.path.chunked": 4, "moe.path.dense": 2,
-                     "attn.path.gqa": 2}
+    assert moved == {"ssm.path.chunked": 4, "attn.path.gqa": 2}
 
 
 # -- the expert layer: shares, drops -----------------------------------------------
