@@ -40,7 +40,12 @@ from blendjax.models.moe import RoutedExperts
 from blendjax.models.transformer import MultiHeadAttention, PatchEmbed
 from blendjax.ops.ssd import ssd_chunked
 from blendjax.precision import default_compute_dtype
-from blendjax.utils.metrics import SCOPE_SSM_MIXER
+from blendjax.utils.metrics import (
+    RESIDUAL_IN_PROJ,
+    SAVED_RESIDUALS,
+    SCOPE_SSM_MIXER,
+    saved_residual,
+)
 
 
 class RMSNorm(nn.Module):
@@ -137,10 +142,10 @@ class Mamba2Mixer(nn.Module):
         a_log = self.param("A_log", _a_log_init, (h,), jnp.float32)
         d = self.param("D", nn.initializers.ones_init(), (h,), jnp.float32)
         with jax.named_scope(SCOPE_SSM_MIXER):
-            zxbcdt = nn.Dense(
+            zxbcdt = saved_residual(nn.Dense(
                 2 * inner + bc + h, use_bias=False, dtype=dtype,
                 param_dtype=jnp.float32, name="in_proj",
-            )(x)
+            )(x), RESIDUAL_IN_PROJ)
             z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + bc], axis=-1)
             # the convolution is a channel's own: x, B and C go through
             # it apart, so that each is written once, as the array the
@@ -221,7 +226,9 @@ class StreamHybrid(nn.Module):
     norm_eps: float = 1e-5
     num_outputs: int = 16
     attn_backend: str = "auto"
-    remat: bool = False  # recompute each layer in the backward pass
+    # recompute each layer in the backward pass but for its large
+    # products' outputs (utils.metrics.SAVED_RESIDUALS), which are kept
+    remat: bool = False
     dtype: Any = None  # None -> the precision policy's compute dtype
 
     @nn.compact
@@ -266,7 +273,12 @@ class StreamHybrid(nn.Module):
             jnp.float32,
         )
         x = x + pos.astype(dtype)
-        layer_cls = nn.remat(HybridLayer) if self.remat else HybridLayer
+        layer_cls = nn.remat(
+            HybridLayer,
+            policy=jax.checkpoint_policies.save_only_these_names(
+                *SAVED_RESIDUALS
+            ),
+        ) if self.remat else HybridLayer
         for i, kind in enumerate(self.pattern):
             x = layer_cls(
                 mixers[kind](), eps=self.norm_eps, dtype=dtype,

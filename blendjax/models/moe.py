@@ -23,10 +23,13 @@ from jax import lax
 from blendjax.precision import default_compute_dtype
 from blendjax.utils.metrics import (
     COUNTERS_COLLECTION,
+    RESIDUAL_EXPERTS_UP,
+    RESIDUAL_SHARED_UP,
     SCOPE_MOE,
     SCOPE_MOE_EXPERTS,
     SCOPE_MOE_ROUTE,
     SCOPE_MOE_SHARED,
+    saved_residual,
 )
 
 
@@ -214,8 +217,10 @@ class RoutedExperts(nn.Module):
                               0.0), axis=1,
                 )                                              # (N, held)
             with jax.named_scope(SCOPE_MOE_EXPERTS):
-                up = jnp.einsum("nc,ecf->nef", tokens, w_up,
-                                preferred_element_type=jnp.float32)
+                up = saved_residual(jnp.einsum(
+                    "nc,ecf->nef", tokens, w_up,
+                    preferred_element_type=jnp.float32,
+                ), RESIDUAL_EXPERTS_UP)
                 y = jnp.einsum(
                     "nef,efc->nc",
                     (gate[:, :, None] * relu2(up)).astype(dtype), w_down,
@@ -223,10 +228,10 @@ class RoutedExperts(nn.Module):
                 )
             if self.shared_width:
                 with jax.named_scope(SCOPE_MOE_SHARED):
-                    hidden = nn.Dense(
+                    hidden = saved_residual(nn.Dense(
                         self.shared_width, use_bias=False, dtype=dtype,
                         param_dtype=jnp.float32, name="shared_up",
-                    )(tokens)
+                    )(tokens), RESIDUAL_SHARED_UP)
                     y = y + nn.Dense(
                         c, use_bias=False, dtype=dtype,
                         param_dtype=jnp.float32, name="shared_down",

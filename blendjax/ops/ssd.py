@@ -72,8 +72,11 @@ from blendjax.ops.attention import (
 from blendjax.utils.metrics import (
     KERNEL_SSD_BWD,
     KERNEL_SSD_FWD,
+    RESIDUAL_SSD_STATES,
+    RESIDUAL_SSD_Y,
     SCOPE_SSD,
     metrics,
+    saved_residual,
 )
 
 _NN = (((1,), (0,)), ((), ()))  # a @ b
@@ -598,6 +601,10 @@ def _ssd_scan(x, dt, a, b, c, d, chunk):
 def _ssd_scan_fwd(x, dt, a, b, c, d, chunk):
     arrays = _kernel_operands(x, dt, a, b, c, d)
     y, states = _scan_fwd(arrays, chunk, save_states=True)
+    # named, so that a ``remat`` policy may keep them: the forward then
+    # runs once a layer
+    y = saved_residual(y, RESIDUAL_SSD_Y)
+    states = saved_residual(states, RESIDUAL_SSD_STATES)
     return y.reshape(x.shape), (x, dt, a, b, c, d, states)
 
 

@@ -62,6 +62,22 @@ STEP_SCOPES = (
     SCOPE_ATTN_CORE, SCOPE_PATCH_EMBED, SCOPE_SSM_MIXER, SCOPE_SSD,
     SCOPE_MOE, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS, SCOPE_MOE_SHARED,
 )
+# The residuals StreamHybrid's layers keep through ``remat``: the outputs
+# of their large products, which the backward reads and plain ``remat``
+# would compute again (``jax.ad_checkpoint.checkpoint_name`` on each, the
+# policy ``save_only_these_names(*SAVED_RESIDUALS)``): the held experts'
+# up-product (float32), the shared expert's hidden pre-activation and the
+# Mamba-2 input projection; and the scan kernel's output with the state
+# each chunk starts from, so that its forward runs once a layer.
+RESIDUAL_EXPERTS_UP = "moe_experts_up"
+RESIDUAL_SHARED_UP = "moe_shared_up"
+RESIDUAL_IN_PROJ = "ssm_in_proj"
+RESIDUAL_SSD_Y = "ssd_y"
+RESIDUAL_SSD_STATES = "ssd_states"
+SAVED_RESIDUALS = (
+    RESIDUAL_EXPERTS_UP, RESIDUAL_SHARED_UP, RESIDUAL_IN_PROJ,
+    RESIDUAL_SSD_Y, RESIDUAL_SSD_STATES,
+)
 # The Pallas decode kernels: each is the ``name=`` of its ``pallas_call``
 # and a scope around the call (inside ``decode``).
 KERNEL_TILE_DECODE_SPATIAL = "tile_decode_spatial"
@@ -87,6 +103,17 @@ SOWN_COUNTERS = (
     ("rows_busiest_share", "moe.rows_busiest_share"),
     ("rows_even_share", "moe.rows_even_share"),
 )
+
+
+def saved_residual(x, name):
+    """``x`` named ``name`` (one of SAVED_RESIDUALS) for the ``remat``
+    policy that keeps it; counted once a trace under
+    ``remat.saved_residuals``. Outside ``remat`` the name changes
+    nothing."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    metrics.count("remat.saved_residuals")
+    return checkpoint_name(x, name)
 
 
 # ``jax.profiler.TraceAnnotation``, bound the first time a span opens

@@ -10,6 +10,8 @@ reason each tolerance is what it is; and the rule that picks between the
 two.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -23,7 +25,7 @@ from blendjax.ops.ssd import (
     ssd_kernel_supported,
     ssd_sequential,
 )
-from blendjax.utils.metrics import metrics
+from blendjax.utils.metrics import SAVED_RESIDUALS, metrics
 
 # float32: the two forms sum the same products in another order (a
 # chunk's 8-32 terms against a running state), a few ulps of values of
@@ -222,6 +224,34 @@ def test_the_kernel_runs_per_batch_shard_of_a_declared_mesh():
     assert _rel(got, want) < 1e-6
     for g, w in zip(got_g, want_g):
         assert _rel(g, w) < 1e-5
+
+
+@pytest.mark.parametrize("policy, forwards", [
+    (None, 2),  # plain remat: the backward runs the forward kernel again
+    (jax.checkpoint_policies.save_only_these_names(*SAVED_RESIDUALS), 1),
+], ids=["plain", "saved"])
+def test_remat_keeps_the_kernels_output_and_states(policy, forwards):
+    """Under ``remat`` with StreamHybrid's policy the kernel's output and
+    the states it starts each chunk from are kept (the names
+    ``ssd_y``, ``ssd_states``), so the forward kernel runs once: the
+    lowered program calls it once, and the value and gradients are the
+    ones without ``remat``."""
+    v = _inputs(300, jnp.float32, dims=ONE_GROUP)
+
+    def loss(*v):
+        return jnp.sum(jnp.sin(ssd_chunked(*v, chunk=128, backend="kernel")))
+
+    want, want_g = jax.jit(jax.value_and_grad(loss, tuple(range(6))))(*v)
+    grad = jax.jit(jax.value_and_grad(
+        jax.checkpoint(loss, policy=policy), tuple(range(6))
+    ))
+    text = grad.lower(*v).as_text()
+    assert len(re.findall(r"call @_scan_fwd(_\d+)?\(", text)) == forwards
+    assert len(re.findall(r"call @_scan_bwd(_\d+)?\(", text)) == 1
+    got, got_g = grad(*v)
+    assert _rel(got, want) < 1e-6
+    for g, w in zip(got_g, want_g):
+        assert _rel(g, w) < 1e-6
 
 
 def test_an_explicit_kernel_refuses_a_shape_it_cannot_block():

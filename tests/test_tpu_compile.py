@@ -19,6 +19,7 @@ and the full-frame palette group.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
 
@@ -340,6 +341,18 @@ def test_ssd_scan_fwd_bwd_compiles(topo, tpu_branches, monkeypatch):
                           "bf16[8,1280", "f32[8,1280")
 
 
+def _nemotron_kwargs(**over):
+    """``nemotron3_nano_30b_a3b``'s model arguments, as the benchmark's
+    configuration file has them, some replaced."""
+    import json
+
+    with open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "configs", "nemotron3_nano_30b_a3b.json",
+    )) as f:
+        return {**json.load(f)["model"]["kwargs"], **over}
+
+
 @pytest.mark.slow  # 5 to 18 s of the TPU compiler on every core, each
 @pytest.mark.parametrize("kind, must_hold", [
     ("M", ("ssd_scan_fwd", "ssd_scan_bwd")),
@@ -357,20 +370,10 @@ def test_hybrid_layer_compiles_at_published_widths(
     heads in front of or behind the kernels), the held experts,
     grouped-query attention through the fused kernels. The whole fused
     step is ``benchmark/compile_rehearsal.py``'s."""
-    import json
-    import os
-
     from blendjax.models import StreamHybrid
 
     monkeypatch.setattr(jax, "device_count", lambda: 1)  # a one-chip machine
-    with open(os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "benchmark", "configs", "nemotron3_nano_30b_a3b.json",
-    )) as f:
-        kwargs = json.load(f)["model"]["kwargs"]
-    model = StreamHybrid(**{
-        **kwargs, "pattern": kind, "remat": False,
-    })
+    model = StreamHybrid(**_nemotron_kwargs(pattern=kind, remat=False))
     placed = SingleDeviceSharding(topo.devices[0])
     images = _sds((B, H, W, C), jnp.uint8, placed)
     params = jax.tree_util.tree_map(
@@ -419,6 +422,69 @@ def test_hybrid_layer_compiles_at_published_widths(
                     "copy", "slice", "pad", "transpose", "broadcast",
                     "concatenate",
                 )), made
+
+
+def _outside_fusions(text):
+    """The compiled program's instructions outside fused computations:
+    one line an operation as the chip runs it (a fusion's inner
+    operations carry the same name stack)."""
+    fused = set(re.findall(r" fusion\(.*calls=(%[\w.-]+)", text))
+    inside = False
+    for ln in text.splitlines():
+        if ln and not ln[0].isspace() and ln.endswith("{"):
+            inside = ln.split(" ")[0] in fused
+        elif not inside:
+            yield ln
+
+
+def test_fused_hybrid_step_recomputes_no_large_product(
+    topo, tpu_branches, monkeypatch
+):
+    """The fused tile step of one Mamba-2 and one expert layer of
+    ``nemotron3_nano_30b_a3b`` at published widths under ``remat`` (a
+    chunk of 2 updates: the scan's body is the same at any): the held
+    experts' up-product ``[9600,8,1856]``, the shared expert's
+    ``[9600,3712]`` and the input projection ``[8,1200,10304]`` are made
+    once an update, in the forward, and the scan's forward kernel runs
+    once; nothing of them is under the recomputed forward
+    (``rematted_computation`` in the name stack), where the rest of the
+    layers is. ``benchmark/compile_rehearsal.py`` sizes the whole step
+    so: 12.56 GB a chip with these residuals kept, 9.40 with plain
+    ``remat``, 17.05 without."""
+    from blendjax.models import StreamHybrid
+    from blendjax.utils.metrics import KERNEL_SSD_FWD, metrics
+
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    one = SingleDeviceSharding(topo.devices[0])
+    model = StreamHybrid(**_nemotron_kwargs(pattern="ME", remat=True))
+    step = make_fused_tile_step(loss_fn=chip_smoke.former_loss)
+    state = _abstract_state(model, one)
+    before = metrics.report()["counters"].get("remat.saved_residuals", 0)
+    lowered = _lower_fused_tile(step, state, 2, one, _tile_plan((16, 32)))
+    # a trace names 5: the expert layer's 2, the Mamba-2 layer's input
+    # projection and the scan kernel's output and states
+    assert metrics.report()["counters"]["remat.saved_residuals"] == before + 5
+    text = _assert_fits_with_kernel(lowered.compile())
+    products = {}
+    for ln in _outside_fusions(text):
+        made = re.match(
+            r"\s*%\S+ = [a-z0-9]+\[(9600,8,1856|9600,3712|8,1200,10304)\]",
+            ln,
+        )
+        name = re.search(r'op_name="([^"]*)"', ln)
+        if made and name and name.group(1).endswith((
+            "moe_experts/nc,ecf->nef/dot_general",
+            "moe_shared/shared_up/dot_general", "in_proj/dot_general",
+        )):
+            products.setdefault(made.group(1), []).append(name.group(1))
+    assert sorted(products) == ["8,1200,10304", "9600,3712", "9600,8,1856"]
+    for names in products.values():
+        assert len(names) == 1 and "transpose(" not in names[0], names
+    kernels = re.findall(
+        rf'%{KERNEL_SSD_FWD}[.\d]* = .*op_name="([^"]*)"', text
+    )
+    assert len(kernels) == 1 and "rematted_computation" not in kernels[0]
+    assert "rematted_computation" in text  # the rest is recomputed
 
 
 def test_gamma_normalize_compiles(topo):
