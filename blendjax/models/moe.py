@@ -23,6 +23,9 @@ from jax import lax
 from blendjax.precision import default_compute_dtype
 from blendjax.utils.metrics import (
     COUNTERS_COLLECTION,
+    RESIDUAL_DENSE_GATE,
+    RESIDUAL_DENSE_OUT,
+    RESIDUAL_DENSE_UP,
     RESIDUAL_EXPERTS_GATE,
     RESIDUAL_EXPERTS_UP,
     RESIDUAL_SHARED_GATE,
@@ -161,16 +164,19 @@ def feed_forward(x, width: int, dtype, gated: bool, prefix: str = "",
 
 class SwiGLU(nn.Module):
     """A dense gated feed-forward layer: :func:`feed_forward` with
-    ``gated``, its matrices ``gate``, ``up`` and ``down``."""
+    ``gated``, its matrices ``gate``, ``up`` and ``down``; the up and the
+    gate product's outputs and its own are named for StreamMoEFormer's
+    ``remat`` policy."""
 
     width: int
     dtype: Any = None
 
     @nn.compact
     def __call__(self, x):
-        return feed_forward(
-            x, self.width, default_compute_dtype(self.dtype), gated=True
-        )
+        return saved_residual(feed_forward(
+            x, self.width, default_compute_dtype(self.dtype), gated=True,
+            residuals=(RESIDUAL_DENSE_UP, RESIDUAL_DENSE_GATE),
+        ), RESIDUAL_DENSE_OUT)
 
 
 class RoutedExperts(nn.Module):

@@ -42,7 +42,11 @@ import jax.numpy as jnp
 from blendjax.models.moe import RoutedExperts, SwiGLU
 from blendjax.models.transformer import MultiHeadAttention, PatchEmbed, RMSNorm
 from blendjax.precision import default_compute_dtype
-from blendjax.utils.metrics import SAVED_RESIDUALS, SCOPE_ATTN_BLOCK
+from blendjax.utils.metrics import (
+    DECODER_RESIDUALS,
+    SAVED_RESIDUALS,
+    SCOPE_ATTN_BLOCK,
+)
 
 
 class DecoderLayer(nn.Module):
@@ -96,7 +100,8 @@ class StreamMoEFormer(nn.Module):
     num_outputs: int = 16
     attn_backend: str = "auto"
     # recompute each layer in the backward pass but for its large
-    # products' outputs (utils.metrics.SAVED_RESIDUALS), which are kept
+    # products' and the attention kernel's outputs
+    # (utils.metrics.SAVED_RESIDUALS and DECODER_RESIDUALS), which are kept
     remat: bool = False
     dtype: Any = None  # None -> the precision policy's compute dtype
 
@@ -122,7 +127,7 @@ class StreamMoEFormer(nn.Module):
         layer_cls = nn.remat(
             DecoderLayer,
             policy=jax.checkpoint_policies.save_only_these_names(
-                *SAVED_RESIDUALS
+                *SAVED_RESIDUALS, *DECODER_RESIDUALS
             ),
         ) if self.remat else DecoderLayer
         for i, kind in enumerate(self.layer_types):

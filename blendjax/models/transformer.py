@@ -32,7 +32,16 @@ from blendjax.ops.image import embed_patches
 from blendjax.parallel.ring import ring_attention
 from blendjax.parallel.ulysses import ulysses_attention
 from blendjax.precision import default_compute_dtype
-from blendjax.utils.metrics import metrics
+from blendjax.utils.metrics import (
+    RESIDUAL_ATTN_GATE,
+    RESIDUAL_ATTN_GATED,
+    RESIDUAL_ATTN_K,
+    RESIDUAL_ATTN_OUT,
+    RESIDUAL_ATTN_Q,
+    RESIDUAL_ATTN_V,
+    metrics,
+    saved_residual,
+)
 
 
 class RMSNorm(nn.Module):
@@ -208,7 +217,10 @@ class MultiHeadAttention(nn.Module):
         query heads that read it, then the three-tensor
         :func:`local_attention` (the fused kernels where ``auto`` takes
         them: one body, K and V repeated in HBM, their gradient summed
-        over the group by the broadcast's transpose)."""
+        over the group by the broadcast's transpose). The products'
+        outputs and the gated heads' output are named for a ``remat``
+        policy (:func:`blendjax.utils.metrics.saved_residual`); one that
+        lists none of them recomputes them."""
         assert not (self.use_ring or self.sp_mode == "ulysses"), (
             "sequence parallelism takes the fused qkv projection"
         )
@@ -216,13 +228,15 @@ class MultiHeadAttention(nn.Module):
         h, kv = self.num_heads, self.num_kv_heads or self.num_heads
         assert h % kv == 0, f"{kv} key/value heads do not divide {h}"
 
-        def project(name, heads):
-            return nn.DenseGeneral(
+        def project(name, heads, saved):
+            return saved_residual(nn.DenseGeneral(
                 (heads, d), axis=-1, use_bias=self.use_bias, dtype=dtype,
                 param_dtype=jnp.float32, name=name,
-            )(x)
+            )(x), saved)
 
-        q, k, v = project("q", h), project("k", kv), project("v", kv)
+        q = project("q", h, RESIDUAL_ATTN_Q)
+        k = project("k", kv, RESIDUAL_ATTN_K)
+        v = project("v", kv, RESIDUAL_ATTN_V)
         if self.qk_norm:
             q = RMSNorm(self.norm_eps, dtype=dtype, name="q_norm")(q)
             k = RMSNorm(self.norm_eps, dtype=dtype, name="k_norm")(k)
@@ -235,12 +249,16 @@ class MultiHeadAttention(nn.Module):
                             backend=self.attn_backend, window=self.window)
         o = o.astype(dtype).reshape(b, t, h * d)
         if self.output_gate:
-            gate = nn.Dense(h * d, use_bias=self.use_bias, dtype=dtype,
-                            param_dtype=jnp.float32, name="gate")(x)
-            o = (o.astype(jnp.float32) * nn.sigmoid(
-                gate.astype(jnp.float32))).astype(dtype)
-        return nn.Dense(c, use_bias=self.use_bias, dtype=dtype,
-                        param_dtype=jnp.float32, name="proj")(o)
+            gate = saved_residual(nn.Dense(
+                h * d, use_bias=self.use_bias, dtype=dtype,
+                param_dtype=jnp.float32, name="gate",
+            )(x), RESIDUAL_ATTN_GATE)
+            o = saved_residual((o.astype(jnp.float32) * nn.sigmoid(
+                gate.astype(jnp.float32))).astype(dtype), RESIDUAL_ATTN_GATED)
+        return saved_residual(nn.Dense(
+            c, use_bias=self.use_bias, dtype=dtype, param_dtype=jnp.float32,
+            name="proj",
+        )(o), RESIDUAL_ATTN_OUT)
 
 
 class Block(nn.Module):

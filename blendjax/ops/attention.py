@@ -104,8 +104,11 @@ from blendjax.utils.metrics import (
     KERNEL_FLASH_FWD,
     KERNEL_FLASH_WINDOW_BWD,
     KERNEL_FLASH_WINDOW_FWD,
+    RESIDUAL_ATTN_LSE,
+    RESIDUAL_ATTN_O,
     SCOPE_ATTN_CORE,
     metrics,
+    saved_residual,
 )
 
 # `auto` takes the fused kernel when one call would otherwise write at
@@ -404,7 +407,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, *rest, d, scale,
     """One query block's part of dQ, dK and dV. ``rest``: the outputs,
     the f32 dK/dV accumulators and the K/V padding scratch. With three
     tensors the outputs are ``dq`` (a block of rows), ``dk`` and ``dv``
-    (whole). ``packed``: one array shaped like the ``qkv`` product, a
+    (whole), and ``lse_ref`` is a block of rows of the log-sum-exp at one
+    column a head, (block_q, H), of which the block's heads are picked.
+    ``packed``: ``lse_ref`` is the forward's lane-wide one, and the
+    gradient is one array shaped like the ``qkv`` product, a
     batch row of it resident over the head blocks and query blocks —
     each gradient is stored at its own column block of it, so the
     array leaves the kernel as the projection's transposes read it —
@@ -438,12 +444,21 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, *rest, d, scale,
     k, v = _kv_in_vmem(k_ref, v_ref, kv_pads, i == 0, slab)
     o, lse = o_ref[0].astype(jnp.float32), lse_ref[0]
     heads = _head_lanes(d)
+    if packed:
+        def lse_of(h):
+            return lse[:, h * d:h * d + 1]
+    else:
+        column = lax.broadcasted_iota(jnp.int32, lse.shape, 1)
+
+        def lse_of(h):
+            return jnp.sum(jnp.where(column == j * len(heads) + h, lse, 0.0),
+                           axis=1, keepdims=True)
     dqs = []
     for h, lanes in enumerate(heads):
         qh, doh = _only(q, lanes), _only(do_ref[0], lanes)
         s = _scores(qh, k, scale=scale, t_kv=k_ref.shape[1], causal=causal,
                     row0=i * q.shape[0], **band)
-        p = jnp.exp(s - lse[:, h * d:h * d + 1])
+        p = jnp.exp(s - lse_of(h))
         dp = lax.dot_general(doh, v, _NT, preferred_element_type=jnp.float32)
         di = jnp.sum(o * doh.astype(jnp.float32), axis=1, keepdims=True)
         ds = (p * (dp - di) * scale).astype(q.dtype)
@@ -587,13 +602,18 @@ def _flash_bwd(q, k, v, o, lse, do, d, causal, scale, block_q, packed=False,
         out_specs = [launch.rows, launch.whole, launch.whole]
         out_shape = [jax.ShapeDtypeStruct(x.shape, x.dtype)
                      for x in (q, k, v)]
+        # the log-sum-exp at one column a head: all of a block's rows
+        lse_rows = pl.BlockSpec((1, block_q, lse.shape[2]),
+                                lambda b, j, i: (b, i, 0))
     # a custom_vjp's backward is traced outside the forward's scope
     with jax.named_scope(SCOPE_ATTN_CORE):
         return pl.pallas_call(
             functools.partial(_bwd_kernel, d=d, scale=scale, causal=causal,
                               packed=packed, window=window),
             grid=launch.grid,
-            in_specs=launch.qkv + [launch.rows] * 3,
+            in_specs=launch.qkv + [
+                launch.rows, launch.rows if packed else lse_rows, launch.rows
+            ],
             out_specs=out_specs,
             out_shape=out_shape,
             scratch_shapes=[
@@ -624,6 +644,13 @@ def _flash_core(q, k, v, d, causal, scale, block_q, window):
 
 def _flash_core_fwd(q, k, v, d, causal, scale, block_q, window):
     o, lse = _flash_fwd(q, k, v, d, causal, scale, block_q, window=window)
+    # named, so that a ``remat`` policy may keep them: the forward then
+    # runs once a layer. The kernel writes a head's log-sum-exp across
+    # its d lanes; the backward reads one column a head, (B, T, H)
+    o = saved_residual(o, RESIDUAL_ATTN_O)
+    lse = saved_residual(
+        lax.slice_in_dim(lse, 0, lse.shape[-1], d, axis=-1), RESIDUAL_ATTN_LSE
+    )
     return o, (q, k, v, o, lse)
 
 

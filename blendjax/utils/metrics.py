@@ -87,6 +87,42 @@ SAVED_RESIDUALS = (
     RESIDUAL_SSD_Y, RESIDUAL_SSD_STATES, RESIDUAL_EXPERTS_GATE,
     RESIDUAL_SHARED_GATE,
 )
+# What StreamMoEFormer's layers keep beside SAVED_RESIDUALS (its policy
+# is ``save_only_these_names(*SAVED_RESIDUALS, *DECODER_RESIDUALS)``), so
+# that the backward recomputes only elementwise work: the three-tensor
+# attention kernel's output and its log-sum-exp (one column a head), so
+# that the kernel's forward runs once a layer; the q, k, v and output
+# gate projections' products, before the q/k norms, RoPE, the K/V repeat
+# and the gate's sigmoid, which are recomputed from them; the gated heads'
+# output, which ``proj`` and its weight gradient read; the dense SwiGLU
+# layers' up and gate products; and the output of the attention sub-block
+# and of a dense one, which its sandwich post-norm reads (else the
+# backward computes ``proj`` and the dense down-product again for it).
+# Where an elementwise value is left to the backward, XLA may fold it
+# into a product's operand and compute it again for every tile of the
+# product: so the gated output is kept, and an expert layer's output is
+# not, since then the down-weight gradient's product read the float32 up
+# and gate products tile by tile, which cost more than the down-product
+# it saves (measured on one TPU v5e). StreamHybrid's one attention layer
+# names its kernel's two, its q, k, v and its output as well, which its
+# policy does not keep.
+RESIDUAL_ATTN_O = "attn_o"
+RESIDUAL_ATTN_LSE = "attn_lse"
+RESIDUAL_ATTN_Q = "attn_q"
+RESIDUAL_ATTN_K = "attn_k"
+RESIDUAL_ATTN_V = "attn_v"
+RESIDUAL_ATTN_GATE = "attn_gate"
+RESIDUAL_ATTN_GATED = "attn_gated"
+RESIDUAL_DENSE_UP = "dense_up"
+RESIDUAL_DENSE_GATE = "dense_gate"
+RESIDUAL_ATTN_OUT = "attn_out"
+RESIDUAL_DENSE_OUT = "dense_out"
+DECODER_RESIDUALS = (
+    RESIDUAL_ATTN_O, RESIDUAL_ATTN_LSE, RESIDUAL_ATTN_Q, RESIDUAL_ATTN_K,
+    RESIDUAL_ATTN_V, RESIDUAL_ATTN_GATE, RESIDUAL_ATTN_GATED,
+    RESIDUAL_DENSE_UP, RESIDUAL_DENSE_GATE, RESIDUAL_ATTN_OUT,
+    RESIDUAL_DENSE_OUT,
+)
 # The Pallas decode kernels: each is the ``name=`` of its ``pallas_call``
 # and a scope around the call (inside ``decode``).
 KERNEL_TILE_DECODE_SPATIAL = "tile_decode_spatial"
@@ -120,8 +156,8 @@ SOWN_COUNTERS = (
 
 
 def saved_residual(x, name):
-    """``x`` named ``name`` (one of SAVED_RESIDUALS) for the ``remat``
-    policy that keeps it; counted once a trace under
+    """``x`` named ``name`` (one of SAVED_RESIDUALS or DECODER_RESIDUALS)
+    for the ``remat`` policy that keeps it; counted once a trace under
     ``remat.saved_residuals``. Outside ``remat`` the name changes
     nothing."""
     from jax.ad_checkpoint import checkpoint_name

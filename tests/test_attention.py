@@ -27,7 +27,11 @@ from blendjax.ops.attention import (  # noqa: E402
     scores_residual_bytes,
 )
 from blendjax.parallel.ring import reference_attention  # noqa: E402
-from blendjax.utils.metrics import metrics  # noqa: E402
+from blendjax.utils.metrics import (  # noqa: E402
+    RESIDUAL_ATTN_LSE,
+    RESIDUAL_ATTN_O,
+    metrics,
+)
 
 
 def _qkv(t=128, b=2, h=2, d=64, dtype=jnp.float32, t_kv=None):
@@ -749,6 +753,69 @@ def test_windowed_kernel_matches_reference(monkeypatch, t, window,
     assert names == ({"flash_attention_window_fwd",
                       "flash_attention_window_bwd"} if banded
                      else {"flash_attention_fwd", "flash_attention_bwd"})
+
+
+@pytest.mark.parametrize("t, h, kv, window, tile_elems", [
+    (256, 2, 2, None, None), (512, 2, 2, 100, 128 * 256),
+    (256, 4, 2, 64, None),
+], ids=["causal", "windowed", "grouped-query"])
+def test_kept_kernel_outputs_give_the_same_gradients(monkeypatch, t, h, kv,
+                                                     window, tile_elems):
+    """Under ``jax.checkpoint`` with a policy that keeps the three-tensor
+    kernel's output and its log-sum-exp (one column a head, which the
+    backward kernel reads as it is) the forward kernel runs once where
+    plain ``jax.checkpoint`` runs it twice; the gradients are the same
+    bits as without ``jax.checkpoint`` and equal
+    ``reference_attention``'s: causal, windowed (four query blocks of
+    128 rows, each on its slab of 384 of the 512 keys) and grouped-query
+    (K/V heads repeated for two query heads each)."""
+    if tile_elems:
+        monkeypatch.setattr(A, "FLASH_TILE_ELEMS", tile_elems)
+    q, k, v = _qkv(t=t, b=1, h=h)
+    k, v = k[:, :, :kv], v[:, :, :kv]
+    w = jax.random.normal(jax.random.key(9), q.shape, jnp.float32)
+
+    def attend(backend):
+        def fn(q, k, v):
+            k, v = (jnp.repeat(a, h // kv, axis=2) for a in (k, v))
+            if backend == "xla":
+                return reference_attention(q, k, v, causal=True,
+                                           window=window)
+            return local_attention(q, k, v, causal=True, backend="flash",
+                                   window=window)
+
+        return fn
+
+    def grad(fn, **remat):
+        def loss(q, k, v):
+            return jnp.sum(fn(q, k, v).astype(jnp.float32) * w)
+
+        if remat:
+            loss = jax.checkpoint(loss, **remat)
+        return jax.jit(jax.value_and_grad(loss, (0, 1, 2)))
+
+    kept = jax.checkpoint_policies.save_only_these_names(
+        RESIDUAL_ATTN_O, RESIDUAL_ATTN_LSE
+    )
+    fwd = "flash_attention_fwd" if window is None else (
+        "flash_attention_window_fwd"
+    )
+    for policy, forwards in ((kept, 1), (None, 2)):
+        calls = [name for name, _ in _pallas_calls(
+            grad(attend("flash"), policy=policy), q, k, v
+        )]
+        assert calls.count(fwd) == forwards, calls
+        assert len(calls) == forwards + 1, calls
+    loss, grads = grad(attend("flash"), policy=kept)(q, k, v)
+    plain_loss, plain_grads = grad(attend("flash"))(q, k, v)
+    assert loss == plain_loss
+    for got, plain in zip(grads, plain_grads):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(plain))
+    want, want_grads = grad(attend("xla"))(q, k, v)
+    np.testing.assert_allclose(loss, want, rtol=1e-5)
+    for got, ref in zip(grads, want_grads):
+        assert got.shape == ref.shape
+        np.testing.assert_allclose(got, ref, atol=5e-6)
 
 
 def test_a_window_narrows_the_reference_to_its_band():

@@ -182,25 +182,46 @@ def test_a_lower_precision_fails_the_tolerance(reference, monkeypatch):
     assert _rel(got, want) > 10 * VALUE_TOL
 
 
+# the names one trace of the model's gradient gives ``saved_residual``
+# (``remat.saved_residuals``): a layer's q, k, v and gate products, its
+# gated heads' output and its attention output (6 x 6), the dense layers'
+# up, gate and output (2 x 3), the expert layers' experts and shared
+# expert, up and gate (4 x 4); and on the fused kernels each layer's
+# kernel output and log-sum-exp (6 x 2)
+SAVED_A_TRACE = {"xla": 58, "flash": 70}
+
+
 def test_remat_changes_neither_the_tree_nor_the_numbers():
+    """``remat`` under StreamMoEFormer's policy (the layers keep their
+    products' and the attention kernel's outputs), on the XLA core and on
+    the fused kernels: the same tree, and the output and every leaf's
+    gradient as without it; the kept values are named once a trace."""
     images = _images()
-    plain, params = _seeded()
-    again, params_again = _seeded(remat=True)
-    assert jax.tree_util.tree_structure(params) == (
-        jax.tree_util.tree_structure(params_again)
-    )
-    for a, b in zip(jax.tree_util.tree_leaves(params),
-                    jax.tree_util.tree_leaves(params_again)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    values = [
-        _out_and_grad(lambda p, m=m: m.apply({"params": p}, images))(params)
-        for m in (plain, again)
-    ]
-    np.testing.assert_allclose(values[0][0], values[1][0], rtol=1e-5,
-                               atol=1e-6)
-    for a, b in zip(*(jax.tree_util.tree_leaves(v[1]) for v in values)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+    for backend, saved in SAVED_A_TRACE.items():
+        plain, params = _seeded(attn_backend=backend)
+        again, params_again = _seeded(remat=True, attn_backend=backend)
+        assert jax.tree_util.tree_structure(params) == (
+            jax.tree_util.tree_structure(params_again)
+        )
+        for a, b in zip(jax.tree_util.tree_leaves(params),
+                        jax.tree_util.tree_leaves(params_again)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        values = []
+        for m in (plain, again):
+            before = metrics.report()["counters"].get(
+                "remat.saved_residuals", 0
+            )
+            values.append(_out_and_grad(
+                lambda p, m=m: m.apply({"params": p}, images)
+            )(params))
+            assert metrics.report()["counters"][
+                "remat.saved_residuals"
+            ] == before + saved, backend
+        np.testing.assert_allclose(values[0][0], values[1][0], rtol=1e-5,
                                    atol=1e-6)
+        for a, b in zip(*(jax.tree_util.tree_leaves(v[1]) for v in values)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=1e-6)
 
 
 def test_a_sliding_layer_is_counted_where_the_window_is_shorter():

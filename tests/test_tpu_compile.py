@@ -99,9 +99,10 @@ def _abstract_state(model, sharding):
     )
 
 
-def _tile_plan(tile, capacity=None):
-    """The packed layout + decode plan of one tile batch at ``REAL``
-    geometry, as the pipeline's host stage hands it to the fused step —
+def _tile_plan(tile, capacity=None, batch=B):
+    """The packed layout + decode plan of one tile batch of ``batch``
+    frames at ``REAL`` geometry, as the pipeline's host stage hands it to
+    the fused step —
     the wire shape the cube producers ship (full-channel tiles as 4-bit
     per-frame palette indices, ``REAL.tile_pal_bits``):
     ``(row_bytes, spec, names, geoms, ref_shape)``."""
@@ -110,13 +111,14 @@ def _tile_plan(tile, capacity=None):
     n = (H // th) * (W // tw)
     bits = int(REAL.tile_pal_bits)
     buf, spec = T.pack_fields({
-        "image" + T.TILEIDX_SUFFIX: np.zeros((B, cap), np.int32),
+        "image" + T.TILEIDX_SUFFIX: np.zeros((batch, cap), np.int32),
         "image" + T.TILEPAL_SUFFIXES[bits]: np.zeros(
-            (B, cap, th * tw * bits // 8), np.uint8
+            (batch, cap, th * tw * bits // 8), np.uint8
         ),
-        "image" + T.PALETTE_SUFFIX: np.zeros((B, 1 << bits, C), np.uint8),
-        "xy": np.zeros((B, 8, 2), np.float32),
-        "frameid": np.zeros((B,), np.int64),
+        "image" + T.PALETTE_SUFFIX: np.zeros((batch, 1 << bits, C),
+                                             np.uint8),
+        "xy": np.zeros((batch, 8, 2), np.float32),
+        "frameid": np.zeros((batch,), np.int64),
     })
     geoms = (tuple(T.tileshape_wire(H, W, C, (th, tw))),)
     return buf.shape[0], spec, ("image",), geoms, (n, th, tw, C)
@@ -173,6 +175,15 @@ def _under_attn_core(text, *ops):
         ln.strip()[:200] for ln in text.splitlines()
         if SCOPE_ATTN_CORE in ln and any(f" {op}(" in ln for op in ops)
     ]
+
+
+def _assert_only_the_kept_lse_is_sliced(text, lse):
+    """No pad under ``attn_core`` and one slice, the kept log-sum-exp's
+    column a head (``f32[lse]``) out of the forward kernel's lane-wide
+    one: no operand is padded or sliced around the kernels."""
+    assert not _under_attn_core(text, "pad")
+    slices = _under_attn_core(text, "slice")
+    assert len(slices) == 1 and f" = f32[{lse}]{{" in slices[0], slices
 
 
 @pytest.mark.parametrize(
@@ -252,8 +263,9 @@ def test_auto_attention_at_the_benchmark_shape_is_fused(
 ):
     """(8, 1200, 12, 64) bf16 through ``auto`` on a one-chip machine:
     both kernels inside by name, the backward's under the ``attn_core``
-    scope too, no pad and no slice around them (1,200 tokens run as
-    1,200) and less temporary memory than the materialised path."""
+    scope too, no pad and no slice of an operand around them (1,200
+    tokens run as 1,200) and less temporary memory than the materialised
+    path."""
     monkeypatch.setattr(jax, "device_count", lambda: 1)
     from blendjax.utils.metrics import (
         KERNEL_FLASH_BWD,
@@ -276,7 +288,7 @@ def test_auto_attention_at_the_benchmark_shape_is_fused(
     )
     assert SCOPE_ATTN_CORE in fwd and "transpose(" not in fwd, fwd
     assert SCOPE_ATTN_CORE in bwd and "transpose(jvp(" in bwd, bwd
-    assert not _under_attn_core(text, "pad", "slice")
+    _assert_only_the_kept_lse_is_sliced(text, "8,1200,12")
     xla = _attn_loss("xla").lower(q, q, q).compile()
     _assert_fits_with_kernel(xla, kernel=False)
     assert (
@@ -450,7 +462,7 @@ def test_windowed_flash_attention_compiles(topo, tpu_branches):
     assert f"%{KERNEL_FLASH_WINDOW_FWD}" in text
     assert f"%{KERNEL_FLASH_WINDOW_BWD}" in text
     assert "%flash_attention_fwd" not in text
-    assert not _under_attn_core(text, "pad", "slice")
+    _assert_only_the_kept_lse_is_sliced(text, "2,4800,32")
 
 
 def _trinity_kwargs(**over):
@@ -565,6 +577,76 @@ def test_fused_hybrid_step_recomputes_no_large_product(
     )
     assert len(kernels) == 1 and "rematted_computation" not in kernels[0]
     assert "rematted_computation" in text  # the rest is recomputed
+
+
+def test_fused_moe_former_step_recomputes_no_large_product(
+    topo, tpu_branches, monkeypatch
+):
+    """The fused tile step of two layers of ``trinity_mini`` at published
+    widths under its ``remat`` (``S F``, the first dense; 2 x 4,800
+    tokens, a chunk of 2 updates): each layer's forward kernel
+    (``flash_attention_window_fwd``, ``flash_attention_fwd``) runs once,
+    outside the recomputed forward; the q, k, v, gate and ``proj``
+    products and the dense layer's up, gate and down products are made
+    once an update, in the forward; and of the layers' products only the
+    expert layer's router and its experts' and shared expert's
+    down-products (whose output is not kept) are under
+    ``rematted_computation``, where the norms, RoPE, the K/V repeat and
+    the activations are. ``benchmark/compile_rehearsal.py`` sizes the
+    whole step (6 layers) so: 14.65 GB a chip with these kept, 12.50
+    with the experts' products alone kept."""
+    from blendjax.models import StreamMoEFormer
+    from blendjax.utils.metrics import (
+        KERNEL_FLASH_FWD,
+        KERNEL_FLASH_WINDOW_FWD,
+        metrics,
+    )
+
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    one = SingleDeviceSharding(topo.devices[0])
+    model = StreamMoEFormer(**_trinity_kwargs(
+        layer_types="SF", num_dense_layers=1, remat=True
+    ))
+    step = make_fused_tile_step(loss_fn=chip_smoke.former_loss)
+    state = _abstract_state(model, one)
+    before = metrics.report()["counters"].get("remat.saved_residuals", 0)
+    lowered = _lower_fused_tile(step, state, 2, one,
+                                _tile_plan((16, 32), batch=2))
+    # a trace names 23: each layer's q, k, v and gate, gated output,
+    # attention output and its kernel's output and log-sum-exp (2 x 8),
+    # the dense layer's up, gate and output, the expert layer's experts
+    # and shared expert, up and gate
+    assert metrics.report()["counters"]["remat.saved_residuals"] == (
+        before + 23
+    )
+    text = _assert_fits_with_kernel(lowered.compile())
+    for kernel in (KERNEL_FLASH_WINDOW_FWD, KERNEL_FLASH_FWD):
+        calls = re.findall(rf'%{kernel}[.\d]* = .*op_name="([^"]*)"', text)
+        assert len(calls) == 1, (kernel, calls)
+        assert "rematted_computation" not in calls[0], kernel
+    products = [
+        re.search(r'op_name="([^"]*)"', ln).group(1)
+        for ln in text.splitlines()
+        if re.search(r" (dot|convolution)\(", ln) and "op_name=" in ln
+    ]
+    grouped = ("attn._grouped/q", "attn._grouped/k", "attn._grouped/v",
+               "attn._grouped/gate", "attn._grouped/proj")
+    for layer, made_here in ((0, grouped + ("ffn/up", "ffn/gate",
+                                            "ffn/down")),
+                             (1, grouped)):
+        for product in made_here:
+            made = [
+                name for name in products if f"/layer{layer}/" in name
+                and name.endswith(f"/{product}/dot_general")
+                and "transpose(" not in name
+            ]
+            assert len(made) == 1, (layer, product, made)
+    recomputed = [n for n in products if "rematted_computation" in n]
+    assert recomputed and all(n.endswith(tuple(
+        f"/layer1/ffn/moe/{part}/dot_general" for part in (
+            "moe_route", "moe_experts/nef,efc->nc", "moe_shared/shared_down"
+        )
+    )) for n in recomputed), recomputed
 
 
 def test_gamma_normalize_compiles(topo):
