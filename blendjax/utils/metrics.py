@@ -132,17 +132,23 @@ DECODER_RESIDUALS = (
 # policy is ``save_only_these_names(*DECODER_RESIDUALS,
 # *DELTA_RESIDUALS)``): a gated delta-rule mixer's q, k, v and output
 # gate products, before their convolutions, norms and activations, which
-# are recomputed from them; the recurrence's output; and the mixer's
-# output, which the post-norm reads.
+# are recomputed from them; the recurrence's output; the state each chunk
+# starts from and each chunk's (I + A)^-1, which the recurrence kernel's
+# forward writes for its backward (so that the forward kernel runs once
+# a layer and the backward inverts nothing); and the mixer's output,
+# which the post-norm reads.
 RESIDUAL_GDN_Q = "gdn_q"
 RESIDUAL_GDN_K = "gdn_k"
 RESIDUAL_GDN_V = "gdn_v"
 RESIDUAL_GDN_GATE = "gdn_gate"
 RESIDUAL_GDN_O = "gdn_o"
+RESIDUAL_GDN_STATES = "gdn_states"
+RESIDUAL_GDN_INVERSE = "gdn_inverse"
 RESIDUAL_GDN_OUT = "gdn_out"
 DELTA_RESIDUALS = (
     RESIDUAL_GDN_Q, RESIDUAL_GDN_K, RESIDUAL_GDN_V, RESIDUAL_GDN_GATE,
-    RESIDUAL_GDN_O, RESIDUAL_GDN_OUT,
+    RESIDUAL_GDN_O, RESIDUAL_GDN_STATES, RESIDUAL_GDN_INVERSE,
+    RESIDUAL_GDN_OUT,
 )
 # The Pallas decode kernels: each is the ``name=`` of its ``pallas_call``
 # and a scope around the call (inside ``decode``).
@@ -158,10 +164,14 @@ KERNEL_FLASH_WINDOW_BWD = "flash_attention_window_bwd"
 # The state-space scan's forward and backward (inside ``ssd``).
 KERNEL_SSD_FWD = "ssd_scan_fwd"
 KERNEL_SSD_BWD = "ssd_scan_bwd"
+# The gated delta rule's forward and backward (inside ``delta_rule``).
+KERNEL_DELTA_RULE_FWD = "delta_rule_fwd"
+KERNEL_DELTA_RULE_BWD = "delta_rule_bwd"
 KERNEL_NAMES = (
     KERNEL_TILE_DECODE_SPATIAL, KERNEL_TILE_DECODE_SCATTER,
     KERNEL_FLASH_FWD, KERNEL_FLASH_BWD, KERNEL_FLASH_WINDOW_FWD,
     KERNEL_FLASH_WINDOW_BWD, KERNEL_SSD_FWD, KERNEL_SSD_BWD,
+    KERNEL_DELTA_RULE_FWD, KERNEL_DELTA_RULE_BWD,
 )
 # Counts made inside the step: the flax collection a model's layers sow
 # integers into (models/moe.py ``RoutedExperts``), which the step

@@ -14,6 +14,7 @@ neither tree nor numbers, and ``layer_types`` takes L and F alone.
 
 import importlib.util
 import os
+import re
 
 import flax.linen as nn
 import numpy as np
@@ -175,6 +176,33 @@ def test_remat_changes_neither_the_tree_nor_the_numbers(dtype):
     for a, b in zip(jax.tree_util.tree_leaves(g0),
                     jax.tree_util.tree_leaves(g1)):
         assert _rel(b, a) < 1e-4
+
+
+def test_remat_runs_the_recurrence_kernel_once_a_layer(monkeypatch):
+    """With the kernel pair taken (forced here; ``auto`` takes it on a
+    TPU), the ``remat`` policy keeps each ``L`` layer's recurrence output
+    and chunk states: the lowered step calls the forward kernel once a
+    layer and the backward once, and the output and every leaf's gradient
+    are the XLA form's."""
+    import blendjax.ops.delta_rule as rule
+
+    images = _images()
+    model, params = _seeded(remat=True)
+
+    def forward(p):
+        return model.apply({"params": p}, images)
+
+    want, want_g = _out_and_grad(forward)(params)
+    monkeypatch.setattr(rule, "auto_picks_kernel", lambda *a, **kw: True)
+    step = _out_and_grad(forward)
+    text = step.lower(params).as_text()
+    assert len(re.findall(r"call @_rule_fwd(_\d+)?\(", text)) == 3
+    assert len(re.findall(r"call @_rule_bwd(_\d+)?\(", text)) == 3
+    got, got_g = step(params)
+    assert _rel(got, want) < VALUE_TOL
+    for g, w in zip(jax.tree_util.tree_leaves(got_g),
+                    jax.tree_util.tree_leaves(want_g)):
+        assert _rel(g, w) < GRAD_TOL
 
 
 @pytest.mark.parametrize("over, message", [

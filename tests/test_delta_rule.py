@@ -9,17 +9,28 @@ decays, from a given state, and with bf16 operands; the reason each
 tolerance is what it is.
 """
 
+import re
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
+from blendjax.ops.attention import batch_sharded_over
 from blendjax.ops.delta_rule import (
+    auto_picks_kernel,
+    delta_rule_kernel_supported,
     gated_delta_rule_chunked,
     gated_delta_rule_sequential,
 )
-from blendjax.utils.metrics import metrics
+from blendjax.utils.metrics import (
+    DECODER_RESIDUALS,
+    DELTA_RESIDUALS,
+    RESIDUAL_GDN_O,
+    metrics,
+    saved_residual,
+)
 
 # float32: the two forms sum the same products in another order (a
 # chunk's triangular solve and its 8-64 terms against a running state):
@@ -165,3 +176,252 @@ def test_the_path_and_its_scope_are_named_once_a_trace():
     ).lower(*args).as_text(debug_info=True)
     assert metrics.report()["counters"]["gdn.path.chunked"] == before + 1
     assert "delta_rule" in text
+
+
+# -- the kernel pair (interpreter mode here; what the chip's compiler says
+# of it is tests/test_tpu_compile.py's) ---------------------------------------
+
+# The cell's head widths, three heads (a group of three, or of one when
+# the blocking batches fewer).
+WIDE = dict(heads=3, key_dim=96, value_dim=192)
+# float32 at K 96, V 192 against the recurrence token by token: every
+# contraction sums 12 times the terms of the tiny widths', and the kernel
+# inverts (I + A) by doubling blocks where the XLA form substitutes:
+# measured up to 2.1e-6 of the largest value (the XLA form 2.0e-6 on the
+# same inputs) and 1.5e-6 of the largest entry of a gradient against the
+# XLA form's autodiff. So 1e-5 for both, five times the readings.
+WIDE_F32_TOL = 1e-5
+# bf16 operands: the kernel rounds where the XLA form rounds, so its
+# distance from the float32 recurrence on the same rounded inputs is the
+# XLA form's (measured equal to three digits, 2.3e-3 to 4.2e-3 of the
+# largest value) and its gradients as close (measured 1.0e-4 to 5.7e-3 of
+# a gradient's largest entry, the XLA form 8.9e-5 to 7.3e-3); a value the
+# XLA form keeps in float32 rounded to bf16 would double it.
+WIDE_BF16_GRAD_TOL = 2e-2
+# g = 0: no decay at all, beside DECAYS' weak and strong
+WIDE_DECAYS = {"none": None, **DECAYS}
+# beta = 2 sigmoid(z): near 0 (the state barely moves) and near 2 (the
+# eigenvalues of I - beta k k^T near -1)
+WIDE_BETAS = {"near_zero": (-4.0, 1.0), "near_two": BETAS["near_two"]}
+
+
+def _wide(t, batch=2, seed=0, beta="near_two", decay="weak",
+          dtype=jnp.float32, heads=3, key_dim=96, value_dim=192):
+    keys = jax.random.split(jax.random.key(seed), 5)
+
+    def unit(key, width):
+        x = jax.random.normal(key, (batch, t, heads, width))
+        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+    q = unit(keys[0], key_dim) * key_dim ** -0.5
+    k = unit(keys[1], key_dim)
+    v = jax.random.normal(keys[2], (batch, t, heads, value_dim))
+    if WIDE_DECAYS[decay] is None:
+        g = jnp.zeros((batch, t, heads))
+    else:
+        g = -jax.nn.softplus(
+            jax.random.normal(keys[3], (batch, t, heads)) + DECAYS[decay])
+    mean, spread = WIDE_BETAS[beta]
+    b = 2.0 * jax.nn.sigmoid(
+        mean + spread * jax.random.normal(keys[4], (batch, t, heads)))
+    return (q.astype(dtype), k.astype(dtype), v.astype(dtype), g, b)
+
+
+def _grads(form, args):
+    return jax.jit(jax.grad(_weighted(form), tuple(range(5))))(*args)
+
+
+def _kernel(*args, chunk=64, **kwargs):
+    return gated_delta_rule_chunked(*args, chunk=chunk, backend="kernel",
+                                    **kwargs)
+
+
+@pytest.mark.parametrize("beta", sorted(WIDE_BETAS))
+@pytest.mark.parametrize("decay", sorted(WIDE_DECAYS))
+@pytest.mark.parametrize("t", [
+    128,  # two whole chunks
+    150,  # a ragged last chunk of 22, masked in the kernel
+    40,   # shorter than a chunk: no carried state
+])
+def test_the_kernel_is_the_recurrence(t, decay, beta):
+    """The kernel pair at the cell's head widths: the output and the final
+    state against the recurrence token by token, every input's gradient
+    against the XLA form's autodiff, float32."""
+    args = _wide(t, beta=beta, decay=decay)
+    want_o, want_s = jax.jit(gated_delta_rule_sequential)(*args)
+    got_o, got_s = jax.jit(_kernel)(*args)
+    assert got_o.shape == want_o.shape and got_o.dtype == jnp.float32
+    assert got_s.shape == want_s.shape == (2, 3, 96, 192)
+    assert _rel(got_o, want_o) < WIDE_F32_TOL
+    assert _rel(got_s, want_s) < WIDE_F32_TOL
+    want_g = _grads(lambda *a: gated_delta_rule_chunked(
+        *a, chunk=64, backend="xla"), args)
+    for name, g, w in zip("q k v g beta".split(), _grads(_kernel, args),
+                          want_g):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        assert np.abs(np.asarray(w)).max() > 0, name
+        assert _rel(g, w) < WIDE_F32_TOL, name
+
+
+@pytest.mark.parametrize("decay", sorted(WIDE_DECAYS))
+def test_the_kernel_keeps_the_xla_forms_precision_at_bf16(decay):
+    """bf16 q, k, v as the mixer passes them: the kernel's output is as
+    close to the float32 recurrence on the same rounded inputs as the XLA
+    form's, and so is each gradient, float32 autodiff of the XLA form on
+    those inputs the truth."""
+    args = _wide(150, decay=decay, dtype=jnp.bfloat16, seed=2)
+    wide = tuple(x.astype(jnp.float32) for x in args)
+    want, _ = jax.jit(gated_delta_rule_sequential)(*wide)
+    got, _ = jax.jit(_kernel)(*args)
+    xla, _ = jax.jit(lambda *a: gated_delta_rule_chunked(
+        *a, chunk=64, backend="xla"))(*args)
+    assert got.dtype == jnp.float32
+    assert F32_TOL[0] < _rel(got, want) < 1.1 * _rel(xla, want)
+    assert _rel(got, want) < BF16_TOL
+    truth = _grads(lambda *a: gated_delta_rule_chunked(
+        *a, chunk=64, backend="xla"), wide)
+    for name, g, w, x in zip("q k v g beta".split(), _grads(_kernel, args),
+                             truth, args):
+        assert g.dtype == x.dtype, name
+        assert _rel(g, w) < WIDE_BF16_GRAD_TOL, name
+
+
+def test_the_kernels_padding_leaves_the_prefix_alone():
+    """A sequence cut after any token gives that prefix's outputs: what a
+    ragged last block holds past the end never reaches back (the
+    interpreter fills it with NaN)."""
+    args = _wide(150, seed=4)
+    whole, _ = jax.jit(_kernel)(*args)
+    part, state = jax.jit(_kernel)(*(x[:, :100] for x in args))
+    _, want = gated_delta_rule_sequential(*(x[:, :100] for x in args))
+    assert np.isfinite(np.asarray(whole)).all()
+    assert _rel(part, whole[:, :100]) < WIDE_F32_TOL
+    assert _rel(state, want) < WIDE_F32_TOL
+
+
+def _shapes(batch, t, h, dk, dv, dtype=jnp.bfloat16):
+    return (jax.ShapeDtypeStruct((batch, t, h, dk), dtype),
+            jax.ShapeDtypeStruct((batch, t, h, dv), dtype))
+
+
+CELL = (2, 4800, 15, 96, 192)          # olmohybrid_replay's layer
+REHEARSAL = (2, 42, 4, 16, 32)         # test_delta_hybrid.py's widths
+
+
+@pytest.mark.parametrize(
+    "shape, chunk, backend, devices, mesh_over, state, picks, supported", [
+        (CELL, 64, "tpu", 1, None, False, True, True),
+        (CELL, 64, "cpu", 1, None, False, False, True),
+        (CELL, 64, "tpu", 1, None, True, False, True),   # a given state
+        (REHEARSAL, 16, "tpu", 1, None, False, True, True),
+        (CELL, 48, "tpu", 1, None, False, False, False),  # not a power of 2
+        (CELL, 8, "tpu", 1, None, False, False, False),   # under bf16's tile
+        ((2, 4800, 15, 100, 192), 64, "tpu", 1, None, False, False, False),
+        # one head's state and blocks past the scoped VMEM
+        ((2, 4800, 16, 512, 1024), 64, "tpu", 1, None, False, False,
+         False),
+        # several devices and nothing declared: the program may be
+        # partitioned, where the lowering refuses a bare kernel
+        (CELL, 64, "tpu", 8, None, False, False, True),
+        # a declared mesh: per batch shard where its axis divides the batch
+        (CELL, 64, "tpu", 8, 2, False, True, True),
+        (CELL, 64, "tpu", 8, 8, False, False, True),
+        (CELL, 64, "tpu", 8, 1, False, True, True),      # a one-device mesh
+    ],
+    ids=["cell", "cell-on-cpu", "initial-state", "rehearsal", "chunk48",
+         "chunk8", "key-of-100", "too-large", "undeclared-8-devices",
+         "mesh2", "mesh8-batch2", "mesh1"],
+)
+def test_which_inputs_take_the_kernel(monkeypatch, shape, chunk, backend,
+                                      devices, mesh_over, state, picks,
+                                      supported):
+    """The eligibility rule as a truth table: ``auto`` takes the kernel
+    on a TPU, where the blocking takes the shape, no initial state is
+    given and the placement may hold a kernel; the XLA form everywhere
+    else."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(jax, "device_count", lambda: devices)
+    q, v = _shapes(*shape)
+    assert delta_rule_kernel_supported(q, v, chunk) == supported
+    initial = jnp.zeros(()) if state else None
+    mesh = None if mesh_over is None else jax.sharding.Mesh(
+        np.array(jax.devices()[:mesh_over]), ("data",)
+    )
+    with batch_sharded_over(mesh):
+        assert auto_picks_kernel(q, v, chunk, initial) == picks
+
+
+@pytest.mark.parametrize("backend, counted, other", [
+    ("auto", "gdn.path.chunked", "gdn.path.kernel"),
+    ("kernel", "gdn.path.kernel", "gdn.path.chunked"),
+], ids=["xla", "kernel"])
+def test_the_kernel_path_is_counted_once_a_trace(backend, counted, other):
+    """One of the two counters a traced call, never both; ``auto`` off a
+    TPU is the XLA form; both run under the scope ``delta_rule``."""
+    args = _wide(64, batch=1)
+    before = metrics.report()["counters"]
+    fn = jax.jit(lambda *a: gated_delta_rule_chunked(
+        *a, chunk=64, backend=backend)[0])
+    text = fn.lower(*args).as_text(debug_info=True)
+    fn.lower(*args)
+    after = metrics.report()["counters"]
+    assert after[counted] == before.get(counted, 0) + 1
+    assert after.get(other, 0) == before.get(other, 0)
+    assert "delta_rule" in text
+
+
+def test_an_explicit_kernel_refuses_what_it_cannot_take():
+    args = _wide(16, batch=1, heads=1, key_dim=8, value_dim=8)
+    with pytest.raises(ValueError, match="power of two"):
+        gated_delta_rule_chunked(*args, chunk=12, backend="kernel")
+    with pytest.raises(ValueError, match="from a given state"):
+        gated_delta_rule_chunked(*args, chunk=8, backend="kernel",
+                                 initial_state=jnp.zeros((1, 1, 8, 8)))
+    with pytest.raises(ValueError, match="unknown delta-rule backend"):
+        gated_delta_rule_chunked(*args, chunk=8, backend="pallas")
+
+
+def test_the_kernel_runs_per_batch_shard_of_a_declared_mesh():
+    """Under a declared mesh the kernel pair runs through ``shard_map``
+    over the batch axis (GSPMD cannot partition a custom call): the same
+    value, final state and gradients as the undeclared call."""
+    args = _wide(70, seed=6)
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("data",))
+    grad = jax.value_and_grad(_weighted(_kernel), tuple(range(5)))
+    want, want_g = jax.jit(grad)(*args)
+    with batch_sharded_over(mesh):
+        got, got_g = jax.jit(grad)(*args)
+    assert _rel(got, want) < 1e-6
+    for g, w in zip(got_g, want_g):
+        assert _rel(g, w) < 1e-6
+
+
+@pytest.mark.parametrize("policy, forwards", [
+    (None, 2),  # plain remat: the backward runs the forward kernel again
+    (jax.checkpoint_policies.save_only_these_names(
+        *DECODER_RESIDUALS, *DELTA_RESIDUALS), 1),
+], ids=["plain", "saved"])
+def test_remat_keeps_the_kernels_states(policy, forwards):
+    """Under ``remat`` with StreamDeltaHybrid's policy the states the
+    kernel starts each chunk from and each chunk's ``(I + A)^-1`` are kept
+    (``gdn_states``, ``gdn_inverse``) beside the output the mixer names
+    (``gdn_o``), so the forward kernel runs once: the lowered program
+    calls it once, and the value and gradients are the ones without
+    ``remat``."""
+    args = _wide(100, seed=7)
+
+    def loss(*a):
+        o, _ = _kernel(*a)
+        return jnp.sum(jnp.sin(saved_residual(o, RESIDUAL_GDN_O)))
+
+    want, want_g = jax.jit(jax.value_and_grad(loss, tuple(range(5))))(*args)
+    grad = jax.jit(jax.value_and_grad(
+        jax.checkpoint(loss, policy=policy), tuple(range(5))
+    ))
+    text = grad.lower(*args).as_text()
+    assert len(re.findall(r"call @_rule_fwd(_\d+)?\(", text)) == forwards
+    assert len(re.findall(r"call @_rule_bwd(_\d+)?\(", text)) == 1
+    got, got_g = grad(*args)
+    assert _rel(got, want) < 1e-6
+    for g, w in zip(got_g, want_g):
+        assert _rel(g, w) < 1e-6

@@ -649,6 +649,105 @@ def test_fused_moe_former_step_recomputes_no_large_product(
     )) for n in recomputed), recomputed
 
 
+def test_delta_rule_fwd_bwd_compiles(topo, tpu_branches, monkeypatch):
+    """The gated delta rule's kernel pair at ``olmohybrid_replay``'s layer
+    shape (2 x 4,800 tokens, 15 heads of 96 / 192, chunks of 64) through
+    ``auto`` on a one-chip machine (which takes it: the working set is
+    within DELTA_VMEM_BYTES, v5e's default scoped VMEM): both kernels come
+    back from the TPU compiler by name, both under the ``delta_rule``
+    scope, the backward's too; no
+    ``triangular-solve`` is left, and nothing chunk-major (N, B, H, C, ·)
+    is in HBM: of a chunk's (C, C) size a head only the forward's
+    ``(I + A)^-1``, which the backward reads."""
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    from blendjax.ops.delta_rule import gated_delta_rule_chunked
+    from blendjax.utils.metrics import (
+        KERNEL_DELTA_RULE_BWD,
+        KERNEL_DELTA_RULE_FWD,
+        SCOPE_DELTA_RULE,
+    )
+
+    one = SingleDeviceSharding(topo.devices[0])
+    b, t, h, dk, dv = 2, 4800, 15, 96, 192
+
+    def loss(*v):
+        o, _ = gated_delta_rule_chunked(*v, chunk=64)
+        return jnp.sum(o)
+
+    compiled = jax.jit(jax.value_and_grad(loss, tuple(range(5)))).lower(
+        _sds((b, t, h, dk), jnp.bfloat16, one),
+        _sds((b, t, h, dk), jnp.bfloat16, one),
+        _sds((b, t, h, dv), jnp.bfloat16, one),
+        _sds((b, t, h), jnp.float32, one), _sds((b, t, h), jnp.float32, one),
+    ).compile()
+    text = _assert_fits_with_kernel(compiled)
+    op_names = {
+        ln.split("=")[0].strip(): ln.split('op_name="')[1].split('"')[0]
+        for ln in text.splitlines() if "tpu_custom_call" in ln
+    }
+    fwd, bwd = (
+        [op for call, op in op_names.items() if call.startswith(f"%{kernel}")]
+        for kernel in (KERNEL_DELTA_RULE_FWD, KERNEL_DELTA_RULE_BWD)
+    )
+    assert len(fwd) == len(bwd) == 1, op_names
+    assert SCOPE_DELTA_RULE in fwd[0] and "transpose(" not in fwd[0], fwd
+    assert SCOPE_DELTA_RULE in bwd[0] and "transpose(jvp(" in bwd[0], bwd
+    assert "triangular-solve" not in text
+    assert not _hbm_lines(text, "f32[75,2,15,64", "bf16[75,2,15,64",
+                          "f32[2,15,75,64")
+    kept = _hbm_lines(text, "f32[2,75,15,64,64")
+    assert kept and all(
+        f"get-tuple-element(%{KERNEL_DELTA_RULE_FWD}" in ln for ln in kept
+    ), kept
+
+
+def _olmo_kwargs(**over):
+    """``olmo_hybrid_7b``'s model arguments, as the benchmark's
+    configuration file has them, some replaced."""
+    import json
+
+    with open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "configs", "olmo_hybrid_7b.json",
+    )) as f:
+        return {**json.load(f)["model"]["kwargs"], **over}
+
+
+def test_fused_delta_hybrid_step_runs_the_kernel_pair(
+    topo, tpu_branches, monkeypatch
+):
+    """The fused tile step of one gated delta-rule layer of
+    ``olmo_hybrid_7b`` at published widths under its ``remat`` (2 x 4,800
+    tokens, a chunk of 2 updates: the scan's body is the same at any):
+    the recurrence is the kernel pair, the forward kernel once, outside the
+    recomputed forward (its chunk states are kept, ``gdn_states``), the
+    backward once, both under ``delta_rule``, and no ``triangular-solve``
+    is left anywhere. ``benchmark/compile_rehearsal.py`` sizes the whole
+    step (4 layers)."""
+    from blendjax.models import StreamDeltaHybrid
+    from blendjax.utils.metrics import (
+        KERNEL_DELTA_RULE_BWD,
+        KERNEL_DELTA_RULE_FWD,
+        SCOPE_DELTA_RULE,
+    )
+
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    one = SingleDeviceSharding(topo.devices[0])
+    model = StreamDeltaHybrid(**_olmo_kwargs(layer_types="L"))
+    step = make_fused_tile_step(loss_fn=chip_smoke.former_loss)
+    lowered = _lower_fused_tile(step, _abstract_state(model, one), 2, one,
+                                _tile_plan((16, 32), batch=2))
+    text = _assert_fits_with_kernel(lowered.compile())
+    for kernel, under in ((KERNEL_DELTA_RULE_FWD, "jvp("),
+                          (KERNEL_DELTA_RULE_BWD, "transpose(jvp(")):
+        calls = re.findall(rf'%{kernel}[.\d]* = .*op_name="([^"]*)"', text)
+        assert len(calls) == 1, (kernel, calls)
+        assert SCOPE_DELTA_RULE in calls[0] and under in calls[0], calls
+        assert "rematted_computation" not in calls[0], calls
+    assert "rematted_computation" in text  # the rest is recomputed
+    assert "triangular-solve" not in text
+
+
 def test_gamma_normalize_compiles(topo):
     """The Pallas gamma kernel is gone (the chip refused its uint8 ->
     float32 cast and nothing called it); what replaced it is plain jnp
