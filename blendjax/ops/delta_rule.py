@@ -76,13 +76,15 @@ Precision, both: ``A``, its inverse (or solve), ``W`` and ``U``, the
 decays, ``beta`` and the carried state are float32 (a bf16 inverse of a
 64-row triangular matrix carries its rounding down the rows); the four
 products with the state and ``V'`` read their operands in the inputs'
-dtype and accumulate in float32; ``o`` is float32. The kernel's backward
-takes the same operands for the transposes of those products, the
-cotangents rounded to the inputs' dtype where they meet a rounded value
-(as ``ops/ssd.py``'s does), and keeps the UT transform's float32. The
-whole op, the kernel's backward too, runs under the scope ``delta_rule``
-and is counted once a trace under ``gdn.path.kernel`` or
-``gdn.path.chunked``.
+dtype and accumulate in float32; ``o`` is float32. In the backward the
+transposes of those products, and of ``Q K^T``, meet a float32
+cotangent (of ``o``, of the carried state, of ``V'``, of the scores)
+with an operand in the inputs' dtype: XLA's autodiff multiplies the two
+at HIGHEST, and the kernel's backward keeps the cotangent float32 there
+too, in three bfloat16 pieces against a bfloat16 operand
+(:func:`_split`), and the UT transform's products in float32. The whole
+op, the kernel's backward too, runs under the scope ``delta_rule`` and is
+counted once a trace under ``gdn.path.kernel`` or ``gdn.path.chunked``.
 """
 
 from __future__ import annotations
@@ -108,9 +110,9 @@ _LANES = 128
 # What a grid step's working set (_vmem_bytes) may come to: v5e's default
 # scoped VMEM. The compiler is given twice that: in a fused step XLA may
 # keep an operand of the kernel in VMEM, and the kernel then asks about
-# 2 MiB more than alone (olmohybrid_replay's backward at 5 heads a group:
-# 15.5 MiB alone, 17.6 in the step; tests/test_tpu_compile.py compiles
-# both).
+# 2 MiB more than alone (olmohybrid_replay's backward at 3 heads a group:
+# 14.8 to 15.0 MiB alone, 16.9 to 17.0 in the step; tests/test_tpu_compile.py
+# compiles both).
 DELTA_VMEM_BYTES = 16 << 20
 
 _einsum = functools.partial(
@@ -180,11 +182,12 @@ def _vmem_bytes(chunk: int, h: int, g: int, dk: int, dv: int,
     larger of the two: a buffer of each block (q, k, v and their
     gradients, do, the states and inverses the chunk starts from, gamma,
     beta and theirs, the final state's cotangent), the carried cotangent,
-    the staged heads (q, k, v, do, dq, dk, dv), ``g`` heads' float32
-    temporaries, about nine each of (C, C), (C, K) and (C, V), and 1 MiB
-    of the compiler's own. (At olmohybrid_replay's shape, compiled for a
-    described v5e: 13.3 MiB at ``g`` 3 and 15.5 at 5; this says 13.7 and
-    16.0.)"""
+    the staged heads (q, k, v, dq, dk, dv and the float32 do), ``g``
+    heads' float32 temporaries, about nine each of (C, C), (C, K) and (C,
+    V), and the three bfloat16 pieces (:func:`_split`) of do, of V''s and
+    of the carried cotangent, and 1 MiB of the compiler's own. (At
+    olmohybrid_replay's shape, compiled for a described v5e: 14.8 to 15.0
+    MiB at ``g`` 3 and 16.4 to 16.5 at 5; this says 15.2 and 18.1.)"""
     def lanes(n):
         return -(-n // _LANES) * _LANES
 
@@ -192,9 +195,12 @@ def _vmem_bytes(chunk: int, h: int, g: int, dk: int, dv: int,
     blocks = chunk * (2 * itemsize * (2 * lanes(h * dk) + lanes(h * dv))
                       + 4 * lanes(h * dv) + 4 * 4 * lanes(h))
     inverses = h * chunk * lanes(chunk) * 4
-    staged = h * chunk * (4 * lanes(dk) + 3 * lanes(dv)) * itemsize
+    staged = h * chunk * ((4 * lanes(dk) + 2 * lanes(dv)) * itemsize
+                          + lanes(dv) * 4)
     temps = 4 * g * chunk * 9 * (lanes(chunk) + lanes(dk) + lanes(dv))
-    return blocks + 3 * state + inverses + staged + temps + (1 << 20)
+    pieces = 3 * 2 * g * (2 * chunk + dk) * lanes(dv)
+    return (blocks + 3 * state + inverses + staged + temps + pieces
+            + (1 << 20))
 
 
 def auto_picks_kernel(q, v, chunk: int, initial_state=None) -> bool:
@@ -334,11 +340,34 @@ _BTN = (((1,), (1,)), ((0,), (0,)))  # a[h].T @ b[h]
 
 def _dot(a, b, dims=_BNN):
     """A product accumulated in float32; float32 operands stay float32
-    on the MXU (HIGHEST: six bf16 passes), as the XLA form's are."""
-    precision = (lax.Precision.HIGHEST if jnp.float32 in (a.dtype, b.dtype)
-                 else None)
+    on the MXU (HIGHEST: six bf16 passes), as the XLA form's are. Either
+    operand may be a float32 value in pieces (:func:`_split`): the sum of
+    the pieces' products."""
+    if isinstance(a, tuple):
+        return sum(_dot(piece, b, dims) for piece in a)
+    if isinstance(b, tuple):
+        return sum(_dot(a, piece, dims) for piece in b)
+    if a.dtype != b.dtype:
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    precision = (lax.Precision.HIGHEST if a.dtype == jnp.float32 else None)
     return lax.dot_general(a, b, dims, precision=precision,
                            preferred_element_type=jnp.float32)
+
+
+def _split(x, dtype):
+    """A float32 cotangent for products with operands of ``dtype``, as
+    :func:`_dot` takes it. For bfloat16, three bfloat16 pieces whose sum
+    is ``x`` (8 significant bits each, float32's 24 in all), each one
+    MXU pass against the bfloat16 operand: the XLA form's float32 x
+    bfloat16 product at HIGHEST, whose other passes multiply the
+    bfloat16 operand's lower pieces, all zero. Otherwise ``x`` whole."""
+    if dtype != jnp.bfloat16:
+        return x
+    pieces = []
+    for _ in range(3):
+        pieces.append(x.astype(dtype))
+        x = x - pieces[-1].astype(jnp.float32)
+    return tuple(pieces)
 
 
 def _rows(x):  # (G, M, N) -> (G, M, 1): the sum over each row
@@ -543,33 +572,35 @@ def _rule_bwd_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref, st_ref,
         hd = _Heads((qs, ks, vs), sums, first, group_size, masks, dk,
                     inverse=inv_ref[0, 0, at])
         dtype = hd.dtype
-        do = dos[at]                                             # (G, C, V)
+        # the cotangents stay float32 where they meet a product, as in the
+        # XLA form's autodiff
+        do = _split(dos[at], dtype)                              # (G, C, V)
         s = st_ref[0, 0, at]                                     # (G, K, V)
         read = s.astype(dtype)
         dnext = dstate[at]
-        dnext_read = dnext.astype(dtype)
+        dnext_pieces = _split(dnext, dtype)
         fresh = (hd.u - _dot(hd.w.astype(dtype), read)).astype(dtype)
         # the entering state's read-out, O += (Q e^gamma) S, and the
         # update, S' = e^gamma_C S + (K e^(gamma_C - gamma))^T V'
         dqe = _dot(do, read, _BNT)
-        dkr = _dot(fresh, dnext_read, _BNT)
+        dkr = _dot(fresh, dnext_pieces, _BNT)
         # inside the chunk: O += Lower(Q K^T * Gamma) V'
         dp = jnp.where(masks.lower, _dot(do, fresh, _BNT), 0.0)
-        d_fresh = _dot(hd.kr.astype(dtype), dnext_read) + _dot(
+        d_fresh = _dot(hd.kr.astype(dtype), dnext_pieces) + _dot(
             hd.scores.astype(dtype), do, _BTN)
-        dfb = d_fresh.astype(dtype)
+        d_fresh_pieces = _split(d_fresh, dtype)
         # V' = U - W S
-        dw = -_dot(dfb, read, _BNT)
+        dw = -_dot(d_fresh_pieces, read, _BNT)
         dstate[at] = hd.whole * dnext + _dot(
-            hd.qe.astype(dtype), do, _BTN) - _dot(hd.w.astype(dtype), dfb,
-                                                  _BTN)
+            hd.qe.astype(dtype), do, _BTN) - _dot(
+            hd.w.astype(dtype), d_fresh_pieces, _BTN)
         # the UT transform, float32: [W | U] = T R, T = (I + A)^-1
         drk = _dot(hd.t, dw, _BTN)
         drv = _dot(hd.t, d_fresh, _BTN)
         da = jnp.where(masks.strict,
                        -(_dot(drk, hd.w, _BNT) + _dot(drv, hd.u, _BNT)), 0.0)
         dkk = da * hd.beta * hd.decay
-        dqk = (dp * hd.decay).astype(dtype)
+        dqk = _split(dp * hd.decay, dtype)
         mixed = da * hd.a + dp * hd.scores   # d(exp(gamma_i - gamma_j))
         dqs[at] = (_dot(dqk, hd.k) + dqe * hd.e).astype(dqs.dtype)
         dks[at] = (drk * hd.beta * hd.e
@@ -642,8 +673,8 @@ def _launch(q, v, gamma, chunk, reverse):
     dtype = q.dtype
     staged = [pltpu.VMEM((h, chunk, dk), dtype)] * 2 + [
         pltpu.VMEM((h, chunk, dv), dtype)]
-    if reverse:  # do, then dq, dk, dv
-        staged += [pltpu.VMEM((h, chunk, dv), dtype)] + staged
+    if reverse:  # do, float32, then dq, dk, dv
+        staged += [pltpu.VMEM((h, chunk, dv), jnp.float32)] + staged
     else:        # o
         staged += [pltpu.VMEM((h, chunk, dv), jnp.float32)]
     return static, dict(

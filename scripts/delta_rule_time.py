@@ -19,9 +19,11 @@ stream's background patch), ``beta`` in (0, 2), decays from weak to
 strong. The cell's trace (``--trace 1``) is the judge of the fused step;
 this says what the kernels cost alone. ``--check`` first prints, a shape,
 the largest difference of the kernel's output and gradients from the XLA
-form's, relative to the latter's largest entry, and of each form's output
-from the float32 recurrence token by token. Exits 2 off a TPU: a CPU time
-says nothing about either form.
+form's, relative to the latter's largest entry; of each form's output
+from the float32 recurrence token by token; and of each form's five
+gradients from float32 autodiff (the XLA form on the same bf16-rounded
+inputs held as float32, every product at HIGHEST). Exits 2 off a TPU: a
+CPU time says nothing about either form.
 """
 
 import json
@@ -124,17 +126,23 @@ def check(shape):
         return jax.jit(jax.value_and_grad(loss, tuple(range(5)),
                                           has_aux=True))
 
+    names = ("dq", "dk", "dv", "dg", "dbeta")
     outs = {form: value_and_o(form)(*args, w) for form in ("xla", "kernel")}
     ((_, o_x), g_x), ((_, o_k), g_k) = outs["xla"], outs["kernel"]
+    wide = tuple(x.astype(jnp.float32) for x in args)
     with jax.default_matmul_precision("highest"):
         want, _ = jax.jit(gated_delta_rule_sequential)(*args)
+        (_, _), truth = value_and_o("xla")(*wide, w)
     return {
         "kernel_minus_xla": {"o": rel(o_k, o_x), **{
-            f"d{name}": rel(k, x)
-            for name, k, x in zip(("q", "k", "v", "g", "beta"), g_k, g_x)
+            name: rel(k, x) for name, k, x in zip(names, g_k, g_x)
         }},
         "o_minus_float32_recurrence": {"xla": rel(o_x, want),
                                        "kernel": rel(o_k, want)},
+        "grads_minus_float32_autodiff": {
+            form: {name: rel(g, t) for name, g, t in zip(names, got, truth)}
+            for form, got in (("xla", g_x), ("kernel", g_k))
+        },
     }
 
 
